@@ -1,0 +1,254 @@
+"""Compile gate: the TPU's own compiler, no TPU.
+
+Every Pallas kernel the GPT-1.3B train step uses, at its real shapes, and
+the whole step at gpt_1p3b width, compiled for a *described* v5e
+(on-chip-measurement guide section 2.3). Nothing executes: this shows what the
+chip's compiler accepts and allocates, not results or times.
+
+The topology is described only inside this file's module-scoped fixture,
+after a test of this file has started: the process that describes it loads
+the TPU's library and keeps it, so it must be the one worker xdist hands this
+file to, and all such tests live in this one file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+V5E_HBM = 16 << 30
+# gpt_1p3b at bs8 x seq1024
+B, S, H, D, HIDDEN, VOCAB = 8, 1024, 16, 128, 2048, 50304
+ROWS = B * S
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip can be written to the
+    # persistent cache but not read back without one
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """`ops/` and `models/` pick kernels by `jax.default_backend()`, which
+    still says cpu here: the test steers it, the program has no option."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def spec(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def compile_for(fn, *specs):
+    return jax.jit(fn).lower(*specs).compile()
+
+
+def pallas_calls(compiled):
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _flash_specs(one_chip):
+    qkv = spec(one_chip, (B, S, H, D), jnp.bfloat16)
+    dummy = spec(one_chip, (1, 1), jnp.float32)
+    return qkv, dummy
+
+
+def _flash_cfg():
+    from paddle_tpu.ops.attention import _plain_cfg
+    return _plain_cfg(True, D ** -0.5)
+
+
+def test_flash_forward(one_chip):
+    from paddle_tpu.ops.attention import _fwd_lse_impl
+    qkv, dummy = _flash_specs(one_chip)
+    compiled = compile_for(
+        lambda q, k, v, a, b, c: _fwd_lse_impl(q, k, v, a, b, c, _flash_cfg(),
+                                               interpret=False),
+        qkv, qkv, qkv, dummy, dummy, dummy)
+    assert pallas_calls(compiled) == 1
+
+
+def test_flash_backward_dq_and_dkv(one_chip):
+    from paddle_tpu.ops.attention import _bwd_impl
+    qkv, dummy = _flash_specs(one_chip)
+    lse = spec(one_chip, (B, H, S, 1), jnp.float32)
+    compiled = compile_for(
+        lambda q, k, v, lse, g, out, a, b, c: _bwd_impl(
+            q, k, v, lse, g, out, a, b, c, _flash_cfg(), interpret=False),
+        qkv, qkv, qkv, lse, qkv, qkv, dummy, dummy, dummy)
+    assert pallas_calls(compiled) == 2          # _bwd_dq_kernel, _bwd_dkv_kernel
+
+
+def test_fused_layer_norm(one_chip, as_tpu):
+    from paddle_tpu.ops.layer_norm import _ln_fwd_impl
+    x = spec(one_chip, (B, S, HIDDEN), jnp.bfloat16)
+    w = spec(one_chip, (HIDDEN,), jnp.bfloat16)
+    assert pallas_calls(compile_for(_ln_fwd_impl, x, w, w)) == 1
+
+
+def test_cross_entropy_forward(one_chip):
+    from paddle_tpu.ops.fused_ops import _xent_fwd_impl
+    compiled = compile_for(
+        lambda lg, lab: _xent_fwd_impl(lg, lab, interpret=False),
+        spec(one_chip, (ROWS, VOCAB), jnp.float32),
+        spec(one_chip, (ROWS,), jnp.int32))
+    assert pallas_calls(compiled) == 1
+
+
+def test_cross_entropy_backward(one_chip):
+    from paddle_tpu.ops.fused_ops import _xent_bwd_impl
+    compiled = compile_for(
+        lambda lg, lab, lse, g: _xent_bwd_impl(lg, lab, lse, g,
+                                               interpret=False),
+        spec(one_chip, (ROWS, VOCAB), jnp.float32),
+        spec(one_chip, (ROWS,), jnp.int32),
+        spec(one_chip, (ROWS, 1), jnp.float32),
+        spec(one_chip, (ROWS,), jnp.float32))
+    assert pallas_calls(compiled) == 1
+
+
+# ---------------------------------------------------------------- whole step
+
+def _chip_mesh(topo, n=1, **axes):
+    """The trainer's six-axis mesh over the first `n` described devices."""
+    import numpy as np
+    names = ("dp", "fsdp", "pp", "tp", "sp", "ep")
+    shape = [axes.get(a, 1) for a in names]
+    return Mesh(np.asarray(topo.devices[:n]).reshape(shape), names)
+
+
+def compile_train_step(topo, layers, batch=B, seq=S):
+    """chip_smoke.py's trainer, its step program compiled for one described
+    v5e. The trainer is built on the CPU (its constructor places real
+    arrays), then pointed at the described device for the trace."""
+    import chip_smoke
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.models import GPT, gpt_1p3b
+
+    mesh_mod.build_mesh(devices=jax.devices()[:1])
+    paddle.seed(0)
+    model = GPT(gpt_1p3b(max_seq_len=seq, num_layers=layers,
+                         remat_policy="full"))
+    model.bfloat16()
+    trainer = chip_smoke.build_trainer(model)
+    chip = _chip_mesh(topo)
+    trainer.mesh = chip
+    mesh_mod.set_mesh(chip)
+    rep = NamedSharding(chip, PartitionSpec())
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=rep),
+            tree)
+
+    state = shapes((trainer.params, trainer.opt_state, trainer.gt_state,
+                    trainer.consts))
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=rep)
+    args = state + (jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
+                    {"input_ids": ids, "labels": ids})
+    in_sh = jax.tree_util.tree_map(lambda s: s.sharding, args)
+    return trainer._build(True, in_shardings=in_sh).lower(*args).compile()
+
+
+def device_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes
+            + m.generated_code_size_in_bytes)
+
+
+def test_train_step_two_layers(topo, as_tpu):
+    compiled = compile_train_step(topo, layers=2)
+    # per layer: flash forward (and its remat twin), dq, dkv, and LayerNorms;
+    # plus the cross-entropy pair
+    assert pallas_calls(compiled) >= 2 * 3 + 2
+    assert device_bytes(compiled) < V5E_HBM
+
+
+@pytest.mark.slow
+def test_train_step_24_layers(topo, as_tpu):
+    compiled = compile_train_step(topo, layers=24)
+    assert pallas_calls(compiled) >= 24 * 3 + 2
+    assert device_bytes(compiled) < V5E_HBM
+
+
+# ------------------------------------------------------------ serving programs
+
+def _serve_decoder(layers):
+    """chip_smoke.py's decoder (constructor defaults, 8 slots x 1,024
+    tokens) at gpt_1p3b width, built on the CPU."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import GPT, gpt_1p3b
+    from paddle_tpu.serving.decoder import PagedGPTDecoder
+
+    paddle.seed(0)
+    model = GPT(gpt_1p3b(max_seq_len=S, num_layers=layers))
+    model.bfloat16()
+    model.eval()
+    return PagedGPTDecoder(model, num_pages=B * (S // 16) + 2, page_size=16,
+                           max_batch=B)
+
+
+# (k, t_tokens, table width) of every packed ragged horizon the smoke's eight
+# prompts (32-512 tokens) and its lone request dispatch at gpt_1p3b, where
+# the scheduler prices one tick per horizon and 128 prompt tokens per slot
+SMOKE_HORIZONS = [(1, 1024, 16), (1, 1024, 32), (1, 512, 32), (1, 256, 64),
+                  (1, 8, 64), (1, 32, 4), (1, 8, 4), (1, 8, 8)]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("k,t,width", SMOKE_HORIZONS)
+def test_serve_horizon_two_layers(one_chip, k, t, width):
+    import functools
+
+    d = _serve_decoder(layers=2)
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda v: spec(one_chip, v.shape, v.dtype), tree)
+
+    def i32(*shape):
+        return spec(one_chip, shape, jnp.int32)
+
+    def flags(*shape):
+        return spec(one_chip, shape, jnp.bool_)
+
+    compiled = jax.jit(
+        functools.partial(d._packed_multi_step, k=k, t=t),
+        donate_argnums=(1, 2),
+    ).lower(shapes(d._w()), shapes(d.k_pages), shapes(d.v_pages),
+            i32(B), i32(B), i32(B, width), i32(B), flags(B), i32(B), i32(),
+            i32(B, d.pend_capacity), i32(B), i32()).compile()
+    # what a 24-layer decoder keeps on the device beside this program: the
+    # GPT it was built from, 22 more layers of weights and pool, and the
+    # other horizons' programs (each carries the embedding and the head)
+    per_layer = sum(v.nbytes // 2 for v in jax.tree_util.tree_leaves(
+        (d._w(), d.k_pages, d.v_pages)))
+    model_bytes = 2 * 24 * 12 * HIDDEN * HIDDEN + 2 * VOCAB * HIDDEN
+    others = (len(SMOKE_HORIZONS) - 1) * \
+        compiled.memory_analysis().generated_code_size_in_bytes
+    assert (device_bytes(compiled) + 22 * per_layer + model_bytes + others
+            < V5E_HBM)
