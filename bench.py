@@ -493,8 +493,7 @@ def run_decode(batch=8, prompt_len=128, gen=128, quant=None):
             pages_per_seq = (prompt_len + gen + page_size - 1) // page_size
             dec = PagedGPTDecoder(
                 model, num_pages=batch * pages_per_seq + 2,
-                page_size=page_size, max_batch=batch, quant=quant,
-                use_kernel=True)
+                page_size=page_size, max_batch=batch, quant=quant)
 
             def run_batch(step_times=None):
                 eng = ContinuousBatchingEngine(dec, max_new_tokens=gen)

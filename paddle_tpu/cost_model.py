@@ -89,21 +89,18 @@ CHIP_SPECS = {
 def chip_spec(kind=None):
     """Resolve a ChipSpec from an explicit name ("v5e") or a jax
     device_kind string ("TPU v5 lite"). With kind=None, asks the live
-    backend; a CPU/no-device environment resolves to v5e (the paper's
-    reference chip), so static analysis off-chip prices for the chip
-    the campaign targets. Branch order matters: 'v6 lite' must check
-    before the generic 'lite' clause or it reads as v5e."""
+    backend; the CPU backend resolves to v5e (the paper's reference
+    chip), so static analysis off-chip prices for the chip the campaign
+    targets. A kind that is in no spec raises: a live device this table
+    cannot price is an error, not a v5e. Branch order matters: 'v6 lite'
+    must check before the generic 'lite' clause or it reads as v5e."""
     if kind is None:
-        try:
-            import jax
-            d = jax.devices()[0]
-            if d.platform != "cpu":
-                kind = d.device_kind
-        except Exception:
-            kind = None
-    if not kind:
-        return CHIP_SPECS["v5e"]
+        import jax
+        d = jax.devices()[0]
+        kind = "cpu" if d.platform == "cpu" else d.device_kind
     k = str(kind).lower()
+    if k == "cpu":
+        return CHIP_SPECS["v5e"]
     if k in CHIP_SPECS:
         return CHIP_SPECS[k]
     if "v6" in k:
@@ -114,7 +111,9 @@ def chip_spec(kind=None):
         return CHIP_SPECS["v5p"]
     if "v4" in k:
         return CHIP_SPECS["v4"]
-    return CHIP_SPECS["v5e"]
+    raise ValueError(
+        f"chip_spec: no ChipSpec for device kind {kind!r} "
+        f"(known: {', '.join(CHIP_SPECS)})")
 
 
 # ------------------------------------------------------------ jaxpr flops
@@ -356,12 +355,6 @@ def best_n_chunks(compute_s, wire_s, max_chunks=64,
 
 # ------------------------------------------------------- decode horizon
 
-# Fallback python-dispatch + device->host-fetch cost of one decode sync
-# when no measurement is available (order of magnitude of a CPython
-# jit-call + np.asarray round-trip on a dev host). The engine's horizon
-# only needs the right magnitude: K is capped and bucketed anyway.
-DEFAULT_DECODE_SYNC_S = 4e-4
-
 _MEASURED_SYNC = {}
 
 
@@ -372,20 +365,17 @@ def measured_host_sync_s(force=False):
     the 'measured host overhead per sync' leg of the K pricing."""
     if _MEASURED_SYNC and not force:
         return _MEASURED_SYNC["s"]
-    try:
-        import jax
-        import jax.numpy as jnp
-        f = jax.jit(lambda x: x + 1)
-        x = jnp.zeros((8,), jnp.int32)
-        np.asarray(f(x))                     # compile outside the timing
-        t0 = time.perf_counter()
-        n = 10
-        for _ in range(n):
-            x = f(x)
-            np.asarray(x)
-        dt = (time.perf_counter() - t0) / n
-    except Exception:
-        dt = DEFAULT_DECODE_SYNC_S
+    import jax
+    import jax.numpy as jnp
+    f = jax.jit(lambda x: x + 1)
+    x = jnp.zeros((8,), jnp.int32)
+    np.asarray(f(x))                         # compile outside the timing
+    t0 = time.perf_counter()
+    n = 10
+    for _ in range(n):
+        x = f(x)
+        np.asarray(x)
+    dt = (time.perf_counter() - t0) / n
     _MEASURED_SYNC["s"] = max(dt, 1e-6)
     return _MEASURED_SYNC["s"]
 

@@ -1,11 +1,14 @@
-"""One-time fallback signalling for Pallas kernels.
+"""What happens when a Pallas kernel cannot be built.
 
-Kernel dispatch keeps a defensive try/except (Pallas lowering support
-varies across backends and interpret mode), but abandoning a kernel must
-never be silent: a production run quietly using the O(L^2)-HBM reference
-path is a perf/memory cliff. Each (kernel, reason) pair warns once.
+On the CPU backend (tests, interpret mode) kernel dispatch falls back to the
+XLA reference path and each (kernel, reason) pair warns once. On any other
+backend there is no fallback: a run on the chip that quietly took the
+O(L^2)-HBM reference path would report that path's speed and memory under
+the kernel's name, so the kernel's own error is raised.
 """
 import warnings
+
+import jax
 
 _warned = set()
 
@@ -13,7 +16,10 @@ __all__ = ["kernel_fallback"]
 
 
 def kernel_fallback(name, err):
-    """Record that Pallas kernel `name` was abandoned because of `err`."""
+    """Called from an `except` that caught `err` building kernel `name`:
+    re-raise it unless the backend is the CPU, where it warns once."""
+    if jax.default_backend() != "cpu":
+        raise err
     key = (name, type(err).__name__)
     if key in _warned:
         return

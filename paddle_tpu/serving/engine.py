@@ -210,7 +210,12 @@ class ContinuousBatchingEngine:
                 from ..cost_model import decode_horizon
                 self.k_max = decode_horizon(decoder.step_hbm_bytes(),
                                             host_sync_s=host_sync_s)
-        self.ragged = bool(self.k_max > 1 if ragged is None else ragged)
+        # a PRICED horizon of one tick (big model: the tick dwarfs the
+        # sync) is still a ragged horizon — only an explicit k_max=1
+        # asks for the legacy per-tick loop, whose blocking prefill
+        # packs a whole admission wave into one dispatch
+        self.ragged = bool((k_max is None or self.k_max > 1)
+                           if ragged is None else ragged)
         # PACKED token-stream dispatch for the ragged horizons (default:
         # the decoder's layout flag): every tick pays its total token
         # count, bucketed pow2 (`HorizonPlan.t_tokens`) — not the dense
