@@ -26,10 +26,13 @@ import statistics
 import sys
 import time
 
-# logit gaps of a bf16 model with hidden 2048 against its own reference: two
-# bf16 roundings of a ~1.0-magnitude logit are 2^-7 apart; 24 layers of them
-# stay well under this
-LOGIT_TOL = 0.25
+# at this init a logit row has a spread of about 0.9, its maximum sits near
+# +3.6 and a token picked for any wrong reason lies about that far below it.
+# Two bf16 computations of one row (the decoder's paged attention and the
+# GPT forward's flash attention round differently through 24 layers) differ
+# by a few hundredths, so the decoder's pick may be the reference's runner-up
+# but never lies this far under its maximum
+LOGIT_TOL = 0.5
 # two bf16 runs of the same batch on different shardings reduce in different
 # orders; the loss is a mean over thousands of fp32 rows
 LOSS_TOL = 0.05

@@ -230,9 +230,11 @@ class Trainer:
             NamedSharding(self.mesh, PartitionSpec()))
         self.params = trainable
         self.consts = consts
-        # slots inherit param shardings: zeros_like under jit keeps sharding
-        self.opt_state = self._mesh_place(
-            jax.jit(optimizer.init_state_pytree)(self.params))
+        self.opt_state = jax.jit(
+            optimizer.init_state_pytree,
+            out_shardings=self._state_shardings(
+                jax.eval_shape(optimizer.init_state_pytree, self.params))
+        )(self.params)
         if self.grad_transform is not None and \
                 hasattr(self.grad_transform, "init_state"):
             self.gt_state = self._mesh_place(
@@ -301,6 +303,23 @@ class Trainer:
         and kept OUT of the drift ledger — the trainer's rendering of
         the serving engines' polluted-window exclusion."""
         self._rec_last_t = None
+
+    def _state_shardings(self, state):
+        """Shardings for an optimizer-state pytree: a slot shaped like its
+        parameter (moments, master copy) is laid out like the parameter,
+        everything else (step counters, scalars) is replicated. Left to
+        the compiler, a zeros-initialised slot depends on no input and
+        comes out replicated on every device."""
+        rep = NamedSharding(self.mesh, PartitionSpec())
+
+        def pick(path, leaf):
+            keys = [getattr(k, "key", None) for k in path]
+            p = self.params.get(keys[1]) if len(keys) > 1 and \
+                keys[0] == "slots" else None
+            if p is not None and p.shape == leaf.shape:
+                return p.sharding
+            return rep
+        return jax.tree_util.tree_map_with_path(pick, state)
 
     def _mesh_place(self, tree):
         """Replicate any single-device leaf onto the full mesh. A state
