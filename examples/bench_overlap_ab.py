@@ -43,8 +43,7 @@ import jax.numpy as jnp                                # noqa: E402
 from jax.sharding import PartitionSpec as P            # noqa: E402
 
 from paddle_tpu.cost_model import chunked_overlap_time  # noqa: E402
-from paddle_tpu.distributed.mesh import (build_mesh,    # noqa: E402
-                                         compat_shard_map)
+from paddle_tpu.distributed.mesh import build_mesh     # noqa: E402
 from paddle_tpu.ops.overlap import (                    # noqa: E402
     chunked_matmul_all_reduce)
 
@@ -74,15 +73,15 @@ def main():
     y = jnp.asarray(rng.randn(M, N) * 0.1, jnp.float32)
 
     def sm(body, n_in):
-        return jax.jit(compat_shard_map(
-            body, mesh,
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P(None, "tp"), P("tp", None))[:n_in] or (P(),),
-            out_specs=P(), axis_names={"tp"}, check=False))
+            out_specs=P(), axis_names={"tp"}, check_vma=False))
 
     compute = sm(lambda xs, ws: xs @ ws, 2)
-    wire = jax.jit(compat_shard_map(
-        lambda ys: jax.lax.psum(ys, "tp"), mesh, in_specs=(P(),),
-        out_specs=P(), axis_names={"tp"}, check=False))
+    wire = jax.jit(jax.shard_map(
+        lambda ys: jax.lax.psum(ys, "tp"), mesh=mesh, in_specs=(P(),),
+        out_specs=P(), axis_names={"tp"}, check_vma=False))
     bulk = sm(lambda xs, ws: chunked_matmul_all_reduce(
         xs, ws, "tp", impl="bulk"), 2)
     ring = sm(lambda xs, ws: chunked_matmul_all_reduce(
@@ -90,11 +89,11 @@ def main():
     # per-permute dispatch floor: one tiny single-hop round on the
     # same gloo wire — the measured value of the cost model's
     # CHUNK_LAUNCH_OVERHEAD_S knob on this transport
-    tiny = jax.jit(compat_shard_map(
+    tiny = jax.jit(jax.shard_map(
         lambda v: jax.lax.ppermute(
             v, "tp", [(i, (i + 1) % p) for i in range(p)]),
-        mesh, in_specs=(P(),), out_specs=P(None), axis_names={"tp"},
-        check=False))
+        mesh=mesh, in_specs=(P(),), out_specs=P(None), axis_names={"tp"},
+        check_vma=False))
 
     # twin discipline holds over the real gloo wire too
     assert np.asarray(ring(x, w)).tobytes() == \

@@ -620,7 +620,7 @@ def gpt_tp_overlap_program(impl="ring", n_chunks=4):
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from ..distributed.mesh import build_mesh, compat_shard_map
+    from ..distributed.mesh import build_mesh
     from .lowering import LoweredProgram, tree_arg_infos
     if len(jax.devices()) < TP_OVERLAP_AXIS:
         raise RuntimeError(
@@ -642,9 +642,9 @@ def gpt_tp_overlap_program(impl="ring", n_chunks=4):
              "w1": P(None, "tp"), "w2": P("tp", None)}
     body = functools.partial(_tp_overlap_block, n_chunks=n_chunks,
                              impl=impl)
-    f = compat_shard_map(body, mesh,
+    f = jax.shard_map(body, mesh=mesh,
                          in_specs=tuple(specs[k] for k in args),
-                         out_specs=P(), axis_names={"tp"}, check=False)
+                         out_specs=P(), axis_names={"tp"}, check_vma=False)
     shardings = tuple(NamedSharding(mesh, specs[k]) for k in args)
     traced = jax.jit(f, in_shardings=shardings).trace(*args.values())
     infos = []

@@ -99,15 +99,12 @@ class LocalSGDTrainer:
         strip = lambda tree: jax.tree_util.tree_map(lambda _: P(axis), tree)
 
         def step(params, opt_state, consts, lr, batch, do_sync):
-            # version/kwarg portability lives in mesh.compat_shard_map
-            from .mesh import compat_shard_map
-            return compat_shard_map(
+            return jax.shard_map(
                 local_step, mesh=self.mesh,
                 in_specs=(strip(params), strip(opt_state), P(), P(),
                           jax.tree_util.tree_map(lambda _: P(axis), batch), P()),
                 out_specs=(strip(params), strip(opt_state), P(), P()),
-                check=False,
-            )(params, opt_state, consts, lr, batch, do_sync)
+                check_vma=False)(params, opt_state, consts, lr, batch, do_sync)
 
         return jax.jit(step, donate_argnums=(0, 1))
 

@@ -22,42 +22,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = ["get_mesh", "set_mesh", "build_mesh", "mesh_axis_size", "PartitionSpec",
            "NamedSharding", "Mesh", "named_sharding", "current_axis_context",
-           "in_shard_map", "axis_scope", "compat_shard_map"]
+           "in_shard_map", "axis_scope"]
 
-
-def compat_shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-                     check=True):
-    """`jax.shard_map` across jax versions (the localsgd.py shim made
-    reusable): top-level export on jax >= 0.6, experimental module on
-    0.4.x; the replication-check kwarg picked by SIGNATURE (check_vma vs
-    check_rep — renamed independently of the import move). `axis_names`
-    (the >= 0.6 manual-axes subset) maps onto 0.4.x's complementary
-    `auto` set, where replication checking must be off (0.4.x rejects
-    check_rep with auto axes)."""
-    import inspect
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if "check_vma" in params:
-        kw["check_vma"] = check
-    elif "check_rep" in params:
-        kw["check_rep"] = check
-    if axis_names:
-        if "axis_names" in params:
-            kw["axis_names"] = set(axis_names)
-        else:
-            # 0.4.x `auto` (the complement set) raises NotImplementedError
-            # on these program shapes; leaving the other axes MANUAL with
-            # replicated specs is numerically equivalent as long as the
-            # body only issues collectives over `axis_names` — true for
-            # every caller here (pipeline schedules over 'pp'). check_rep
-            # can't see that and must be off.
-            if "check_rep" in kw:
-                kw["check_rep"] = False
-    return sm(f, **kw)
 
 _state = {"mesh": None, "axis_context": ()}
 

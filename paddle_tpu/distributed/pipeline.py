@@ -49,31 +49,11 @@ from jax.sharding import PartitionSpec as P
 
 __all__ = ["pipeline_apply", "interleaved_schedule_table"]
 
-# jax 0.4.x shard_map transpose convention (pre-VMA, detected via the
-# pcast API that shipped with the new system): the cotangent of a
-# replicated (P()) OUTPUT reaches a custom_vjp body divided by the FULL
-# device count, while a replicated input's cotangent is only psummed
-# over the axes its in_spec leaves unmentioned. A body whose params ride
-# in_spec P(axis, ...) therefore comes out 1/axis_size too small and
-# must rescale dparams itself; >= 0.6 transposes symmetrically and needs
-# no correction (autodiff-through-shard_map is symmetric on both).
-_LEGACY_SHARD_MAP_TRANSPOSE = not hasattr(jax.lax, "pcast")
-
-
-def _legacy_dparams_fix(dparams, axis_name):
-    if not _LEGACY_SHARD_MAP_TRANSPOSE:
-        return dparams
-    s = jax.lax.psum(1, axis_name)
-    return jax.tree_util.tree_map(lambda v: v * s, dparams)
-
-
 def _make_varying(axis_name):
     def _varying(z):
         try:
             return jax.lax.pcast(z, (axis_name,), to="varying")
         except ValueError:       # already varying over axis_name
-            return z
-        except AttributeError:   # jax 0.4.x: no VMA system — nothing to cast
             return z
     return _varying
 
@@ -178,8 +158,7 @@ def _1f1b_local(stage_fn, n_micro, n_stages, axis_name):
         # only stage 0 holds the true input grad; psum the masked value so
         # the cotangent is pp-invariant, matching the replicated in_spec
         dxv = jnp.where(idx == 0, dxv, jnp.zeros_like(dxv))
-        return (_legacy_dparams_fix(dparams, axis_name),
-                jax.lax.psum(dxv, axis_name))
+        return dparams, jax.lax.psum(dxv, axis_name)
 
     run.defvjp(run_fwd, run_bwd)
     return run
@@ -427,8 +406,7 @@ def _interleaved_1f1b_local(stage_fn, n_micro, n_stages, virtual, axis_name):
             btick, (dbuf0, dmb0, dparams0, dsend0), jnp.arange(T))
         dxv = dmb.reshape((M * mb_shape[0],) + mb_shape[1:])
         dxv = jnp.where(idx == 0, dxv, jnp.zeros_like(dxv))
-        return (_legacy_dparams_fix(dparams, axis_name),
-                jax.lax.psum(dxv, axis_name))
+        return dparams, jax.lax.psum(dxv, axis_name)
 
     run.defvjp(run_fwd, run_bwd)
     return run
@@ -503,10 +481,8 @@ def pipeline_apply(stage_fn, stacked_params, x, n_microbatch, mesh=None,
     if param_specs is None:
         param_specs = jax.tree_util.tree_map(
             lambda v: P(axis_name, *([None] * (v.ndim - 1))), stacked_params)
-    from .mesh import compat_shard_map
-    return compat_shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),
-        axis_names={axis_name},
-    )(stacked_params, x)
+        axis_names={axis_name})(stacked_params, x)

@@ -194,8 +194,8 @@ class Trainer:
         # the chunked ascending ring (ops/overlap.py) — each bucket's
         # wire overlaps the optimizer update consuming the previous
         # bucket, and 'ring' is bit-identical to 'bulk' by the twin
-        # pin. Targets dp meshes (other axes stay size 1 on jax 0.4.x,
-        # where manual shard_map axes cannot be subset).
+        # pin. The shard_map is manual over 'dp' alone (axis_names), so
+        # the other mesh axes stay with GSPMD.
         if dp_overlap not in ("off", "bulk", "ring"):
             raise ValueError(f"dp_overlap must be 'off', 'bulk' or "
                              f"'ring', got {dp_overlap!r}")
@@ -408,7 +408,6 @@ class Trainer:
         # ('ring') or bulk psum ('bulk'), bit-identical by the twin pin.
         from jax.sharding import PartitionSpec as P
         from ..ops.overlap import chunked_all_reduce
-        from .mesh import compat_shard_map
         impl = "ring" if self.dp_overlap == "ring" else "bulk"
         n_buckets = max(1, self.dp_overlap_buckets)
         mesh = self.mesh
@@ -446,11 +445,11 @@ class Trainer:
             return new_params, new_state, gt_state, new_consts, loss_v
 
         def _inner_dp(params, opt_state, gt_state, consts, lr, batch):
-            return compat_shard_map(
-                _shard_body, mesh,
+            return jax.shard_map(
+                _shard_body, mesh=mesh,
                 in_specs=(P(), P(), P(), P(), P(), P("dp")),
                 out_specs=(P(), P(), P(), P(), P()),
-                axis_names={"dp"}, check=False)(
+                axis_names={"dp"}, check_vma=False)(
                 params, opt_state, gt_state, consts, lr, batch)
 
         return _inner_dp

@@ -38,7 +38,7 @@ schedulable pieces.  The indivisible fallback exchanges full partials
 by the axis size where throughput matters.
 
 Public wrappers (``overlap_*``) take GLOBAL arrays and wrap
-``distributed.mesh.compat_shard_map`` over one named axis; the
+``jax.shard_map`` over one named axis; the
 ``chunked_*`` bodies are usable directly inside an existing shard_map
 (or a ``make_jaxpr(axis_env=...)`` capture).  ``impl="bulk"`` keeps the
 jnp bulk reference as the A/B path behind a flag.
@@ -280,10 +280,9 @@ def _resolve_mesh(mesh):
 
 
 def _wrap(body, mesh, axis, in_specs, out_specs):
-    from ..distributed.mesh import compat_shard_map
-    return compat_shard_map(body, mesh, in_specs=in_specs,
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                             out_specs=out_specs, axis_names={axis},
-                            check=False)
+                            check_vma=False)
 
 
 def overlap_matmul_all_reduce(x, w, axis="tp", n_chunks=4, mesh=None,

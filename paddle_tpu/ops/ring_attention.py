@@ -31,15 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-def _axis_size(axis_name):
-    """Static size of a shard_map axis: `jax.lax.axis_size` on jax >= 0.6;
-    on 0.4.x, psum of a literal 1 (constant-folded to the static size)."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
-
-
 __all__ = ["ring_attention_local", "ring_attention",
            "ring_flash_attention_local", "zigzag_ring_attention_local",
            "zigzag_ring_flash_attention_local"]
@@ -77,7 +68,7 @@ def _ring_flash(q, k, v, axis_name, causal, scale):
 def _ring_flash_fwd_compute(q, k, v, axis_name, causal, scale):
     from .attention import _flash_fwd_lse_impl
 
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 
@@ -120,7 +111,7 @@ def _ring_flash_bwd(axis_name, causal, scale, res, cts):
 
     q, k, v, out, lse = res
     g = cts[0].astype(q.dtype)   # lse cotangent is zero in ring use
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 
@@ -212,7 +203,7 @@ def _zz_ring_flash(q, k, v, axis_name, scale):
 def _zz_ring_flash_fwd_compute(q, k, v, axis_name, scale):
     from .attention import _flash_fwd_lse_impl
 
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     d = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % sp) for i in range(sp)]
     Lh = q.shape[1] // 2
@@ -278,7 +269,7 @@ def _zz_ring_flash_bwd(axis_name, scale, res, cts):
 
     q, k, v, out, lse = res
     g = cts[0].astype(q.dtype)
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     d = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % sp) for i in range(sp)]
     Lh = q.shape[1] // 2
@@ -379,7 +370,7 @@ def ring_attention_local(q, k, v, axis_name="sp", causal=True, scale=None,
 
 def _ring_dense_local(q, k, v, axis_name="sp", causal=True, scale=None):
     """Dense per-step scores (materializes Lq x Lk per ring step)."""
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     scale = scale if scale is not None else 1.0 / np.sqrt(q.shape[-1])
 
@@ -454,7 +445,7 @@ def zigzag_ring_attention_local(q, k, v, axis_name="sp", scale=None,
 
 def _zigzag_dense_local(q, k, v, axis_name="sp", scale=None):
     """Dense zigzag step blocks (materializes Lh x Lh scores per block)."""
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     d = jax.lax.axis_index(axis_name)
     scale = scale if scale is not None else 1.0 / np.sqrt(q.shape[-1])
 
@@ -584,7 +575,7 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=True,
     """
     from jax.sharding import PartitionSpec as P
 
-    from ..distributed.mesh import compat_shard_map, get_mesh
+    from ..distributed.mesh import get_mesh
 
     mesh = mesh or get_mesh()
     spec = P(batch_axes, axis_name, None, None)
@@ -621,5 +612,5 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=True,
         # the vma checker can't see through pallas_call's out_shape (same
         # caveat as ulysses.py); keep it active for the dense paths
         check_vma = not use_flash
-    return compat_shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec, check=check_vma)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec, check_vma=check_vma)(q, k, v)

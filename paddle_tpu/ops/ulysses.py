@@ -62,7 +62,7 @@ def ulysses_attention(q, k, v, mesh=None, axis_name="sp", causal=True,
     `axis_name`. Requires H % sp == 0."""
     from jax.sharding import PartitionSpec as P
 
-    from ..distributed.mesh import compat_shard_map, get_mesh
+    from ..distributed.mesh import get_mesh
 
     mesh = mesh or get_mesh()
     sp = mesh.shape[axis_name]
@@ -76,5 +76,5 @@ def ulysses_attention(q, k, v, mesh=None, axis_name="sp", causal=True,
                            causal=causal, scale=scale)
     # check_vma=False: the vma checker can't see through pallas_call's
     # out_shape, so it would force the flash kernel onto the fallback path
-    return compat_shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec, check=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec, check_vma=False)(q, k, v)

@@ -56,8 +56,7 @@ def test_collectives_inside_shard_map():
             return all_reduce(x)
 
     x = jnp.arange(8.0)
-    from paddle_tpu.distributed.mesh import compat_shard_map
-    out = compat_shard_map(local, mesh=mesh, in_specs=P("dp"),
+    out = jax.shard_map(local, mesh=mesh, in_specs=P("dp"),
                            out_specs=P("dp"))(x)
     np.testing.assert_allclose(np.asarray(out), np.full(8, 28.0))
 

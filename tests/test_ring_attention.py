@@ -227,8 +227,7 @@ def test_zigzag_layout_roundtrip():
         z = _contig_to_zigzag(v, "sp", 4)
         return _zigzag_to_contig(z, "sp", 4)
 
-    from paddle_tpu.distributed.mesh import compat_shard_map
-    out = compat_shard_map(rt, mesh=mesh, in_specs=P(None, "sp"),
+    out = jax.shard_map(rt, mesh=mesh, in_specs=P(None, "sp"),
                            out_specs=P(None, "sp"))(x)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 

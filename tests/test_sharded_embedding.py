@@ -126,8 +126,7 @@ def test_manual_shard_map_lookup_matches_dense():
                 out = e(paddle.Tensor(ids_local))
         return out._value
 
-    from paddle_tpu.distributed.mesh import compat_shard_map
-    out = compat_shard_map(body, mesh=get_mesh(),
+    out = jax.shard_map(body, mesh=get_mesh(),
                            in_specs=(P(), P("tp", None)),
                            out_specs=P())(ids, w)
     with functional_call(e, {"weight": w}):
