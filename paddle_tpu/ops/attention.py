@@ -28,6 +28,7 @@ import numpy as np
 
 from ..framework.core import Tensor, apply_op
 from ._fallback import kernel_fallback
+from ._per_device import BATCH_AXES, P, dim_axes, kernel_mesh, per_device
 
 __all__ = ["flash_attention", "flash_attention_available", "mha_reference"]
 
@@ -550,6 +551,59 @@ def _bwd_impl(q, k, v, lse, g, out, kvb, fb, seed, cfg, interpret=None):
 
 
 # ---------------------------------------------------------------------------
+# More than one device: attention is independent per batch row and per head,
+# so each device runs the kernels on its own rows (data axes) and heads
+# ('tp'). A full bias that carries heads keeps the heads together.
+# ---------------------------------------------------------------------------
+
+def _flash_shard_specs(mesh, cfg, q, k):
+    _, _, _, has_kvb, kvb_b, has_fb, fb_b, _ = cfg
+    b = dim_axes(mesh, q.shape[0], BATCH_AXES)
+    h = None if has_fb else dim_axes(mesh, k.shape[2], ("tp",))
+    return (P(b, None, h, None),                    # q, k, v, g, out
+            P(b, h, None, None),                    # lse
+            P(b if has_kvb and kvb_b else None),    # kvb
+            P(b if has_fb and fb_b else None),      # fb
+            (b or ()) + (h or ()))
+
+
+def _shard_seed(seed, cfg, axes):
+    """Inside the shard_map: the dropout hash counts batch rows and heads
+    from the kernel's own grid, so give every shard its own seed."""
+    if not cfg[2]:
+        return seed
+    idx = jnp.zeros((), jnp.int32)
+    for a in axes:
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
+    return seed + idx.astype(seed.dtype)
+
+
+def _fwd_lse(q, k, v, kvb, fb, seed, cfg):
+    mesh = kernel_mesh()
+    if mesh is None:
+        return _fwd_lse_impl(q, k, v, kvb, fb, seed, cfg)
+    blhd, lse, kvb_s, fb_s, axes = _flash_shard_specs(mesh, cfg, q, k)
+    return per_device(
+        lambda q, k, v, kvb, fb, seed: _fwd_lse_impl(
+            q, k, v, kvb, fb, _shard_seed(seed, cfg, axes), cfg),
+        mesh, (blhd, blhd, blhd, kvb_s, fb_s, P()), (blhd, lse),
+    )(q, k, v, kvb, fb, seed)
+
+
+def _bwd(q, k, v, lse, g, out, kvb, fb, seed, cfg):
+    mesh = kernel_mesh()
+    if mesh is None:
+        return _bwd_impl(q, k, v, lse, g, out, kvb, fb, seed, cfg)
+    blhd, lse_s, kvb_s, fb_s, axes = _flash_shard_specs(mesh, cfg, q, k)
+    return per_device(
+        lambda q, k, v, lse, g, out, kvb, fb, seed: _bwd_impl(
+            q, k, v, lse, g, out, kvb, fb, _shard_seed(seed, cfg, axes), cfg),
+        mesh, (blhd, blhd, blhd, lse_s, blhd, blhd, kvb_s, fb_s, P()),
+        (blhd, blhd, blhd),
+    )(q, k, v, lse, g, out, kvb, fb, seed)
+
+
+# ---------------------------------------------------------------------------
 # custom_vjp core. Extras (kvb, fb, seed) are always passed (dummy (1, 1)
 # zeros when unused — cfg flags gate both the kernels and the specs), so one
 # function covers every feature combination without None-pytree contortions.
@@ -580,7 +634,7 @@ def _ref_with_extras(cfg, q, k, v, kvb, fb, seed):
 
 def _flash_core_fwd(cfg, q, k, v, kvb, fb, seed):
     try:
-        out, lse = _fwd_lse_impl(q, k, v, kvb, fb, seed, cfg)
+        out, lse = _fwd_lse(q, k, v, kvb, fb, seed, cfg)
         return out, (q, k, v, kvb, fb, seed, lse, out)
     except Exception as e:
         kernel_fallback("flash_attention_fwd", e)
@@ -593,7 +647,7 @@ def _flash_core_bwd(cfg, res, g):
     zeros = (jnp.zeros_like(kvb), jnp.zeros_like(fb), jnp.zeros_like(seed))
     if lse is not None:
         try:
-            dq, dk, dv = _bwd_impl(q, k, v, lse, g, out, kvb, fb, seed, cfg)
+            dq, dk, dv = _bwd(q, k, v, lse, g, out, kvb, fb, seed, cfg)
             return (dq, dk, dv) + zeros
         except Exception as e:
             kernel_fallback("flash_attention_bwd", e)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ._fallback import kernel_fallback
+from ._per_device import BATCH_AXES, P, dim_axes, kernel_mesh, per_device
 
 __all__ = ["fused_softmax_cross_entropy", "fused_adamw",
            "fused_dropout_residual_layer_norm"]
@@ -118,9 +119,21 @@ def fused_softmax_cross_entropy(logits, labels):
     return loss
 
 
+def _xent_rows(mesh, n):
+    """Rows are independent: split them over the data axes in whole
+    256-row blocks (the vocab stays together; the compiler gathers it)."""
+    return P(dim_axes(mesh, n // 256, BATCH_AXES) if n % 256 == 0 else None)
+
+
 def _xent_fwd_impl(logits, labels, interpret=None):
     from jax.experimental import pallas as pl
 
+    mesh = kernel_mesh()
+    if mesh is not None:
+        rows = _xent_rows(mesh, logits.shape[0])
+        return per_device(
+            lambda lg, lab: _xent_fwd_impl(lg, lab, interpret),
+            mesh, (rows, rows), (rows, rows))(logits, labels)
     if interpret is None:
         interpret = _interpret_default()
     from jax.experimental.pallas import tpu as pltpu
@@ -167,6 +180,13 @@ def _xent_fwd(logits, labels):
 def _xent_bwd_impl(logits, labels, lse, g, interpret=None):
     from jax.experimental import pallas as pl
 
+    mesh = kernel_mesh()
+    if mesh is not None:
+        rows = _xent_rows(mesh, logits.shape[0])
+        return per_device(
+            lambda lg, lab, lse, g: _xent_bwd_impl(lg, lab, lse, g,
+                                                   interpret),
+            mesh, (rows, rows, rows, rows), rows)(logits, labels, lse, g)
     if interpret is None:
         interpret = _interpret_default()
     n, v = logits.shape

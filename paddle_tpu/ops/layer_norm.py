@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from ._fallback import kernel_fallback
+from ._per_device import BATCH_AXES, P, dim_axes, kernel_mesh, per_device
 
 __all__ = ["fused_layer_norm", "fused_rms_norm",
            "fused_layer_norm_op", "fused_rms_norm_op"]
@@ -56,9 +57,21 @@ def _rows_block(n_rows, h, dtype):
     return rows
 
 
+def _rows_spec(mesh, x):
+    """Rows of a norm are independent: dim 0 over the data axes, and the
+    sequence dim of a [B, L, h] activation over 'sp'."""
+    seq = (dim_axes(mesh, x.shape[1], ("sp",)),) if x.ndim >= 3 else ()
+    return P(dim_axes(mesh, x.shape[0], BATCH_AXES), *seq)
+
+
 def _ln_fwd_impl(x, weight, bias, eps=1e-5):
     from jax.experimental import pallas as pl
 
+    mesh = kernel_mesh()
+    if mesh is not None:
+        rows = _rows_spec(mesh, x)
+        return per_device(lambda x, w, b: _ln_fwd_impl(x, w, b, eps),
+                          mesh, (rows, P(), P()), rows)(x, weight, bias)
     h = x.shape[-1]
     flat = x.reshape(-1, h)
     n = flat.shape[0]
@@ -97,6 +110,11 @@ def _ln_bwd(res, g, eps):
 def _rms_fwd_impl(x, weight, eps=1e-6):
     from jax.experimental import pallas as pl
 
+    mesh = kernel_mesh()
+    if mesh is not None:
+        rows = _rows_spec(mesh, x)
+        return per_device(lambda x, w: _rms_fwd_impl(x, w, eps),
+                          mesh, (rows, P()), rows)(x, weight)
     h = x.shape[-1]
     flat = x.reshape(-1, h)
     n = flat.shape[0]

@@ -129,46 +129,46 @@ def test_cross_entropy_backward(one_chip):
 
 # ---------------------------------------------------------------- whole step
 
-def _chip_mesh(topo, n=1, **axes):
-    """The trainer's six-axis mesh over the first `n` described devices."""
-    import numpy as np
-    names = ("dp", "fsdp", "pp", "tp", "sp", "ep")
-    shape = [axes.get(a, 1) for a in names]
-    return Mesh(np.asarray(topo.devices[:n]).reshape(shape), names)
-
-
-def compile_train_step(topo, layers, batch=B, seq=S):
-    """chip_smoke.py's trainer, its step program compiled for one described
-    v5e. The trainer is built on the CPU (its constructor places real
-    arrays), then pointed at the described device for the trace."""
+def compile_train_step(topo, layers, batch=B, seq=S, **axes):
+    """chip_smoke.py's trainer, its step program compiled for described
+    v5e chips: one, or a mesh of `axes` (fsdp=2, tp=2). The trainer is
+    built on CPU devices (its constructor places real arrays), then pointed
+    at the described ones for the trace, every argument keeping the
+    PartitionSpec the trainer gave it."""
     import chip_smoke
+    import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec
 
     import paddle_tpu as paddle
     from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.io.prefetch import batch_shardings
     from paddle_tpu.models import GPT, gpt_1p3b
 
-    mesh_mod.build_mesh(devices=jax.devices()[:1])
+    n = int(np.prod(list(axes.values()) or [1]))
+    cpu = mesh_mod.build_mesh(devices=jax.devices()[:n], **axes)
     paddle.seed(0)
     model = GPT(gpt_1p3b(max_seq_len=seq, num_layers=layers,
                          remat_policy="full"))
     model.bfloat16()
-    trainer = chip_smoke.build_trainer(model)
-    chip = _chip_mesh(topo)
+    trainer = chip_smoke.build_trainer(model, cpu)
+    chip = Mesh(np.asarray(topo.devices[:n]).reshape(cpu.devices.shape),
+                cpu.axis_names)
     trainer.mesh = chip
     mesh_mod.set_mesh(chip)
-    rep = NamedSharding(chip, PartitionSpec())
 
-    def shapes(tree):
-        return jax.tree_util.tree_map(
-            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=rep),
-            tree)
-
-    state = shapes((trainer.params, trainer.opt_state, trainer.gt_state,
-                    trainer.consts))
-    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=rep)
-    args = state + (jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
-                    {"input_ids": ids, "labels": ids})
+    state = jax.tree_util.tree_map(
+        lambda v: jax.ShapeDtypeStruct(
+            v.shape, v.dtype, sharding=NamedSharding(chip, v.sharding.spec)),
+        (trainer.params, trainer.opt_state, trainer.gt_state,
+         trainer.consts))
+    ids = np.zeros((batch, seq), np.int32)
+    tokens = jax.tree_util.tree_map(
+        lambda v, sh: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sh),
+        {"input_ids": ids, "labels": ids},
+        batch_shardings({"input_ids": ids, "labels": ids}, chip))
+    lr = jax.ShapeDtypeStruct((), jnp.float32,
+                              sharding=NamedSharding(chip, PartitionSpec()))
+    args = state + (lr, tokens)
     in_sh = jax.tree_util.tree_map(lambda s: s.sharding, args)
     return trainer._build(True, in_shardings=in_sh).lower(*args).compile()
 
@@ -186,6 +186,16 @@ def test_train_step_two_layers(topo, as_tpu):
     # plus the cross-entropy pair
     assert pallas_calls(compiled) >= 2 * 3 + 2
     assert device_bytes(compiled) < V5E_HBM
+
+
+def test_sharded_train_step_two_layers(topo, as_tpu):
+    """chip_smoke.py --chips 4: bs4 on fsdp=2 x tp=2. GSPMD cannot partition
+    a Mosaic kernel, so every kernel has to arrive inside a shard_map."""
+    compiled = compile_train_step(topo, layers=2, batch=4, fsdp=2, tp=2)
+    text = compiled.as_text()
+    assert pallas_calls(compiled) >= 2 * 3 + 2
+    assert "all-gather" in text and "all-reduce" in text
+    assert device_bytes(compiled) < V5E_HBM             # bytes on each device
 
 
 @pytest.mark.slow

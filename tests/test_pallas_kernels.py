@@ -256,3 +256,83 @@ def test_paged_attention_kernel_path(monkeypatch):
     out_r = P.paged_attention(q, k_pages, v_pages, table, lens, use_kernel=False)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=2e-5, atol=2e-5)
+
+
+# -- more than one device: GSPMD cannot partition a Mosaic kernel, so the
+# train step's kernels run once per device through shard_map (ops/_per_device)
+
+@pytest.fixture
+def sharded_mesh():
+    from paddle_tpu.distributed import build_mesh
+    from paddle_tpu.distributed import mesh as mesh_mod
+    mesh = build_mesh(fsdp=2, tp=2, devices=jax.devices()[:4])
+    yield mesh
+    mesh_mod.set_mesh(None)
+
+
+def _shard_maps(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count("shard_map")
+
+
+def test_flash_maps_over_batch_and_heads(sharded_mesh):
+    rng = np.random.RandomState(2)
+    B, L, H, D = 2, 256, 2, 64
+    q, k, v = (jnp.asarray(rng.randn(B, L, H, D), jnp.float32) for _ in range(3))
+
+    def loss(flash):
+        return lambda q, k, v: jnp.sum(flash(q, k, v) ** 2)
+    mapped = loss(lambda q, k, v: _flash(q, k, v, True, 0.125))
+    ref = loss(lambda q, k, v: mha_reference(q, k, v, causal=True))
+    grad = jax.jit(jax.value_and_grad(mapped, argnums=(0, 1, 2)))
+    assert _shard_maps(grad, q, k, v) == 2              # forward, backward
+    (l1, g1), (l2, g2) = grad(q, k, v), jax.value_and_grad(
+        ref, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+def test_flash_dropout_seed_differs_per_shard(sharded_mesh):
+    from paddle_tpu.ops.attention import _fwd_lse
+    rng = np.random.RandomState(3)
+    B, L, H, D = 2, 128, 2, 64
+    row = jnp.asarray(rng.randn(1, L, 1, D), jnp.float32)
+    q = k = v = jnp.broadcast_to(row, (B, L, H, D))     # identical rows, heads
+    d = jnp.zeros((1, 1), jnp.float32)
+    cfg = (True, 0.125, 0.5, False, False, False, False, False)
+    out, _ = jax.jit(lambda q, k, v: _fwd_lse(q, k, v, d, d, d + 7.0, cfg))(
+        q, k, v)
+    out = np.asarray(out)
+    assert not np.allclose(out[0, :, 0], out[1, :, 0])  # fsdp shards
+    assert not np.allclose(out[0, :, 0], out[0, :, 1])  # tp shards
+
+
+def test_layer_norm_maps_over_rows(sharded_mesh):
+    rng = np.random.RandomState(4)
+    x = jnp.asarray(rng.randn(4, 16, 256), jnp.float32)
+    w, b = (jnp.asarray(rng.randn(256), jnp.float32) for _ in range(2))
+    fn = jax.jit(lambda x, w, b: fused_layer_norm(x, w, b))
+    assert _shard_maps(fn, x, w, b) == 1
+    np.testing.assert_allclose(np.asarray(fn(x, w, b)),
+                               np.asarray(_ln_ref(x, w, b, 1e-5)), atol=1e-5)
+    rms = jax.jit(lambda x, w: fused_rms_norm(x, w))
+    assert _shard_maps(rms, x, w) == 1
+    np.testing.assert_allclose(np.asarray(rms(x, w)),
+                               np.asarray(_rms_ref(x, w, 1e-6)), atol=1e-5)
+
+
+def test_cross_entropy_maps_over_row_blocks(sharded_mesh):
+    from paddle_tpu.ops.fused_ops import (_xent_ref,
+                                          fused_softmax_cross_entropy)
+    rng = np.random.RandomState(5)
+    n, v = 512, 256
+    logits = jnp.asarray(rng.randn(n, v), jnp.float32)
+    labels = jnp.asarray(rng.randint(0, v, n), jnp.int32)
+    grad = jax.jit(jax.value_and_grad(
+        lambda lg: jnp.mean(fused_softmax_cross_entropy(lg, labels))))
+    assert _shard_maps(grad, logits) == 2
+    (l1, g1) = grad(logits)
+    (l2, g2) = jax.value_and_grad(
+        lambda lg: jnp.mean(_xent_ref(lg, labels)))(logits)
+    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-6)
