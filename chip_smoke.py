@@ -22,6 +22,7 @@ run, not a benchmark.
 import argparse
 import gc
 import json
+import math
 import statistics
 import sys
 import time
@@ -36,6 +37,8 @@ LOGIT_TOL = 0.5
 # two bf16 runs of the same batch on different shardings reduce in different
 # orders; the loss is a mean over thousands of fp32 rows
 LOSS_TOL = 0.05
+TRAIN_STEPS = 6
+SHARDED_BATCH, SHARDED_STEPS = 4, 2
 
 
 def emit(obj):
@@ -44,12 +47,10 @@ def emit(obj):
 
 def sizes(tiny):
     if tiny:
-        return dict(model="gpt_tiny", seq=128, batch=2, steps=6,
-                    requests=4, max_new=8, prompt=(8, 48), slots=4,
-                    sharded_batch=4, sharded_steps=2)
-    return dict(model="gpt_1p3b", seq=1024, batch=8, steps=6,
-                requests=8, max_new=32, prompt=(32, 512), slots=8,
-                sharded_batch=4, sharded_steps=2)
+        return dict(model="gpt_tiny", seq=128, batch=2, requests=4,
+                    max_new=8, prompt=(8, 48), slots=4)
+    return dict(model="gpt_1p3b", seq=1024, batch=8, requests=8,
+                max_new=32, prompt=(32, 512), slots=8)
 
 
 def build_model(sz, seed):
@@ -108,14 +109,13 @@ def device_bytes(dev):
 
 
 def finite(xs):
-    import math
     return all(math.isfinite(x) for x in xs)
 
 
 def phase_train(model, sz, seed, dev):
     trainer = build_trainer(model)
     batch = token_batch(model, sz["batch"], sz["seq"], seed)
-    losses, secs = timed_steps(trainer, batch, sz["steps"])
+    losses, secs = timed_steps(trainer, batch, TRAIN_STEPS)
     # the program step() dispatched, compiled again (from the cache when the
     # backend keeps one) for its text and its memory
     compiled = trainer.lower_step(batch).compile()
@@ -260,8 +260,8 @@ def phase_sharded(sz, seed, dev):
         mesh = build_mesh(**kw)
         model = build_model(sz, seed)
         trainer = build_trainer(model, mesh)
-        batch = token_batch(model, sz["sharded_batch"], sz["seq"], seed)
-        losses, secs = timed_steps(trainer, batch, sz["sharded_steps"])
+        batch = token_batch(model, SHARDED_BATCH, sz["seq"], seed)
+        losses, secs = timed_steps(trainer, batch, SHARDED_STEPS)
         state = (trainer.params, trainer.opt_state)
         held, spread = placement(state)
         split = sum(not leaf.sharding.is_fully_replicated
@@ -286,8 +286,8 @@ def phase_sharded(sz, seed, dev):
             < 0.02 and max(shares.values()) < 0.35,
     }
     emit({"phase": "sharded_train", "model": sz["model"],
-          "mesh": {"fsdp": 2, "tp": 2}, "batch": sz["sharded_batch"],
-          "seq": sz["seq"], "steps": sz["sharded_steps"],
+          "mesh": {"fsdp": 2, "tp": 2}, "batch": SHARDED_BATCH,
+          "seq": sz["seq"], "steps": SHARDED_STEPS,
           "losses": sharded["losses"], "losses_one_device": single["losses"],
           "loss_tolerance": LOSS_TOL,
           "split_leaves": sharded["split_leaves"],

@@ -8,9 +8,8 @@ rows, attention heads) are instead run once per device through
 unmentioned is gathered by the compiler.
 """
 import jax
-from jax.sharding import PartitionSpec as P
 
-__all__ = ["kernel_mesh", "dim_axes", "per_device", "BATCH_AXES", "P"]
+__all__ = ["kernel_mesh", "dim_axes", "BATCH_AXES"]
 
 BATCH_AXES = ("dp", "fsdp")
 
@@ -29,17 +28,10 @@ def kernel_mesh():
 
 
 def dim_axes(mesh, size, axes):
-    """Those of `axes` that are larger than one and together divide `size`:
-    the PartitionSpec entry for a dim of that size (None when none do)."""
-    kept, n = [], 1
-    for a in axes:
-        s = mesh.shape.get(a, 1)
-        if s > 1 and size % (n * s) == 0:
-            kept.append(a)
-            n *= s
-    return tuple(kept) or None
+    """Those of `axes` that are larger than one and together divide `size`,
+    as a tuple: the PartitionSpec entry for a dim of that size (None when
+    none do). The rule is `sharding_utils.feasible_spec`'s."""
+    from ..distributed.sharding_utils import feasible_spec
 
-
-def per_device(fn, mesh, in_specs, out_specs):
-    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+    kept = feasible_spec((size,), (tuple(axes),), mesh)[0]
+    return (kept,) if isinstance(kept, str) else kept

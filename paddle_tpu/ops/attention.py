@@ -24,11 +24,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 import numpy as np
 
 from ..framework.core import Tensor, apply_op
 from ._fallback import kernel_fallback
-from ._per_device import BATCH_AXES, P, dim_axes, kernel_mesh, per_device
+from ._per_device import BATCH_AXES, dim_axes, kernel_mesh
 
 __all__ = ["flash_attention", "flash_attention_available", "mha_reference"]
 
@@ -583,10 +584,11 @@ def _fwd_lse(q, k, v, kvb, fb, seed, cfg):
     if mesh is None:
         return _fwd_lse_impl(q, k, v, kvb, fb, seed, cfg)
     blhd, lse, kvb_s, fb_s, axes = _flash_shard_specs(mesh, cfg, q, k)
-    return per_device(
+    return jax.shard_map(
         lambda q, k, v, kvb, fb, seed: _fwd_lse_impl(
             q, k, v, kvb, fb, _shard_seed(seed, cfg, axes), cfg),
-        mesh, (blhd, blhd, blhd, kvb_s, fb_s, P()), (blhd, lse),
+        mesh=mesh, in_specs=(blhd, blhd, blhd, kvb_s, fb_s, P()),
+        out_specs=(blhd, lse), check_vma=False,
     )(q, k, v, kvb, fb, seed)
 
 
@@ -595,11 +597,12 @@ def _bwd(q, k, v, lse, g, out, kvb, fb, seed, cfg):
     if mesh is None:
         return _bwd_impl(q, k, v, lse, g, out, kvb, fb, seed, cfg)
     blhd, lse_s, kvb_s, fb_s, axes = _flash_shard_specs(mesh, cfg, q, k)
-    return per_device(
+    return jax.shard_map(
         lambda q, k, v, lse, g, out, kvb, fb, seed: _bwd_impl(
             q, k, v, lse, g, out, kvb, fb, _shard_seed(seed, cfg, axes), cfg),
-        mesh, (blhd, blhd, blhd, lse_s, blhd, blhd, kvb_s, fb_s, P()),
-        (blhd, blhd, blhd),
+        mesh=mesh,
+        in_specs=(blhd, blhd, blhd, lse_s, blhd, blhd, kvb_s, fb_s, P()),
+        out_specs=(blhd, blhd, blhd), check_vma=False,
     )(q, k, v, lse, g, out, kvb, fb, seed)
 
 

@@ -17,10 +17,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 import numpy as np
 
 from ._fallback import kernel_fallback
-from ._per_device import BATCH_AXES, P, dim_axes, kernel_mesh, per_device
+from ._per_device import BATCH_AXES, dim_axes, kernel_mesh
 
 __all__ = ["fused_softmax_cross_entropy", "fused_adamw",
            "fused_dropout_residual_layer_norm"]
@@ -131,9 +132,10 @@ def _xent_fwd_impl(logits, labels, interpret=None):
     mesh = kernel_mesh()
     if mesh is not None:
         rows = _xent_rows(mesh, logits.shape[0])
-        return per_device(
-            lambda lg, lab: _xent_fwd_impl(lg, lab, interpret),
-            mesh, (rows, rows), (rows, rows))(logits, labels)
+        return jax.shard_map(
+            lambda lg, lab: _xent_fwd_impl(lg, lab, interpret), mesh=mesh,
+            in_specs=(rows, rows), out_specs=(rows, rows),
+            check_vma=False)(logits, labels)
     if interpret is None:
         interpret = _interpret_default()
     from jax.experimental.pallas import tpu as pltpu
@@ -183,10 +185,11 @@ def _xent_bwd_impl(logits, labels, lse, g, interpret=None):
     mesh = kernel_mesh()
     if mesh is not None:
         rows = _xent_rows(mesh, logits.shape[0])
-        return per_device(
+        return jax.shard_map(
             lambda lg, lab, lse, g: _xent_bwd_impl(lg, lab, lse, g,
                                                    interpret),
-            mesh, (rows, rows, rows, rows), rows)(logits, labels, lse, g)
+            mesh=mesh, in_specs=(rows, rows, rows, rows), out_specs=rows,
+            check_vma=False)(logits, labels, lse, g)
     if interpret is None:
         interpret = _interpret_default()
     n, v = logits.shape

@@ -10,9 +10,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ._fallback import kernel_fallback
-from ._per_device import BATCH_AXES, P, dim_axes, kernel_mesh, per_device
+from ._per_device import BATCH_AXES, dim_axes, kernel_mesh
 
 __all__ = ["fused_layer_norm", "fused_rms_norm",
            "fused_layer_norm_op", "fused_rms_norm_op"]
@@ -70,8 +71,10 @@ def _ln_fwd_impl(x, weight, bias, eps=1e-5):
     mesh = kernel_mesh()
     if mesh is not None:
         rows = _rows_spec(mesh, x)
-        return per_device(lambda x, w, b: _ln_fwd_impl(x, w, b, eps),
-                          mesh, (rows, P(), P()), rows)(x, weight, bias)
+        return jax.shard_map(
+            lambda x, w, b: _ln_fwd_impl(x, w, b, eps), mesh=mesh,
+            in_specs=(rows, P(), P()), out_specs=rows,
+            check_vma=False)(x, weight, bias)
     h = x.shape[-1]
     flat = x.reshape(-1, h)
     n = flat.shape[0]
@@ -113,8 +116,10 @@ def _rms_fwd_impl(x, weight, eps=1e-6):
     mesh = kernel_mesh()
     if mesh is not None:
         rows = _rows_spec(mesh, x)
-        return per_device(lambda x, w: _rms_fwd_impl(x, w, eps),
-                          mesh, (rows, P()), rows)(x, weight)
+        return jax.shard_map(
+            lambda x, w: _rms_fwd_impl(x, w, eps), mesh=mesh,
+            in_specs=(rows, P()), out_specs=rows,
+            check_vma=False)(x, weight)
     h = x.shape[-1]
     flat = x.reshape(-1, h)
     n = flat.shape[0]

@@ -207,15 +207,16 @@ def test_train_step_24_layers(topo, as_tpu):
 
 # ------------------------------------------------------------ serving programs
 
-def _serve_decoder(layers):
+@pytest.fixture(scope="module")
+def serve_decoder():
     """chip_smoke.py's decoder (constructor defaults, 8 slots x 1,024
-    tokens) at gpt_1p3b width, built on the CPU."""
+    tokens) at gpt_1p3b width and two layers, built on the CPU."""
     import paddle_tpu as paddle
     from paddle_tpu.models import GPT, gpt_1p3b
     from paddle_tpu.serving.decoder import PagedGPTDecoder
 
     paddle.seed(0)
-    model = GPT(gpt_1p3b(max_seq_len=S, num_layers=layers))
+    model = GPT(gpt_1p3b(max_seq_len=S, num_layers=2))
     model.bfloat16()
     model.eval()
     return PagedGPTDecoder(model, num_pages=B * (S // 16) + 2, page_size=16,
@@ -231,10 +232,10 @@ SMOKE_HORIZONS = [(1, 1024, 16), (1, 1024, 32), (1, 512, 32), (1, 256, 64),
 
 @pytest.mark.slow
 @pytest.mark.parametrize("k,t,width", SMOKE_HORIZONS)
-def test_serve_horizon_two_layers(one_chip, k, t, width):
+def test_serve_horizon_two_layers(one_chip, serve_decoder, k, t, width):
     import functools
 
-    d = _serve_decoder(layers=2)
+    d = serve_decoder
 
     def shapes(tree):
         return jax.tree_util.tree_map(
