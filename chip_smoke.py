@@ -6,9 +6,10 @@
 
 One process and no children. GPT-1.3B at its published width and depth,
 random weights from `--seed`, through the entry points a user calls:
-`Trainer.step` for six steps, then `PagedGPTDecoder` behind a
-`ContinuousBatchingEngine` for eight requests, each checked against the
-plain `GPT` forward. One JSON line per phase, and a last line
+`Trainer.step` for six steps, the trained weights kept on the host as a
+checkpoint, then `PagedGPTDecoder` behind a `ContinuousBatchingEngine` for
+eight requests, each checked against the plain `GPT` forward. One JSON line
+per phase, and a last line
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 
@@ -65,6 +66,17 @@ def build_model(sz, seed):
     return model
 
 
+def load_model(sz, seed, checkpoint):
+    """A fresh model holding the trained weights (host arrays by name)."""
+    model = build_model(sz, seed)
+    missing, unexpected = model.set_state_dict(checkpoint)
+    if missing or unexpected:
+        raise RuntimeError(f"checkpoint does not fit the model: missing "
+                           f"{missing}, unexpected {unexpected}")
+    model.eval()
+    return model
+
+
 def build_trainer(model, mesh=None):
     import paddle_tpu as paddle
     from paddle_tpu.distributed.trainer import Trainer
@@ -112,7 +124,12 @@ def finite(xs):
     return all(math.isfinite(x) for x in xs)
 
 
-def phase_train(model, sz, seed, dev):
+def phase_train(sz, seed, dev):
+    """Six steps; returns the gates and the trained weights on the host."""
+    import numpy as np
+
+    model = build_model(sz, seed)
+    cfg = model.cfg
     trainer = build_trainer(model)
     batch = token_batch(model, sz["batch"], sz["seq"], seed)
     losses, secs = timed_steps(trainer, batch, TRAIN_STEPS)
@@ -122,7 +139,9 @@ def phase_train(model, sz, seed, dev):
     pallas_calls = compiled.as_text().count("tpu_custom_call")
     mem = compiled.memory_analysis()
     trainer.sync_to_model()
-    del trainer
+    checkpoint = {name: np.asarray(t._value)
+                  for name, t in model.state_dict().items()}
+    del trainer, model
     gc.collect()
     gates = {
         "losses_finite": finite(losses),
@@ -132,7 +151,7 @@ def phase_train(model, sz, seed, dev):
         "pallas_calls": pallas_calls > 0 or dev.platform != "tpu",
     }
     emit({"phase": "train", "model": sz["model"],
-          "layers": model.cfg.num_layers, "hidden": model.cfg.hidden_size,
+          "layers": cfg.num_layers, "hidden": cfg.hidden_size,
           "batch": sz["batch"], "seq": sz["seq"], "remat_policy": "full",
           "steps": len(losses), "losses": losses,
           "pallas_calls": pallas_calls,
@@ -146,7 +165,7 @@ def phase_train(model, sz, seed, dev):
               "aliased": mem.alias_size_in_bytes} if mem else None,
           "device_bytes": device_bytes(dev),
           "device": device_dict(), "gates": gates})
-    return gates
+    return gates, checkpoint
 
 
 def serve_once(decoder, prompts, max_new):
@@ -187,28 +206,40 @@ def reference_margins(model, prompts, streams, seq):
     return worst
 
 
-def phase_serve(model, sz, seed, dev):
+def phase_serve(checkpoint, sz, seed, dev):
+    """Serve from the checkpoint, then check the streams against the plain
+    model. The decoder keeps its own stacked copy of the weights, so the
+    Layer is dropped while it serves and loaded again for the reference:
+    the largest horizon's program needs 12 GiB of the chip's 16."""
     import numpy as np
 
     from paddle_tpu.serving.decoder import PagedGPTDecoder
 
-    model.eval()
+    model = load_model(sz, seed, checkpoint)
+    cfg = model.cfg
     page = 16
     pages_per_seq = sz["seq"] // page
     decoder = PagedGPTDecoder(model, num_pages=sz["slots"] * pages_per_seq + 2,
                               page_size=page, max_batch=sz["slots"])
+    del model
+    gc.collect()
     rng = np.random.RandomState(seed + 1)
     lo, hi = sz["prompt"]
     lengths = rng.randint(lo, hi + 1, sz["requests"])
     lengths[0], lengths[-1] = lo, hi
-    vocab = model.cfg.vocab_size
+    vocab = cfg.vocab_size
     prompts = [rng.randint(0, vocab, int(n)).tolist() for n in lengths]
 
     streams, cold_s, _, _ = serve_once(decoder, prompts, sz["max_new"])
     again, warm_s, horizon_s, stats = serve_once(decoder, prompts,
                                                  sz["max_new"])
     alone, _, _, _ = serve_once(decoder, prompts[:1], sz["max_new"])
-    worst = reference_margins(model, prompts, streams, sz["seq"])
+    pool_pages = decoder.num_pages
+    serving_bytes = device_bytes(dev)
+    del decoder
+    gc.collect()
+    worst = reference_margins(load_model(sz, seed, checkpoint), prompts,
+                              streams, sz["seq"])
     gates = {
         "all_finished": all(len(s) == sz["max_new"] for s in streams),
         "tokens_in_vocab": all(0 <= t < vocab for s in streams for t in s),
@@ -216,8 +247,8 @@ def phase_serve(model, sz, seed, dev):
         "reference_margin": worst <= LOGIT_TOL,
     }
     emit({"phase": "serve", "model": sz["model"],
-          "layers": model.cfg.num_layers, "hidden": model.cfg.hidden_size,
-          "slots": sz["slots"], "pool_pages": decoder.num_pages,
+          "layers": cfg.num_layers, "hidden": cfg.hidden_size,
+          "slots": sz["slots"], "pool_pages": pool_pages,
           "page_size": page, "requests": len(prompts),
           "prompt_tokens": [int(n) for n in lengths],
           "generated_tokens": [len(s) for s in streams],
@@ -226,7 +257,7 @@ def phase_serve(model, sz, seed, dev):
           "cold_run_s": cold_s, "warm_run_s": warm_s,
           "compile_s": cold_s - warm_s,
           "horizon_ms_median": 1e3 * statistics.median(horizon_s),
-          "engine": stats, "device_bytes": device_bytes(dev),
+          "engine": stats, "device_bytes": serving_bytes,
           "device": device_dict(), "gates": gates})
     return gates
 
@@ -342,11 +373,10 @@ def main():
         if args.chips == 4:
             gates = phase_sharded(sz, args.seed, dev)
         else:
-            model = build_model(sz, args.seed)
-            gates = {f"train.{k}": v for k, v in
-                     phase_train(model, sz, args.seed, dev).items()}
-            gates.update({f"serve.{k}": v for k, v in
-                          phase_serve(model, sz, args.seed, dev).items()})
+            train, checkpoint = phase_train(sz, args.seed, dev)
+            serve = phase_serve(checkpoint, sz, args.seed, dev)
+            gates = {f"train.{k}": v for k, v in train.items()}
+            gates.update({f"serve.{k}": v for k, v in serve.items()})
     except BaseException as e:
         fail(f"{type(e).__name__}: {e}")
         raise
