@@ -207,35 +207,29 @@ def test_train_step_24_layers(topo, as_tpu):
 
 # ------------------------------------------------------------ serving programs
 
-@pytest.fixture(scope="module")
-def serve_decoder():
+def _serve_decoder(layers):
     """chip_smoke.py's decoder (constructor defaults, 8 slots x 1,024
-    tokens) at gpt_1p3b width and two layers, built on the CPU."""
+    tokens) at gpt_1p3b width, built on the CPU."""
     import paddle_tpu as paddle
     from paddle_tpu.models import GPT, gpt_1p3b
     from paddle_tpu.serving.decoder import PagedGPTDecoder
 
     paddle.seed(0)
-    model = GPT(gpt_1p3b(max_seq_len=S, num_layers=2))
+    model = GPT(gpt_1p3b(max_seq_len=S, num_layers=layers))
     model.bfloat16()
     model.eval()
     return PagedGPTDecoder(model, num_pages=B * (S // 16) + 2, page_size=16,
                            max_batch=B)
 
 
-# (k, t_tokens, table width) of every packed ragged horizon the smoke's eight
-# prompts (32-512 tokens) and its lone request dispatch at gpt_1p3b, where
-# the scheduler prices one tick per horizon and 128 prompt tokens per slot
-SMOKE_HORIZONS = [(1, 1024, 16), (1, 1024, 32), (1, 512, 32), (1, 256, 64),
-                  (1, 8, 64), (1, 32, 4), (1, 8, 4), (1, 8, 8)]
+@pytest.fixture(scope="module")
+def two_layer_decoder():
+    return _serve_decoder(2)
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("k,t,width", SMOKE_HORIZONS)
-def test_serve_horizon_two_layers(one_chip, serve_decoder, k, t, width):
+def compile_horizon(one_chip, d, k, t, width):
+    """One packed ragged horizon of decoder `d`, as `ragged_multi` jits it."""
     import functools
-
-    d = serve_decoder
 
     def shapes(tree):
         return jax.tree_util.tree_map(
@@ -247,19 +241,34 @@ def test_serve_horizon_two_layers(one_chip, serve_decoder, k, t, width):
     def flags(*shape):
         return spec(one_chip, shape, jnp.bool_)
 
-    compiled = jax.jit(
+    return jax.jit(
         functools.partial(d._packed_multi_step, k=k, t=t),
         donate_argnums=(1, 2),
     ).lower(shapes(d._w()), shapes(d.k_pages), shapes(d.v_pages),
             i32(B), i32(B), i32(B, width), i32(B), flags(B), i32(B), i32(),
             i32(B, d.pend_capacity), i32(B), i32()).compile()
-    # what a 24-layer decoder keeps on the device beside this program: the
-    # GPT it was built from, 22 more layers of weights and pool, and the
-    # other horizons' programs (each carries the embedding and the head)
-    per_layer = sum(v.nbytes // 2 for v in jax.tree_util.tree_leaves(
-        (d._w(), d.k_pages, d.v_pages)))
-    model_bytes = 2 * 24 * 12 * HIDDEN * HIDDEN + 2 * VOCAB * HIDDEN
+
+
+# (k, t_tokens, table width) of every packed ragged horizon the smoke's eight
+# prompts (32-512 tokens) and its lone request dispatch at gpt_1p3b, where
+# the scheduler prices one tick per horizon and 128 prompt tokens per slot
+SMOKE_HORIZONS = [(1, 1024, 16), (1, 1024, 32), (1, 512, 32), (1, 256, 64),
+                  (1, 8, 64), (1, 32, 4), (1, 8, 4), (1, 8, 8)]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("k,t,width", SMOKE_HORIZONS)
+def test_serve_horizon_two_layers(one_chip, two_layer_decoder, k, t, width):
+    compiled = compile_horizon(one_chip, two_layer_decoder, k, t, width)
+    assert device_bytes(compiled) < V5E_HBM
+
+
+@pytest.mark.slow
+def test_largest_serve_horizon_24_layers(one_chip):
+    """The one that decides whether the smoke's serve phase fits: weights,
+    pool and 7.9 GiB of temporaries, beside the other horizons' programs
+    (each carries the embedding and the head as constants)."""
+    compiled = compile_horizon(one_chip, _serve_decoder(24), 1, 1024, 32)
     others = (len(SMOKE_HORIZONS) - 1) * \
         compiled.memory_analysis().generated_code_size_in_bytes
-    assert (device_bytes(compiled) + 22 * per_layer + model_bytes + others
-            < V5E_HBM)
+    assert device_bytes(compiled) + others < V5E_HBM
