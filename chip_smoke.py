@@ -28,13 +28,15 @@ import statistics
 import sys
 import time
 
-# at this init a logit row has a spread of about 0.9, its maximum sits near
-# +3.6 and a token picked for any wrong reason lies about that far below it.
-# Two bf16 computations of one row (the decoder's paged attention and the
-# GPT forward's flash attention round differently through 24 layers) differ
-# by a few hundredths, so the decoder's pick may be the reference's runner-up
-# but never lies this far under its maximum
-LOGIT_TOL = 0.5
+# how far a generated token's logit may lie under the maximum of its row in
+# the reference, as a share of that row's (maximum - mean). At this init a
+# row's spread is about 0.9 and its maximum sits near +3.6; a token picked
+# for any wrong reason lies about a whole (maximum - mean) below it. Two bf16
+# computations of one row (the decoder's paged attention and the GPT
+# forward's flash attention round differently through 24 layers) differ by a
+# percent or two of the logits' size, so the decoder's pick may be the
+# reference's runner-up but never lies this far under its maximum
+LOGIT_TOL = 0.15
 # two bf16 runs of the same batch on different shardings reduce in different
 # orders; the loss is a mean over thousands of fp32 rows
 LOSS_TOL = 0.05
@@ -182,7 +184,8 @@ def serve_once(decoder, prompts, max_new):
 
 def reference_margins(model, prompts, streams, seq):
     """For every generated token, how far its logit lies under the maximum
-    of its position in the plain GPT forward over prompt + generated."""
+    of its position in the plain GPT forward over prompt + generated: the
+    worst gap, and the worst gap as a share of its row's (maximum - mean)."""
     import numpy as np
 
     import jax
@@ -193,7 +196,7 @@ def reference_margins(model, prompts, streams, seq):
     forward = jax.jit(lambda params, ids: functional_call(
         model, params, paddle.Tensor(ids))._value)
     params = state_pytree(model)
-    worst = 0.0
+    worst = worst_share = 0.0
     for prompt, stream in zip(prompts, streams):
         ids = np.zeros((1, seq), np.int32)
         n, g = len(prompt), len(stream)
@@ -201,9 +204,12 @@ def reference_margins(model, prompts, streams, seq):
         ids[0, n:n + g] = stream
         logits = np.asarray(forward(params, ids)[0], np.float32)
         rows = logits[n - 1:n + g - 1]        # row i predicts token i + 1
-        gap = rows.max(axis=-1) - rows[np.arange(g), stream]
+        top = rows.max(axis=-1)
+        gap = top - rows[np.arange(g), stream]
         worst = max(worst, float(gap.max()))
-    return worst
+        worst_share = max(worst_share,
+                          float((gap / (top - rows.mean(axis=-1))).max()))
+    return worst, worst_share
 
 
 def phase_serve(checkpoint, sz, seed, dev):
@@ -238,13 +244,13 @@ def phase_serve(checkpoint, sz, seed, dev):
     serving_bytes = device_bytes(dev)
     del decoder
     gc.collect()
-    worst = reference_margins(load_model(sz, seed, checkpoint), prompts,
-                              streams, sz["seq"])
+    worst, worst_share = reference_margins(
+        load_model(sz, seed, checkpoint), prompts, streams, sz["seq"])
     gates = {
         "all_finished": all(len(s) == sz["max_new"] for s in streams),
         "tokens_in_vocab": all(0 <= t < vocab for s in streams for t in s),
         "repeat_identical": again == streams,
-        "reference_margin": worst <= LOGIT_TOL,
+        "reference_margin": worst_share <= LOGIT_TOL,
     }
     emit({"phase": "serve", "model": sz["model"],
           "layers": cfg.num_layers, "hidden": cfg.hidden_size,
@@ -252,7 +258,9 @@ def phase_serve(checkpoint, sz, seed, dev):
           "page_size": page, "requests": len(prompts),
           "prompt_tokens": [int(n) for n in lengths],
           "generated_tokens": [len(s) for s in streams],
-          "worst_logit_margin": worst, "logit_tolerance": LOGIT_TOL,
+          "worst_logit_margin": worst,
+          "worst_logit_margin_share": worst_share,
+          "logit_tolerance_share": LOGIT_TOL,
           "alone_equals_batched": alone[0] == streams[0],
           "cold_run_s": cold_s, "warm_run_s": warm_s,
           "compile_s": cold_s - warm_s,

@@ -179,3 +179,32 @@ def test_optimizer_slots_are_sharded_like_their_parameters():
             split += not slot.sharding.is_fully_replicated
     assert split > 0
     assert trainer.opt_state["step"].sharding.is_fully_replicated
+
+
+def test_reference_gate_tells_a_wrong_stream_from_a_right_one():
+    """The serve gate's measure: the model's own greedy continuation lies at
+    its rows' maxima (share 0), random tokens lie about a whole
+    (maximum - mean) below them."""
+    import numpy as np
+
+    sys.path.insert(0, ROOT)
+    import chip_smoke
+
+    sz = chip_smoke.sizes(tiny=True)
+    model = chip_smoke.build_model(sz, seed=0)
+    model.eval()
+    rng = np.random.RandomState(0)
+    prompt = rng.randint(0, model.cfg.vocab_size, 16).tolist()
+    greedy = []
+    for _ in range(4):
+        ids = np.asarray([prompt + greedy], np.int32)
+        logits = np.asarray(model(paddle_tpu.to_tensor(ids))._value[0, -1],
+                            np.float32)
+        greedy.append(int(logits.argmax()))
+    _, share = chip_smoke.reference_margins(model, [prompt], [greedy],
+                                            sz["seq"])
+    assert share <= chip_smoke.LOGIT_TOL
+    wrong = rng.randint(0, model.cfg.vocab_size, 4).tolist()
+    _, share = chip_smoke.reference_margins(model, [prompt], [wrong],
+                                            sz["seq"])
+    assert share > 3 * chip_smoke.LOGIT_TOL
