@@ -6,6 +6,8 @@ based on jax error-checking semantics — `check_numerics` inserts a device-side
 assert-like guard; `enable_check_nan_inf` flips a global that the Trainer and
 eager dispatch honor on loss/grads.
 """
+import statistics
+
 import jax
 import jax.numpy as jnp
 
@@ -33,11 +35,63 @@ def serving_stats():
     return _stats()
 
 
+def _horizon_summary(events):
+    """(programs, schedule entries, pad ledger) from an engine's horizon
+    records (`serve_schedule()`, always on: no recorder needed). Per
+    `program` (the name its compiled program carries in a trace) the
+    count of horizons, how many were a first use (a compile or a cache
+    load inside) and the median tick — sync to sync: from the later of
+    the previous horizon's `t_fetched` and its own `t_round` to its own
+    `t_fetched`, over its k ticks. For the schedule: the median round of
+    host work (`admit_s + plan_s + dispatch_s + book_s`), the deepest
+    queue an admission pass left behind and the median submit-to-admit
+    wait. Which program is slow, how much host work a round hides behind
+    the device, whether requests queue: what a device trace alone cannot
+    answer. The pad ledger is the recent-horizon view of
+    `stats.pad_fraction`: how much of the dispatched token layout was
+    padding."""
+    by_program, host, depth, waits = {}, [], [], []
+    disp = padded = 0
+    prev_fetched = None
+    for ev in events:
+        if ev.get("kind") != "horizon" or "t_fetched" not in ev:
+            continue
+        start = ev["t_round"] if prev_fetched is None \
+            else max(prev_fetched, ev["t_round"])
+        prev_fetched = ev["t_fetched"]
+        ticks, first = by_program.setdefault(ev["program"], ([], []))
+        ticks.append((ev["t_fetched"] - start) / ev["k"])
+        first.append(bool(ev["first_use"]))
+        host.append(ev["admit_s"] + ev["plan_s"] + ev["dispatch_s"]
+                    + ev["book_s"])
+        depth.append(ev["queue_depth"])
+        waits.extend(ev["admit_waits_s"])
+        disp += ev["tokens_dispatched"]
+        padded += ev.get("tokens_padded") or 0
+    programs = [{"program": name, "n": len(ticks),
+                 "first_uses": sum(first),
+                 "tick_ms_p50": 1e3 * statistics.median(ticks)}
+                for name, (ticks, first) in sorted(by_program.items())]
+    schedule = {}
+    if host:
+        schedule = {"round_host_ms_p50": 1e3 * statistics.median(host),
+                    "queue_depth_max": max(depth),
+                    "queue_wait_ms_p50": (1e3 * statistics.median(waits)
+                                          if waits else None)}
+    pad = {"tokens_dispatched": disp, "tokens_padded": padded,
+           "pad_fraction": round(padded / disp, 4)} if disp else None
+    return programs, schedule, pad
+
+
 def serving_report(drift_factor=None, print_report=False):
     """Deep serving observability, one dict per live engine (sorted by
     engine name/id like `serving_stats`): the `ServeStats` summary,
     the recent scheduling-decision trace summarized (horizons,
-    prefill syncs, stalls), and — when the engine carries a flight
+    prefill syncs, stalls, the median round of host work, the deepest
+    queue and the median queue wait), per `program` the count and the
+    median sync-to-sync tick, and the pad ledger (`_horizon_summary`,
+    from the engine's always-on horizon records: no recorder
+    needed), and — when the engine carries a flight
     recorder (`ContinuousBatchingEngine(trace=...)`) — the rolling
     roofline-drift ledger per dispatch shape with its mispriced
     shapes flagged (`serving.trace.FlightRecorder.drift_report`; the
@@ -67,6 +121,12 @@ def serving_report(drift_factor=None, print_report=False):
                     ev.get("kind") == "prefill_sync"
                     and ev.get("decode_active", 0) > 0 for ev in events),
             }
+            programs, schedule, pad = _horizon_summary(events)
+            entry["schedule"].update(schedule)
+            if programs:
+                entry["programs"] = programs
+            if pad:
+                entry["pad"] = pad
         rec = getattr(eng, "trace", None)
         if rec is not None:
             drift = rec.drift_report(factor=drift_factor)
@@ -74,20 +134,6 @@ def serving_report(drift_factor=None, print_report=False):
             entry["drifting_shapes"] = [d["shape"] for d in drift
                                         if d["drifting"]]
             entry["trace_events"] = len(rec.events)
-            # pad ledger over the recorder's tick window: how much of
-            # the dispatched token layout was padding (the packed
-            # ragged layout's before/after evidence — the lifetime
-            # view lives in stats.pad_fraction; this is the recent-
-            # horizon view the tick records carry)
-            ticks = [ev for ev in rec.events if ev["kind"] == "tick"
-                     and ev.get("tokens_dispatched")]
-            disp = sum(ev["tokens_dispatched"] for ev in ticks)
-            if disp:
-                padded = sum(ev.get("tokens_padded") or 0
-                             for ev in ticks)
-                entry["pad"] = {
-                    "tokens_dispatched": disp, "tokens_padded": padded,
-                    "pad_fraction": round(padded / disp, 4)}
         report.append(entry)
     if print_report:
         for entry in report:
@@ -96,6 +142,10 @@ def serving_report(drift_factor=None, print_report=False):
             for key in ("stats", "schedule", "pad"):
                 if key in entry:
                     print(f"  {key}: {entry[key]}")
+            for pr in entry.get("programs", ()):
+                print(f"  program {pr['program']}: n={pr['n']} "
+                      f"(first uses {pr['first_uses']}) tick p50 "
+                      f"{pr['tick_ms_p50']:.3f} ms")
             for d in entry.get("drift", ()):
                 flag = "  << DRIFTING" if d["drifting"] else ""
                 print(f"  drift {d['shape']}: predicted "

@@ -19,6 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..framework.core import Tensor
 from ..nn.layer_base import functional_call, load_state_pytree
+from ..profiler import span
 from .mesh import get_mesh
 from .sharding_utils import plan_shardings
 
@@ -258,8 +259,8 @@ class Trainer:
         self._placed_multis = {}
         # FLIGHT RECORDER (serving.trace.FlightRecorder, shared schema
         # with the serving engines): off by default — attach_recorder
-        # turns step_multi horizons into tick records with predicted
-        # vs measured drift accounting. Every hook is a dead
+        # turns `step`s and step_multi horizons into tick records with
+        # predicted vs measured drift accounting. Every hook is a dead
         # `if self.recorder is not None` branch.
         self.recorder = None
         self._rec_predicted_step_s = None
@@ -270,8 +271,10 @@ class Trainer:
     def attach_recorder(self, recorder, predicted_step_s=None,
                         predicted_serial_step_s=None):
         """Attach a `serving.trace.FlightRecorder` (or True for a
-        default one): every `step_multi` horizon records a "train"
-        tick — N steps, measured dispatch-to-dispatch wall seconds,
+        default one): every `step_multi` horizon — and every `step`,
+        as a horizon of one under the shape ("step", 1) — records a
+        "train" tick — N steps, measured dispatch-to-dispatch wall
+        seconds,
         and (when `predicted_step_s` is given, normally
         `cost_model.roofline_step_time(...).step_s` or the schedule
         pass's overlap-aware `overlap_step_s`) the roofline-predicted
@@ -640,61 +643,69 @@ class Trainer:
         loss vector unfetched — drain it through a `LossBuffer` (vector
         appends are supported) so host contact stays at horizon
         boundaries."""
-        arrays, sig, horizon_sh = self.place_horizon(batches)
-        n = jax.tree_util.tree_leaves(arrays)[0].shape[0]
-        if lrs is None:
-            lrs = self._horizon_lrs(n)
-        else:
-            lrs = np.asarray(lrs, np.float32)
-            if lrs.shape != (n,):
-                raise ValueError(
-                    f"step_multi: lrs shape {lrs.shape} != ({n},)")
-            # parity with step(batch, lr=x), which advances the
-            # scheduler even under an explicit lr: N explicit-lr steps
-            # leave the scheduler N positions further along
-            sched = self.optimizer._lr_scheduler
-            if sched is not None:
-                for _ in range(int(n)):
-                    sched.step()
-        t0 = time.perf_counter() if self.recorder is not None else None
-        # a signature never dispatched before will compile inside this
-        # window — a pollution source the drift ledger must skip, like
-        # the first horizon (the memo is the compile's proxy: first
-        # call per signature pays the XLA compile)
-        warm_sig = sig in self._placed_multis
-        fn = self._placed_multi(sig, horizon_sh)
-        (self.params, self.opt_state, self.gt_state, self.consts,
-         losses) = fn(
-            self.params, self.opt_state, self.gt_state, self.consts,
-            jnp.asarray(lrs), arrays)
-        # horizon-aware step accounting: state()/load_state round-trip
-        # the TRUE device step count, not the host dispatch count
-        self._host_step += int(n)
-        if self.recorder is not None:
-            # dispatch is NON-blocking, so this call's own wall time is
-            # not the horizon's: in a steady-state loop the dispatch-to-
-            # dispatch gap is (the next dispatch blocks on the donated
-            # carry), so measure that. The FIRST horizon after attach or
-            # mark_recorder_idle() has no previous dispatch — its call
-            # wall is recorded but kept out of the drift ledger (cold
-            # compiles and host pauses are pollution, the same
-            # exclusion the serving engines apply to prefill windows)
-            now = time.perf_counter()
-            steady = self._rec_last_t is not None and warm_sig
-            # the tick's chrome slice must span the window it measured:
-            # steady ticks start at the PREVIOUS dispatch, not this one
-            start = self._rec_last_t if self._rec_last_t is not None \
-                else t0
-            measured = now - start
-            self._rec_last_t = now
-            pred = self._rec_predicted_step_s
-            serial = self._rec_predicted_serial_s
-            self.recorder.tick(
-                "train", ("train", int(n)), measured, ts=start,
-                predicted_s=(pred * int(n)) if pred else None,
-                predicted_serial_s=(serial * int(n)) if serial else None,
-                drift=steady, k=int(n), decode_rows=0, prefill_rows=0)
-        return losses
+        step = self._host_step
+        with span("trainer.step", step=step):
+            with span("trainer.place_batch", step=step):
+                arrays, sig, horizon_sh = self.place_horizon(batches)
+            n = jax.tree_util.tree_leaves(arrays)[0].shape[0]
+            if lrs is None:
+                lrs = self._horizon_lrs(n)
+            else:
+                lrs = np.asarray(lrs, np.float32)
+                if lrs.shape != (n,):
+                    raise ValueError(
+                        f"step_multi: lrs shape {lrs.shape} != ({n},)")
+                # parity with step(batch, lr=x), which advances the
+                # scheduler even under an explicit lr: N explicit-lr steps
+                # leave the scheduler N positions further along
+                sched = self.optimizer._lr_scheduler
+                if sched is not None:
+                    for _ in range(int(n)):
+                        sched.step()
+            t0 = time.perf_counter() if self.recorder is not None else None
+            # a signature never dispatched before will compile inside this
+            # window — a pollution source the drift ledger must skip, like
+            # the first horizon (the memo is the compile's proxy: first
+            # call per signature pays the XLA compile)
+            warm_sig = sig in self._placed_multis
+            fn = self._placed_multi(sig, horizon_sh)
+            with span("trainer.dispatch", step=step):
+                (self.params, self.opt_state, self.gt_state, self.consts,
+                 losses) = fn(
+                    self.params, self.opt_state, self.gt_state, self.consts,
+                    jnp.asarray(lrs), arrays)
+            # horizon-aware step accounting: state()/load_state round-trip
+            # the TRUE device step count, not the host dispatch count
+            self._host_step += int(n)
+            if self.recorder is not None:
+                self._record_tick(("train", int(n)), int(n), warm_sig, t0)
+            return losses
+
+    def _record_tick(self, shape, n, warm_sig, t0):
+        """One dispatched horizon of `n` steps into the recorder.
+        Called only with a recorder attached.
+
+        Dispatch is NON-blocking, so the call's own wall time is not
+        the horizon's: in a steady-state loop the dispatch-to-dispatch
+        gap is (the next dispatch blocks on the donated carry), so
+        measure that. The FIRST horizon after attach or
+        mark_recorder_idle() has no previous dispatch — its call wall
+        (from `t0`) is recorded but kept out of the drift ledger (cold
+        compiles and host pauses are pollution, the same exclusion the
+        serving engines apply to prefill windows)."""
+        now = time.perf_counter()
+        steady = self._rec_last_t is not None and warm_sig
+        # the tick's chrome slice must span the window it measured:
+        # steady ticks start at the PREVIOUS dispatch, not this one
+        start = self._rec_last_t if self._rec_last_t is not None else t0
+        self._rec_last_t = now
+        pred = self._rec_predicted_step_s
+        serial = self._rec_predicted_serial_s
+        self.recorder.tick(
+            "train", shape, now - start, ts=start,
+            predicted_s=(pred * n) if pred else None,
+            predicted_serial_s=(serial * n) if serial else None,
+            drift=steady, k=n, decode_rows=0, prefill_rows=0)
 
     def lower_step(self, batch, lr=0.0):
         """Lower the SAME specialized program `step()` dispatches for this
@@ -765,18 +776,30 @@ class Trainer:
         """Dispatch one compiled step. NON-BLOCKING: the returned loss is
         an unfetched device array — `float()` it only when you must (or
         batch the syncs through a `LossBuffer`), so dispatch of step N+1
-        overlaps step N's compute."""
-        lr = self.optimizer.get_lr() if lr is None else lr
-        batch, sig, batch_sh = self.place_batch(batch)
-        step_fn = self._placed_step(sig, batch_sh)
-        (self.params, self.opt_state, self.gt_state, self.consts,
-         loss) = step_fn(
-            self.params, self.opt_state, self.gt_state, self.consts, lr, batch)
-        sched = self.optimizer._lr_scheduler
-        if sched is not None:
-            sched.step()
-        self._host_step += 1
-        return loss
+        overlaps step N's compute. Under a profiler session the step
+        is a `trainer.step` span with `trainer.place_batch` and
+        `trainer.dispatch` inside (docs/observability.md); with a
+        recorder attached it records a tick like `step_multi`."""
+        step = self._host_step
+        with span("trainer.step", step=step):
+            t0 = time.perf_counter() if self.recorder is not None else None
+            lr = self.optimizer.get_lr() if lr is None else lr
+            with span("trainer.place_batch", step=step):
+                batch, sig, batch_sh = self.place_batch(batch)
+            warm_sig = sig in self._placed_steps
+            step_fn = self._placed_step(sig, batch_sh)
+            with span("trainer.dispatch", step=step):
+                (self.params, self.opt_state, self.gt_state, self.consts,
+                 loss) = step_fn(
+                    self.params, self.opt_state, self.gt_state,
+                    self.consts, lr, batch)
+            sched = self.optimizer._lr_scheduler
+            if sched is not None:
+                sched.step()
+            self._host_step += 1
+            if self.recorder is not None:
+                self._record_tick(("step", 1), 1, warm_sig, t0)
+            return loss
 
     def sync_to_model(self):
         """Copy trained params AND accumulated buffers (BN running stats)

@@ -5,6 +5,7 @@ paths as GPT; tp partition specs on the projections.
 """
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 from .. import nn
@@ -103,15 +104,17 @@ class BertForPretraining(nn.Layer):
 
     def forward(self, input_ids, token_type_ids=None, attention_mask=None):
         seq, pooled = self.bert(input_ids, token_type_ids, attention_mask)
-        h = self.mlm_norm(F.gelu(self.mlm_transform(seq)))
         from ..framework.core import apply_op
-        import jax
-        mlm_logits = apply_op(
-            lambda hv, e, b: jax.lax.dot_general(
-                hv, e, (((2,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) + b,
-            h, self.bert.embeddings.word_embeddings.weight, self.mlm_bias)
-        nsp_logits = self.nsp(pooled)
+        with jax.named_scope("mlm_head"):
+            h = self.mlm_norm(F.gelu(self.mlm_transform(seq)))
+            mlm_logits = apply_op(
+                lambda hv, e, b: jax.lax.dot_general(
+                    hv, e, (((2,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) + b,
+                h, self.bert.embeddings.word_embeddings.weight,
+                self.mlm_bias)
+        with jax.named_scope("nsp_head"):
+            nsp_logits = self.nsp(pooled)
         return mlm_logits, nsp_logits
 
 
@@ -122,10 +125,12 @@ class BertPretrainingCriterion(nn.Layer):
 
     def forward(self, mlm_logits, nsp_logits, mlm_labels, nsp_labels):
         from ..tensor.manipulation import reshape
-        mlm = F.cross_entropy(reshape(mlm_logits, [-1, self.vocab_size]),
-                              reshape(mlm_labels, [-1]), ignore_index=-100)
-        nsp = F.cross_entropy(nsp_logits, nsp_labels)
-        return mlm + nsp
+        with jax.named_scope("loss"):
+            mlm = F.cross_entropy(
+                reshape(mlm_logits, [-1, self.vocab_size]),
+                reshape(mlm_labels, [-1]), ignore_index=-100)
+            nsp = F.cross_entropy(nsp_logits, nsp_labels)
+            return mlm + nsp
 
 
 class BertForSequenceClassification(nn.Layer):

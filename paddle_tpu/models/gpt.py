@@ -116,45 +116,47 @@ class GPTBlock(Layer):
         B, L = x.shape[0], x.shape[1]
         res = x
         y = self.ln1(x)
-        qkv = self.qkv(y)
-        from ..tensor.manipulation import reshape
-        qkv = reshape(qkv, [B, L, 3, cfg.num_heads, cfg.head_dim])
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        if cache is not None:
-            attn, cache = self._attend_cached(q, k, v, cache, pos)
-        else:
-            from ..distributed.mesh import get_mesh
-            mesh = get_mesh(create_default=False)
-            if mesh is not None and mesh.shape.get("sp", 1) > 1:
-                # sequence parallel over the 'sp' ICI axis: exact ring
-                # attention, or Ulysses all-to-all head-resharding when
-                # configured and the head count divides
-                if cfg.sp_mode == "ulysses":
-                    # ops/ulysses.py raises if heads don't divide 'sp' —
-                    # an explicit error beats silently measuring ring
-                    from ..ops.ulysses import ulysses_attention
-                    attn = apply_op(
-                        lambda qv, kv, vv: ulysses_attention(
-                            qv, kv, vv, mesh=mesh, causal=True), q, k, v)
-                else:
-                    from ..ops.ring_attention import ring_attention
-                    layout = ("zigzag" if cfg.sp_mode == "zigzag"
-                              else "contiguous")
-                    attn = apply_op(
-                        lambda qv, kv, vv: ring_attention(
-                            qv, kv, vv, mesh=mesh, causal=True,
-                            layout=layout),
-                        q, k, v)
+        with jax.named_scope("attention"):
+            qkv = self.qkv(y)
+            from ..tensor.manipulation import reshape
+            qkv = reshape(qkv, [B, L, 3, cfg.num_heads, cfg.head_dim])
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            if cache is not None:
+                attn, cache = self._attend_cached(q, k, v, cache, pos)
             else:
-                attn = F.scaled_dot_product_attention(q, k, v, is_causal=True,
-                                                      dropout_p=cfg.dropout,
-                                                      training=self.training)
-        attn = reshape(attn, [B, L, cfg.hidden_size])
-        x = res + self._row_parallel(self.proj, attn)
+                from ..distributed.mesh import get_mesh
+                mesh = get_mesh(create_default=False)
+                if mesh is not None and mesh.shape.get("sp", 1) > 1:
+                    # sequence parallel over the 'sp' ICI axis: exact ring
+                    # attention, or Ulysses all-to-all head-resharding when
+                    # configured and the head count divides
+                    if cfg.sp_mode == "ulysses":
+                        # ops/ulysses.py raises if heads don't divide 'sp' —
+                        # an explicit error beats silently measuring ring
+                        from ..ops.ulysses import ulysses_attention
+                        attn = apply_op(
+                            lambda qv, kv, vv: ulysses_attention(
+                                qv, kv, vv, mesh=mesh, causal=True), q, k, v)
+                    else:
+                        from ..ops.ring_attention import ring_attention
+                        layout = ("zigzag" if cfg.sp_mode == "zigzag"
+                                  else "contiguous")
+                        attn = apply_op(
+                            lambda qv, kv, vv: ring_attention(
+                                qv, kv, vv, mesh=mesh, causal=True,
+                                layout=layout),
+                            q, k, v)
+                else:
+                    attn = F.scaled_dot_product_attention(q, k, v, is_causal=True,
+                                                          dropout_p=cfg.dropout,
+                                                          training=self.training)
+            attn = reshape(attn, [B, L, cfg.hidden_size])
+            x = res + self._row_parallel(self.proj, attn)
         res = x
         y = self.ln2(x)
-        y = self._row_parallel(self.fc2, F.gelu(self.fc1(y),
-                                                approximate=True))
+        with jax.named_scope("mlp"):
+            y = self._row_parallel(self.fc2, F.gelu(self.fc1(y),
+                                                    approximate=True))
         out = res + y
         return out if cache is None else (out, cache)
 
@@ -281,14 +283,15 @@ class GPT(Layer):
         x = self.ln_f(x)
         # tied head: [B,L,H] @ [H,V] — the big MXU matmul; fp32 accum via
         # preferred_element_type to keep loss numerics honest in bf16
-        if cfg.tie_embeddings:
-            logits = apply_op(
-                lambda h, e: jax.lax.dot_general(
-                    h, e, (((2,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32),
-                x, self.wte.weight)
-        else:
-            logits = self.lm_head(x)
+        with jax.named_scope("lm_head"):
+            if cfg.tie_embeddings:
+                logits = apply_op(
+                    lambda h, e: jax.lax.dot_general(
+                        h, e, (((2,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32),
+                    x, self.wte.weight)
+            else:
+                logits = self.lm_head(x)
         return logits if cache is None else (logits, new_cache)
 
 
@@ -301,24 +304,25 @@ class GPTPretrainingCriterion(Layer):
     otherwise the jnp cross_entropy path."""
 
     def forward(self, logits, labels):
-        V = logits.shape[-1]
-        from ..tensor.manipulation import reshape
-        flat = reshape(logits, [-1, V])
-        flat_labels = reshape(labels, [-1])
-        n = flat.shape[0]
-        from ..ops.fused_ops import can_fuse_xent
-        if can_fuse_xent(n, V):
-            from ..framework.core import apply_op
-            from ..ops.fused_ops import fused_softmax_cross_entropy
+        with jax.named_scope("loss"):
+            V = logits.shape[-1]
+            from ..tensor.manipulation import reshape
+            flat = reshape(logits, [-1, V])
+            flat_labels = reshape(labels, [-1])
+            n = flat.shape[0]
+            from ..ops.fused_ops import can_fuse_xent
+            if can_fuse_xent(n, V):
+                from ..framework.core import apply_op
+                from ..ops.fused_ops import fused_softmax_cross_entropy
 
-            def _f(lg, lab):
-                lab = lab.astype(jnp.int32)
-                valid = lab >= 0
-                rows = fused_softmax_cross_entropy(lg, jnp.maximum(lab, 0))
-                rows = jnp.where(valid, rows, 0.0)
-                return jnp.sum(rows) / jnp.maximum(jnp.sum(valid), 1)
-            return apply_op(_f, flat, flat_labels)
-        return F.cross_entropy(flat, flat_labels, ignore_index=-100, reduction="mean")
+                def _f(lg, lab):
+                    lab = lab.astype(jnp.int32)
+                    valid = lab >= 0
+                    rows = fused_softmax_cross_entropy(lg, jnp.maximum(lab, 0))
+                    rows = jnp.where(valid, rows, 0.0)
+                    return jnp.sum(rows) / jnp.maximum(jnp.sum(valid), 1)
+                return apply_op(_f, flat, flat_labels)
+            return F.cross_entropy(flat, flat_labels, ignore_index=-100, reduction="mean")
 
 
 def _preset(kw, **defaults):

@@ -1,4 +1,5 @@
 """Normalization layers — reference python/paddle/nn/layer/norm.py."""
+import jax
 import jax.numpy as jnp
 
 from ...framework.core import Tensor
@@ -34,7 +35,9 @@ class LayerNorm(Layer):
             self.bias = None
 
     def forward(self, input):
-        return F.layer_norm(input, self._normalized_shape, self.weight, self.bias, self._epsilon)
+        with jax.named_scope("layer_norm"):
+            return F.layer_norm(input, self._normalized_shape, self.weight,
+                                self.bias, self._epsilon)
 
     def extra_repr(self):
         return f"normalized_shape={self._normalized_shape}, epsilon={self._epsilon}"

@@ -5,6 +5,7 @@ shapes allow; projections are single [d, 3d] matmuls to keep the MXU busy.
 """
 import collections
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -114,17 +115,20 @@ class TransformerEncoderLayer(Layer):
         residual = src
         if self.normalize_before:
             src = self.norm1(src)
-        if cache is None:
-            src = self.self_attn(src, src, src, src_mask)
-        else:
-            src, cache = self.self_attn(src, src, src, src_mask, cache)
+        with jax.named_scope("attention"):
+            if cache is None:
+                src = self.self_attn(src, src, src, src_mask)
+            else:
+                src, cache = self.self_attn(src, src, src, src_mask, cache)
         src = residual + self.dropout1(src)
         if not self.normalize_before:
             src = self.norm1(src)
         residual = src
         if self.normalize_before:
             src = self.norm2(src)
-        src = self.linear2(self.dropout(self.activation(self.linear1(src))))
+        with jax.named_scope("mlp"):
+            src = self.linear2(
+                self.dropout(self.activation(self.linear1(src))))
         src = residual + self.dropout2(src)
         if not self.normalize_before:
             src = self.norm2(src)

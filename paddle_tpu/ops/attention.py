@@ -466,6 +466,7 @@ def _fwd_lse_impl(q, k, v, kvb, fb, seed, cfg, interpret=None):
             jax.ShapeDtypeStruct((B, H, Lq_f, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qh, kh, vh, *extra_args)
     if seq_q_real is not None:
         out = _unfold_gqa(out, Hq, Lq)
@@ -517,6 +518,7 @@ def _bwd_impl(q, k, v, lse, g, out, kvb, fb, seed, cfg, interpret=None):
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Lq_f, D), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qh, kh, vh, doh, lse, delta, *extra_args)
 
     extra_specs, extra_args = _bias_specs(cfg, B, H, bq, Lk, fb_rows, kvb, fb, seed,
@@ -544,6 +546,7 @@ def _bwd_impl(q, k, v, lse, g, out, kvb, fb, seed, cfg, interpret=None):
             jax.ShapeDtypeStruct((B, H, Lk, D), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qh, kh, vh, doh, lse, delta, *extra_args)
     if seq_q_real is not None:
         dq = _unfold_gqa(dq, Hq, Lq)

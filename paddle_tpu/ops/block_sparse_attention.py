@@ -102,6 +102,7 @@ def _bs_fwd(q, k, v, block_cols, block_counts, block_size, scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, L, D), q.dtype),
         interpret=interpret,
+        name="block_sparse_attention_fwd",
     )(block_cols.astype(jnp.int32), block_counts.astype(jnp.int32),
       q, k, v)
 

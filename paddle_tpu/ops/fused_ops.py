@@ -165,6 +165,7 @@ def _xent_fwd_impl(logits, labels, interpret=None):
             pltpu.VMEM((block_n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="xent_fwd",
     )(logits, labels.astype(jnp.int32).reshape(n, 1))
     return loss[:, 0], lse
 
@@ -207,6 +208,7 @@ def _xent_bwd_impl(logits, labels, lse, g, interpret=None):
         out_specs=pl.BlockSpec((block_n, block_v), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, v), logits.dtype),
         interpret=interpret,
+        name="xent_bwd",
     )(logits, labels.astype(jnp.int32).reshape(n, 1), lse.reshape(n, 1),
       g.reshape(n, 1))
 
@@ -276,6 +278,7 @@ def fused_adamw(p, g, m, v, step, lr, beta1=0.9, beta2=0.999, eps=1e-8,
                        jax.ShapeDtypeStruct((flat,), m.dtype),
                        jax.ShapeDtypeStruct((flat,), v.dtype)],
             interpret=interpret,
+            name="fused_adamw",
         )(*args)
     except Exception as e:
         kernel_fallback("fused_adamw", e)
@@ -384,6 +387,7 @@ def fused_dropout_residual_layer_norm(x, residual, weight, bias, p=0.1,
                 out_shape=[jax.ShapeDtypeStruct((n, h), x.dtype),
                            jax.ShapeDtypeStruct((n, h), x.dtype)],
                 interpret=interpret,
+                name="dropout_residual_ln",
             )(x, residual, w, b, rng_arg))
         except Exception as e:
             kernel_fallback("fused_dropout_residual_ln", e)

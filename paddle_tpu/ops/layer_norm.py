@@ -93,6 +93,7 @@ def _ln_fwd_impl(x, weight, bias, eps=1e-5):
             out_specs=pl.BlockSpec((rows, h), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((n, h), x.dtype),
             interpret=jax.default_backend() == "cpu",
+            name="layer_norm_fwd",
         )(flat, weight, bias)
         return out.reshape(x.shape)
     except Exception as e:
@@ -137,6 +138,7 @@ def _rms_fwd_impl(x, weight, eps=1e-6):
             out_specs=pl.BlockSpec((rows, h), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((n, h), x.dtype),
             interpret=jax.default_backend() == "cpu",
+            name="rms_norm_fwd",
         )(flat, weight)
         return out.reshape(x.shape)
     except Exception as e:

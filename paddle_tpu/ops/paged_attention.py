@@ -197,5 +197,6 @@ def _paged_kernel_call(q, k_pages, v_pages, page_table, seq_lens, scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q, k_pages, v_pages)

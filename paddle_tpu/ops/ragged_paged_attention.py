@@ -154,21 +154,24 @@ def _ragged_ref(q, k_pages, v_pages, page_table, start, scale,
     MP = page_table.shape[1]
     safe = jnp.maximum(page_table, 0)
     quantized = k_scale is not None and not int4
-    if int4:
-        # packed payload [n, MP, ps, PB] -> per-page [MP][n, ps, PB];
-        # group scales [n, MP, ps, G] -> per-page [MP][n, ps, G]
-        kg = jnp.moveaxis(k_pages[safe], 1, 0)
-        vg = jnp.moveaxis(v_pages[safe], 1, 0)
-        ksg = jnp.moveaxis(k_scale[safe], 1, 0)
-        vsg = jnp.moveaxis(v_scale[safe], 1, 0)
-    else:
-        # [n, MP, ps, H, D] -> per-page [MP][n, H, ps, D]
-        kg = jnp.moveaxis(k_pages[safe], (1, 3), (0, 2))
-        vg = jnp.moveaxis(v_pages[safe], (1, 3), (0, 2))
-        if quantized:
-            # [n, MP, ps] -> per-page [MP][n, ps]
+    # named for the trace: the gather of every row's whole page table
+    # is most of a serving tick's device time (PERF.md, S1b)
+    with jax.named_scope("paged_gather"):
+        if int4:
+            # packed payload [n, MP, ps, PB] -> per-page [MP][n, ps, PB];
+            # group scales [n, MP, ps, G] -> per-page [MP][n, ps, G]
+            kg = jnp.moveaxis(k_pages[safe], 1, 0)
+            vg = jnp.moveaxis(v_pages[safe], 1, 0)
             ksg = jnp.moveaxis(k_scale[safe], 1, 0)
             vsg = jnp.moveaxis(v_scale[safe], 1, 0)
+        else:
+            # [n, MP, ps, H, D] -> per-page [MP][n, H, ps, D]
+            kg = jnp.moveaxis(k_pages[safe], (1, 3), (0, 2))
+            vg = jnp.moveaxis(v_pages[safe], (1, 3), (0, 2))
+            if quantized:
+                # [n, MP, ps] -> per-page [MP][n, ps]
+                ksg = jnp.moveaxis(k_scale[safe], 1, 0)
+                vsg = jnp.moveaxis(v_scale[safe], 1, 0)
     qf = (q.astype(jnp.float32) * scale).transpose(0, 2, 1, 3)  # [n,H,W,D]
     qpos = (start[:, None] + jnp.arange(W))[:, None, :]         # [n,1,W]
 
@@ -203,19 +206,21 @@ def _ragged_ref(q, k_pages, v_pages, page_table, start, scale,
             k_scale=ksj[:, None] if quantized else None,
             v_scale=vsj[:, None] if quantized else None), None
 
-    carry = (jnp.full((n, H, W, 1), _MASK, jnp.float32),
-             jnp.zeros((n, H, W, 1), jnp.float32),
-             jnp.zeros((n, H, W, D), jnp.float32))
     pages = (kg, vg) + ((ksg, vsg) if (quantized or int4) else ())
-    if MP <= _UNROLL_PAGES:
-        for j in range(MP):
-            carry, _ = page_step(carry, (j,) + tuple(x[j] for x in pages))
-    else:
-        carry, _ = jax.lax.scan(page_step, carry,
-                                (jnp.arange(MP),) + pages)
-    m, s, acc = carry
-    out = acc / jnp.maximum(s, _DENOM_EPS)               # [n, H, W, D]
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)     # [n, W, H, D]
+    with jax.named_scope("paged_attention"):
+        carry = (jnp.full((n, H, W, 1), _MASK, jnp.float32),
+                 jnp.zeros((n, H, W, 1), jnp.float32),
+                 jnp.zeros((n, H, W, D), jnp.float32))
+        if MP <= _UNROLL_PAGES:
+            for j in range(MP):
+                carry, _ = page_step(
+                    carry, (j,) + tuple(x[j] for x in pages))
+        else:
+            carry, _ = jax.lax.scan(page_step, carry,
+                                    (jnp.arange(MP),) + pages)
+        m, s, acc = carry
+        out = acc / jnp.maximum(s, _DENOM_EPS)           # [n, H, W, D]
+        return out.transpose(0, 2, 1, 3).astype(q.dtype)  # [n, W, H, D]
 
 
 # the reference always executes COMPILED, even when the caller is
@@ -349,6 +354,7 @@ def _ragged_kernel_call(q, k_pages, v_pages, page_table, start, scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, W, H, D), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(page_table.astype(jnp.int32), start.astype(jnp.int32),
       *operands)
 
@@ -426,6 +432,7 @@ def _packed_kernel_call(q2, k_pages, v_pages, page_table, row_ids, pos,
         body, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, W, H, D), q2.dtype),
         interpret=interpret,
+        name="ragged_paged_attention_packed",
     )(page_table.astype(jnp.int32), row_ids.astype(jnp.int32),
       pos.astype(jnp.int32), *operands)
 

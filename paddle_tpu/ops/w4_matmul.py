@@ -112,6 +112,7 @@ def w4_matmul(x, packed, scale, K, block_n=256, block_s=512):
             out_specs=pl.BlockSpec((bs, block_n), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((Sp, Np), x.dtype),
             interpret=jax.default_backend() == "cpu",
+            name="w4_matmul",
         )(xp, pk, sc)
         return out[:S, :N].reshape(*lead, N)
     except Exception as e:

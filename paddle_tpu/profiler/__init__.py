@@ -9,7 +9,10 @@ Three measurement layers, all real:
   (RecordEvent), and — while a profiler is active — per-op eager dispatch
   timings hooked into framework.core.apply_op (the TPU rendering of the
   reference's op-level CPU/GPU time tables);
-- summary()/export(): aggregated statistics table / chrome-trace JSON.
+- summary()/export(): aggregated statistics table / chrome-trace JSON;
+- `span(name, **ids)`: the program's own regions on the host's timeline of
+  the JAX profiler's trace, live exactly when a profiler session is;
+  `read_spans(log_dir)` reads them back from the session's `.xplane.pb`.
 """
 import contextlib
 import json
@@ -19,7 +22,8 @@ import time
 
 import jax
 
-__all__ = ["Profiler", "ProfilerTarget", "RecordEvent", "profiler_guard",
+__all__ = ["Profiler", "ProfilerTarget", "RecordEvent", "span",
+           "read_spans", "profiler_guard",
            "export_chrome_tracing", "make_scheduler", "ProfilerState",
            "SortedKeys", "export_protobuf", "load_profiler_result"]
 
@@ -296,21 +300,71 @@ class Profiler:
         return False
 
 
+def span(name, **ids):
+    """A region of host code on the JAX profiler's own timeline: a
+    `jax.profiler.TraceAnnotation`, so it lands in the `.xplane.pb` host
+    plane on the clock of the device operations whenever a profiler
+    session is running (`Profiler`, `jax.profiler.trace`, a benchmark's
+    traced run) and costs about half a microsecond when none is. A span
+    is name, start, end; its parent is the span that encloses it on the
+    thread. `ids` (`seq` of a serving round, `rid` of a request, `step`
+    of a training step, ...) become the event's stats and leave its name
+    clean, so spans join to each other and to the engine's horizon
+    records by id. The engine's and the trainer's vocabulary is in
+    docs/observability.md."""
+    return jax.profiler.TraceAnnotation(name, **ids)
+
+
+def read_spans(log_dir, prefixes=("engine.", "trainer.")):
+    """The spans a finished profiler session under `log_dir` holds, read
+    back from its newest `.xplane.pb`: [{"name", "start_ns", "end_ns",
+    **ids}] in order of start, a parent before its children. `prefixes`
+    picks them by name (default: the engine's and the trainer's
+    vocabulary; `("",)` takes every event of every plane, device
+    operations included). How the `span`s are read without a profile
+    viewer: which phase of which `seq` ran when, on the device
+    operations' clock."""
+    import glob
+    from jax.profiler import ProfileData
+    paths = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
+    prefixes = tuple(prefixes)
+    spans = []
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefixes):
+                    spans.append(dict(
+                        ev.stats, name=ev.name, start_ns=ev.start_ns,
+                        end_ns=ev.start_ns + ev.duration_ns))
+    return sorted(spans, key=lambda s: (s["start_ns"], -s["end_ns"]))
+
+
 class RecordEvent:
-    """Named region: shows in the XLA trace via named_scope AND is host-timed
-    into the active Profiler's event table."""
+    """Named region, three ways: a `span` on the profiler's host timeline
+    (in the `.xplane.pb` whenever a profiler session is running, eager
+    code included), a `jax.named_scope` (which names the operations
+    TRACED under it in their HLO metadata and marks nothing on the
+    host's timeline), and a host-timed row in the active `Profiler`'s
+    event table."""
 
     def __init__(self, name, event_type=None):
         self.name = name
         self._scope = jax.named_scope(name)
+        self._span = span(name)
 
     def begin(self):
         self._t0 = time.perf_counter()
         _event_stack().append(self.name)
+        self._span.__enter__()
         self._scope.__enter__()
 
     def end(self):
         self._scope.__exit__(None, None, None)
+        self._span.__exit__(None, None, None)
         stack = _event_stack()
         if stack and stack[-1] == self.name:
             stack.pop()

@@ -37,7 +37,19 @@ def clear_compiled_memos():
             if getattr(dec, attr) is not None:
                 n += 1
                 setattr(dec, attr, None)
+        dec._used.clear()      # what recompiles is a first use again
     return n
+
+
+def _named_jit(fn, name, **jit_kw):
+    """`jax.jit(fn)` under `name`: the XLA module is called `jit_<name>`,
+    so the profiler's "XLA Modules" line and a compile log tell one
+    serving program from another by its key (every one of them would
+    otherwise carry the name of the method it partially applies)."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kw)
 
 
 # decode_multi's result bundle: device arrays — the engine feeds
@@ -487,13 +499,19 @@ class PagedGPTDecoder:
         if self.mesh is not None:
             self._shard_for_tp()
 
-        self._decode = jax.jit(self._decode_step, donate_argnums=(1, 2))
+        self._decode = _named_jit(self._decode_step,
+                                  self.program_name("tick", 1, 1, None),
+                                  donate_argnums=(1, 2))
         self._multis = {}     # (k, return_logits) -> jitted fused loop
-        self._raggeds = {}    # (k, w) -> jitted mixed ragged horizon
-        self._packeds = {}    # (k, t) -> jitted PACKED mixed horizon
+        # the mixed horizons are memoized per table width too (a shape:
+        # it compiled a program of its own before as well), so that each
+        # program carries its whole key in its name
+        self._raggeds = {}    # (k, w, width) -> jitted mixed ragged horizon
+        self._packeds = {}    # (k, t, width) -> jitted PACKED mixed horizon
         # (w rides as a traced scalar — per-dispatch width changes
         # never compile a new program; dispatches bucket by total
         # token count t alone)
+        self._used = set()    # program names dispatched so far (first_use)
         self._packed_prefills = {}   # t -> jitted packed prefill
         self._verify = None   # jitted lazily (speculative decoding only)
         self._probs = None    # jitted lazily (sampled speculation)
@@ -514,6 +532,35 @@ class PagedGPTDecoder:
         self.n_adapters = 0
         self._adapter_salts = [b""]
         _LIVE_DECODERS.add(self)
+
+    def first_use(self, program):
+        """True the first time `program` (a `program_name`) is asked
+        about on this decoder, False ever after. A first use has a
+        compile or a cache load inside its measured time: the engines
+        stamp it on the horizon's record and keep such a window out of
+        the drift ledger."""
+        if program in self._used:
+            return False
+        self._used.add(program)
+        return True
+
+    @staticmethod
+    @functools.lru_cache(maxsize=None)
+    def program_name(kind, k, x, width):
+        """The ONE name of a horizon's program, made of the key the
+        decoder memoizes it by — the dispatch shape (`kind`, k ticks,
+        `x` = the packed token bucket t or the ragged window w) and the
+        page table's `width` in columns: `jit_<name>` on a trace's "XLA
+        Modules" line, `program` on the engine's `engine.dispatch` span
+        and on the horizon's record, and what `first_use` is asked
+        about. Kinds: "packed", "ragged", "decode" (`decode_multi`, on
+        the whole table) and "tick" (the per-tick `decode`). Cached: a
+        round pays a lookup, not a format."""
+        if kind == "packed":
+            return f"packed_multi_k{k}_t{x}_p{width}"
+        if kind == "ragged":
+            return f"ragged_multi_k{k}_w{x}_p{width}"
+        return f"decode_multi_k{k}" if kind == "decode" else "decode_step"
 
     # ---------------------------------------------------- multi-LoRA
 
@@ -606,11 +653,11 @@ class PagedGPTDecoder:
             from ..models.generation import mask_logits
             if self.sampling:
                 t, tk, tp = self.sampling
-                self._probs = jax.jit(lambda lg: jax.nn.softmax(
-                    mask_logits(lg, t, tk, tp), axis=-1))
+                self._probs = _named_jit(lambda lg: jax.nn.softmax(
+                    mask_logits(lg, t, tk, tp), axis=-1), "sampling_probs")
             else:
-                self._probs = jax.jit(
-                    lambda lg: jax.nn.softmax(lg, axis=-1))
+                self._probs = _named_jit(
+                    lambda lg: jax.nn.softmax(lg, axis=-1), "greedy_probs")
         return np.asarray(self._probs(logits))
 
     def _shard_for_tp(self):
@@ -705,8 +752,9 @@ class PagedGPTDecoder:
                 qkv = qkv + _lora_delta(wl, y, aids).reshape(
                     S, 3, H, D).astype(qkv.dtype)
             q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-            kp = _kv_set(kp, pids, offs, k)
-            vp = _kv_set(vp, pids, offs, v)
+            with jax.named_scope("kv_write"):
+                kp = _kv_set(kp, pids, offs, k)
+                vp = _kv_set(vp, pids, offs, v)
             # the ONE ragged kernel behind every serving path (decode is
             # the W=1 row kind): causal over kpos <= lens, i.e. the
             # slot's prefix plus the key written just above
@@ -721,8 +769,9 @@ class PagedGPTDecoder:
             x = x + _mm(h, wl["fc2_w"], wl["fc2_b"], quant)
             return x, (kp, vp)
 
-        x, (k_pages, v_pages) = jax.lax.scan(
-            layer, x, (weights, k_pages, v_pages))
+        with jax.named_scope("layers"):
+            x, (k_pages, v_pages) = jax.lax.scan(
+                layer, x, (weights, k_pages, v_pages))
         x = _ln(x, self.ln_f_w, self.ln_f_b)
         logits = x.astype(jnp.float32) @ self.lm_head.astype(jnp.float32)
         return logits, k_pages, v_pages
@@ -843,8 +892,9 @@ class PagedGPTDecoder:
                 qkv = qkv + _lora_delta(wl, yf, aid_tok).reshape(
                     n, W, 3, H, D).astype(qkv.dtype)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            kp = _kv_set(kp, pids, offs, k)
-            vp = _kv_set(vp, pids, offs, v)
+            with jax.named_scope("kv_write"):
+                kp = _kv_set(kp, pids, offs, k)
+                vp = _kv_set(vp, pids, offs, v)
             # pos rows are contiguous windows (start + arange(W)), so
             # the row's first entry IS its cached length
             from ..ops.ragged_paged_attention import ragged_paged_attention
@@ -888,9 +938,10 @@ class PagedGPTDecoder:
         pids = jnp.where(in_range, pids, self.num_pages - 1)
         offs = pos % ps
 
-        x, (k_pages, v_pages) = jax.lax.scan(
-            self._windowed_layer(pos, pids, offs, table), x,
-            (weights, k_pages, v_pages))
+        with jax.named_scope("layers"):
+            x, (k_pages, v_pages) = jax.lax.scan(
+                self._windowed_layer(pos, pids, offs, table), x,
+                (weights, k_pages, v_pages))
         x = _ln(x, self.ln_f_w, self.ln_f_b)
         logits = x.astype(jnp.float32) @ self.lm_head.astype(jnp.float32)
         return (jnp.argmax(logits, axis=-1).astype(jnp.int32), logits,
@@ -899,8 +950,8 @@ class PagedGPTDecoder:
     def verify(self, tokens, lens, table, return_probs=False):
         """Batched speculative verify (see _verify_step)."""
         if self._verify is None:
-            self._verify = jax.jit(self._verify_step,
-                                   donate_argnums=(1, 2))
+            self._verify = _named_jit(self._verify_step, "verify_step",
+                                      donate_argnums=(1, 2))
         out, logits, self.k_pages, self.v_pages = self._verify(
             self.weights, self.k_pages, self.v_pages,
             jnp.asarray(tokens, jnp.int32), jnp.asarray(lens, jnp.int32),
@@ -951,9 +1002,10 @@ class PagedGPTDecoder:
         pids = jnp.where(in_range, pids, self.num_pages - 1)
         offs = pos % ps
 
-        x, (k_pages, v_pages) = jax.lax.scan(
-            self._windowed_layer(pos, pids, offs, table, aids=aids), x,
-            (weights, k_pages, v_pages))
+        with jax.named_scope("layers"):
+            x, (k_pages, v_pages) = jax.lax.scan(
+                self._windowed_layer(pos, pids, offs, table, aids=aids),
+                x, (weights, k_pages, v_pages))
         x = _ln(x, self.ln_f_w, self.ln_f_b)
         last = jnp.take_along_axis(
             x, jnp.clip(true_len - 1 - start, 0, W - 1)
@@ -1079,8 +1131,9 @@ class PagedGPTDecoder:
                 qkv = qkv + _lora_delta(wl, y, aids[rows]).reshape(
                     T, 3, H, D).astype(qkv.dtype)
             q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-            kp = _kv_set(kp, pids, offs, k)
-            vp = _kv_set(vp, pids, offs, v)
+            with jax.named_scope("kv_write"):
+                kp = _kv_set(kp, pids, offs, k)
+                vp = _kv_set(vp, pids, offs, v)
             from ..ops.ragged_paged_attention import \
                 ragged_paged_attention_packed
             attn = ragged_paged_attention_packed(
@@ -1122,9 +1175,11 @@ class PagedGPTDecoder:
         pids = jnp.where(write_ok, pids, self.num_pages - 1)
         offs = pos % ps
 
-        x, (k_pages, v_pages) = jax.lax.scan(
-            self._packed_layer(rows, pos, pids, offs, table, aids=aids),
-            x, (weights, k_pages, v_pages))
+        with jax.named_scope("layers"):
+            x, (k_pages, v_pages) = jax.lax.scan(
+                self._packed_layer(rows, pos, pids, offs, table,
+                                   aids=aids),
+                x, (weights, k_pages, v_pages))
         x = _ln(x, self.ln_f_w, self.ln_f_b)
         last = x[jnp.clip(last_idx, 0, x.shape[0] - 1)]   # [S, h]
         last = jnp.where(live[:, None], last, 0.0)
@@ -1289,8 +1344,9 @@ class PagedGPTDecoder:
         if aids is None:
             aids = [0] * len(requests)
         if self._suffix_prefill is None:
-            self._suffix_prefill = jax.jit(self._prefill_suffix_step,
-                                           donate_argnums=(1, 2))
+            self._suffix_prefill = _named_jit(
+                self._prefill_suffix_step, "prefill_suffix",
+                donate_argnums=(1, 2))
         MP = self.max_pages
         groups = {}
         for i, (ids, start, pages) in enumerate(requests):
@@ -1377,8 +1433,9 @@ class PagedGPTDecoder:
                 cur += n
             fn = self._packed_prefills.get(t)
             if fn is None:
-                fn = jax.jit(self._prefill_packed_step,
-                             donate_argnums=(1, 2))
+                fn = _named_jit(self._prefill_packed_step,
+                                f"prefill_packed_t{t}",
+                                donate_argnums=(1, 2))
                 self._packed_prefills[t] = fn
             self._draws += 1
             call = (jnp.asarray(ptok), jnp.asarray(pos),
@@ -1410,7 +1467,8 @@ class PagedGPTDecoder:
                     return a.at[:, d].set(a[:, s])
                 return (jax.tree_util.tree_map(one, kp),
                         jax.tree_util.tree_map(one, vp))
-            self._copy = jax.jit(cp, donate_argnums=(0, 1))
+            self._copy = _named_jit(cp, "copy_page",
+                                    donate_argnums=(0, 1))
         self.k_pages, self.v_pages = self._copy(
             self.k_pages, self.v_pages,
             jnp.asarray(int(src), jnp.int32),
@@ -1473,8 +1531,8 @@ class PagedGPTDecoder:
                     return tuple(out) if isinstance(pool, tuple) \
                         else out[0]
                 return setp(kp, kvals), setp(vp, vvals)
-            fn = self._mount_multi[n] = jax.jit(mnt,
-                                                donate_argnums=(0, 1))
+            fn = self._mount_multi[n] = _named_jit(
+                mnt, f"mount_pages_n{n}", donate_argnums=(0, 1))
 
         def stack(part):
             n_leaves = len(payloads[0][part])
@@ -1504,7 +1562,8 @@ class PagedGPTDecoder:
                     return tuple(out) if isinstance(pool, tuple) \
                         else out[0]
                 return setp(kp, kvals), setp(vp, vvals)
-            self._mount = jax.jit(mnt, donate_argnums=(0, 1))
+            self._mount = _named_jit(mnt, "mount_page",
+                                     donate_argnums=(0, 1))
         self.k_pages, self.v_pages = self._mount(
             self.k_pages, self.v_pages, jnp.asarray(int(page), jnp.int32),
             tuple(jnp.asarray(x) for x in payload["k"]),
@@ -1935,9 +1994,11 @@ class PagedGPTDecoder:
         key = (k, bool(return_logits))
         fn = self._multis.get(key)
         if fn is None:
-            fn = jax.jit(
+            fn = _named_jit(
                 functools.partial(self._decode_multi_step, k=k,
                                   return_logits=bool(return_logits)),
+                self.program_name("decode", k, 1, None)
+                + ("_logits" if return_logits else ""),
                 donate_argnums=(1, 2))
             self._multis[key] = fn
         if done is None:
@@ -2022,11 +2083,13 @@ class PagedGPTDecoder:
                 raise ValueError(
                     f"t_tokens {t} < max_batch {S}: the packed bucket "
                     "must cover at least one token per slot")
-            key = (k, t)
+            width = args[2].shape[1]
+            key = (k, t, width)
             fn = self._packeds.get(key)
             if fn is None:
-                fn = jax.jit(
+                fn = _named_jit(
                     functools.partial(self._packed_multi_step, k=k, t=t),
+                    self.program_name("packed", k, t, width),
                     donate_argnums=(1, 2))
                 self._packeds[key] = fn
             call = args + (jnp.asarray(w, jnp.int32),)
@@ -2034,11 +2097,13 @@ class PagedGPTDecoder:
                 call += (jnp.asarray(self._aids_or_default(aids)),)
             out = fn(self._w(), self.k_pages, self.v_pages, *call)
         else:
-            key = (k, w)
+            width = args[2].shape[1]
+            key = (k, w, width)
             fn = self._raggeds.get(key)
             if fn is None:
-                fn = jax.jit(
+                fn = _named_jit(
                     functools.partial(self._ragged_multi_step, k=k, w=w),
+                    self.program_name("ragged", k, w, width),
                     donate_argnums=(1, 2))
                 self._raggeds[key] = fn
             call = args

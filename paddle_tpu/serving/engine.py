@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework.core import Tensor
+from ..profiler import span
 from .decoder import PagedGPTDecoder, _spec_accept
 from .stats import _ENGINES, ServeStats
 
@@ -17,6 +18,30 @@ __all__ = ["ContinuousBatchingEngine", "SpeculativeEngine"]
 # bounded schedule-event window: the SERVE-PREFILL-STALL audit reads
 # the most recent scheduling decisions, not the process lifetime
 _SCHED_WINDOW = 4096
+
+
+class _Phase:
+    """One phase of a scheduling round: a `profiler.span` around it (in
+    the trace whenever a profiler session runs, half a microsecond
+    otherwise) and its seconds on the host's clock added to the
+    horizon's record under `key` (`rec` None: the span alone, as for a
+    `step()` called outside a run loop)."""
+    __slots__ = ("_span", "_rec", "_key", "_t0")
+
+    def __init__(self, name, rec, key, **ids):
+        self._span = span(name, **ids)
+        self._rec, self._key = rec, key
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        if self._rec is not None:
+            self._rec[self._key] += dt
+        return False
 
 
 class ContinuousBatchingEngine:
@@ -224,8 +249,14 @@ class ContinuousBatchingEngine:
         # pad-fraction bench runs both off one decoder).
         self.packed = bool(decoder.packed if packed is None else packed)
         self._prompt_len = [0] * S           # admitted prompt length/slot
-        # scheduling-decision trace for the SERVE-PREFILL-STALL audit
+        # THE record of every dispatched horizon (one "horizon" dict
+        # each, always on: `_begin_round`), beside the "prefill_sync"
+        # marks of the blocking path: what `serve_schedule()` returns,
+        # the SERVE-PREFILL-STALL audit and a benchmark's readers
+        # read, and — the same dicts — a flight recorder's ticks
         self._sched_events = collections.deque(maxlen=_SCHED_WINDOW)
+        self._seq = 0                        # scheduling rounds begun
+        self._open = None                    # the open round's record
         self.stats = ServeStats(
             engine=type(self).__name__, k_max=self.k_max,
             # num_pages - 1: the reserved scratch page never holds a
@@ -248,7 +279,7 @@ class ContinuousBatchingEngine:
         self.trace = trace or None
         self._trace_price = None         # (hbm, flops/token, sync_s)
         self._trace_pool_mark = (0, 0)   # (cow, evictions) marks
-        self._trace_warm = set()         # dispatch shapes already compiled
+        self._restore_warm = False       # a restore tick was recorded
         if self.trace is not None:
             self.trace.meta.update(
                 engine=type(self).__name__, k_max=self.k_max,
@@ -297,15 +328,13 @@ class ContinuousBatchingEngine:
         self._trace_pool_mark = (cow, ev)
         return d
 
-    def _trace_shape_warm(self, key):
-        """First dispatch of a compiled-program shape pays its XLA
-        compile inside the measured window — its tick is recorded but
-        kept OUT of the drift ledger (one compile sample would inflate
-        the rolling mean for hundreds of steady ticks). Called only
-        with tracing on."""
-        warm = key in self._trace_warm
-        self._trace_warm.add(key)
-        return warm
+    def _trace_event(self, kind, **fields):
+        """One lifecycle event into the recorder, with the `seq` of the
+        scheduling round it happened in (a submit between rounds
+        carries the last round begun): a request's events join to the
+        horizons' records and to the profiler's `engine.*` spans by
+        id, with no conversion of clocks. Called only with tracing on."""
+        return self.trace.record(kind, seq=self._seq, **fields)
 
     def _trace_admits(self, admitted, now):
         """Admit events with the prefix-cache mount detail (cached
@@ -313,7 +342,7 @@ class ContinuousBatchingEngine:
         submit and first_token marks. Called only with tracing on."""
         for slot, rid, ids, _pages in admitted:
             meta = self._cache_meta.get(rid)
-            self.trace.record(
+            self._trace_event(
                 "admit", ts=now, rid=rid, slot=slot,
                 prompt_tokens=len(ids),
                 cached_tokens=int(meta[0]) if meta else 0,
@@ -324,7 +353,107 @@ class ContinuousBatchingEngine:
         Called only with tracing on, from the token-processing loops."""
         n = len(self._outputs[rid])
         if n % self.trace.progress_every == 0:
-            self.trace.record("progress", rid=rid, tokens=n)
+            self._trace_event("progress", rid=rid, tokens=n)
+
+    # ------------------------------------------- the horizon's record
+
+    def _begin_round(self):
+        """Open the record of one scheduling round: THE dict that
+        describes the horizon this round dispatches (a round that
+        dispatches none drops it). Always on — some ten clock reads
+        and twenty entries a horizon, microseconds against a shortest
+        horizon of milliseconds. Stamped with `time.perf_counter()`
+        where the work happens; every field is about THIS horizon, so
+        on the pipelined loops `fetch_wait_s`, `book_s`, `on_sync_s`,
+        `t_fetched` and the work fields are filled one round later,
+        when its block lands. Fields (docs/observability.md):
+
+        identity  kind, seq, k, w, t_tokens, decode_rows, prefill_rows,
+                  slots, program (`PagedGPTDecoder.program_name`: the
+                  name the jitted program and the `engine.dispatch`
+                  span carry), first_use (this decoder had not
+                  dispatched the program before: a compile or a cache
+                  load lies inside)
+        queue     queue_depth after admission; admit_waits_s, the
+                  submit-to-admit wait of each request admitted
+        times     t_round, t_fetched; admit_s, plan_s, dispatch_s,
+                  fetch_wait_s, book_s, on_sync_s
+        work      tokens emitted, tokens_dispatched, tokens_padded
+
+        Every field has a reader, named in PERF.md section 3: a field
+        nothing reads is not stamped."""
+        self._seq += 1
+        rec = self._open = {
+            "kind": "horizon", "seq": self._seq, "admit_waits_s": [],
+            "admit_s": 0.0, "plan_s": 0.0, "dispatch_s": 0.0,
+            "fetch_wait_s": 0.0, "book_s": 0.0, "on_sync_s": 0.0,
+            "t_round": time.perf_counter()}
+        return rec
+
+    def _note_admitted(self, admitted, now):
+        """Queue-wait stamps (submit -> admit) of one admission pass:
+        into the stats and into the open round's record."""
+        rec = self._open
+        for _, rid, _, _ in admitted:
+            t0 = self._submit_t.get(rid)
+            if t0 is not None:
+                self._note_queue_wait(rid, now - t0)
+                if rec is not None:
+                    rec["admit_waits_s"].append(now - t0)
+        if self.trace is not None:
+            self._trace_admits(admitted, now)
+
+    def _horizon_dispatched(self, rec, shape, program, k, w, t_tokens,
+                            decode_rows, prefill_rows, disp_toks,
+                            priced=True):
+        """The open round has dispatched its horizon: stamp what it is
+        and append the record to the schedule. `shape` is the dispatch
+        shape the drift ledger keys on, `program` the name of the
+        compiled program it ran (the shape with the table's width:
+        `PagedGPTDecoder.program_name`). With a recorder attached the
+        SAME dict becomes its tick, the price added (`priced=False`:
+        a window polluted by a blocking prefill is recorded unpriced
+        and stays out of the ledger). The pending tiered-KV restore
+        price is drained here even when untraced, so it cannot
+        accumulate: the H2D of a restore dispatched at this round's
+        admission lands inside THIS horizon's window."""
+        restore_s = self._take_restore_s()
+        rec.update(
+            k=k, w=w, t_tokens=t_tokens, decode_rows=decode_rows,
+            prefill_rows=prefill_rows, slots=self.d.max_batch,
+            program=program, first_use=self.d.first_use(program),
+            queue_depth=len(self._queue), tokens_dispatched=disp_toks)
+        self._sched_events.append(rec)
+        if self.trace is not None:
+            pred = serial = None
+            if priced:
+                pred = restore_s + self._price_horizon(
+                    k, w, prefill_rows, decode_rows=decode_rows)
+                serial = restore_s + self._price_horizon(
+                    k, w, prefill_rows, decode_rows=decode_rows,
+                    serial=True)
+            self.trace.tick_dispatch(
+                "serve", shape, ts=rec["t_round"], ev=rec,
+                predicted_s=pred, predicted_serial_s=serial)
+
+    def _horizon_booked(self, rec, step_times, drift=True, **work):
+        """The horizon's block is fetched and book-kept: stamp its
+        `work` (tokens, tokens_padded; `step()` has stamped
+        its own) and close the measured window (round start to here:
+        it spans the dispatching round and, on the pipelined loops, the
+        next round up to this call). Returns the window's seconds."""
+        dt = time.perf_counter() - rec["t_round"]
+        rec.update(work)
+        if step_times is not None:
+            step_times.append(dt)
+        if self.trace is not None:
+            # the program's first (compiling) dispatch stays out of
+            # the drift ledger: one compile sample would inflate the
+            # rolling mean for hundreds of steady ticks
+            self.trace.tick_complete(
+                rec, dt, drift=drift and not rec["first_use"],
+                pool=self._trace_pool_delta())
+        return dt
 
     # ------------------------------------------------------- tiered KV
 
@@ -373,7 +502,7 @@ class ContinuousBatchingEngine:
                 if tier.put(key, payload):
                     self.stats.tier_spills += 1
                     if self.trace is not None:
-                        self.trace.record("spill", page=int(page),
+                        self._trace_event("spill", page=int(page),
                                           bytes=tier.entry_bytes(key))
             self.stats.host_tier_bytes = tier.bytes_used
         return freed
@@ -483,8 +612,11 @@ class ContinuousBatchingEngine:
         if self.trace is not None:
             self.trace.tick(
                 "serve", ("h2d_restore",), dt, predicted_s=pred,
-                drift=self._trace_shape_warm(("h2d_restore",)),
-                rid=rid, blocks=len(pages), bytes=tot_bytes)
+                drift=self._restore_warm,
+                seq=self._seq, rid=rid, blocks=len(pages),
+                bytes=tot_bytes)
+            # the first restore compiled its mount program inside dt
+            self._restore_warm = True
         return out
 
     def _note_restore(self, seconds):
@@ -563,7 +695,7 @@ class ContinuousBatchingEngine:
             self._rid_adapter[rid] = adapter
         self._queue.append((rid, ids))
         if self.trace is not None:
-            self.trace.record("submit", ts=self._submit_t[rid], rid=rid,
+            self._trace_event("submit", ts=self._submit_t[rid], rid=rid,
                               prompt_tokens=len(ids),
                               **(trace_fields or {}))
         return rid
@@ -609,13 +741,7 @@ class ContinuousBatchingEngine:
         admitted = self._gather_admissions()
         if not admitted:
             return []
-        now = time.perf_counter()
-        for _, rid, _, _ in admitted:
-            t0 = self._submit_t.get(rid)
-            if t0 is not None:
-                self._note_queue_wait(rid, now - t0)
-        if self.trace is not None:
-            self._trace_admits(admitted, now)
+        self._note_admitted(admitted, time.perf_counter())
         self._table_cache = None
         firsts = self._prefill_admitted(admitted)
         self.stats.prefill_syncs += 1
@@ -641,7 +767,7 @@ class ContinuousBatchingEngine:
                 self._note_ttft(rid, done_t - t0)
             self._outputs[rid] = [first]
             if self.trace is not None:
-                self.trace.record("first_token", ts=done_t, rid=rid)
+                self._trace_event("first_token", ts=done_t, rid=rid)
             self.stats.tokens += 1
             if (self.eos is not None and first == self.eos) \
                     or self.max_new <= 1:
@@ -912,7 +1038,7 @@ class ContinuousBatchingEngine:
     def _retire(self, slot):
         if self.trace is not None:
             rid = self._slot_req[slot]
-            self.trace.record(
+            self._trace_event(
                 "retire", rid=rid,
                 tokens=len(self._outputs.get(rid, ())))
         shared = self._slot_shared[slot]
@@ -1012,41 +1138,76 @@ class ContinuousBatchingEngine:
                 t[s, :len(pg)] = pg
         return t
 
-    def step(self):
-        """Admit + one decode tick. Returns number of active slots."""
-        self._admit()
+    def _step_admit(self, rec, seq):
+        """The admission phase of `step()`: (active slots, whether a
+        blocking prefill ran inside it — such a window is no decode
+        tick, so its record goes unpriced and stays out of the drift
+        ledger and the token percentiles)."""
+        before_p = self.stats.prefill_syncs
+        with _Phase("engine.admit", rec, "admit_s", seq=seq,
+                    n=len(self._queue)):
+            self._admit()
         active = [s for s in range(self.d.max_batch)
                   if self._slot_req[s] is not None]
+        return active, self.stats.prefill_syncs != before_p
+
+    def step(self):
+        """Admit + one decode tick. Returns number of active slots.
+        Inside `run()`'s per-tick loop the tick is that round's
+        horizon and fills its record (`self._open`); called on its own
+        it keeps no record and its spans carry the last round's `seq`."""
+        rec, seq = self._open, self._seq
+        active, prefilled = self._step_admit(rec, seq)
         if not active:
             return 0
-        if self._table_cache is None:        # slots changed since last tick
-            self._table_cache = self._table(self._slot_pages, self.d)
-        nxt = np.asarray(self.d.decode(self._tokens, self._lens,
-                                       self._table_cache,
-                                       kids=self._kids,
-                                       aids=self._aids))
-        self.steps += 1
-        self.stats.ticks += 1
-        self.stats.decode_syncs += 1
-        # pad ledger: the tick computed every batch row (one position
-        # each); only the active rows' positions were real work
-        self.stats.tokens_dispatched += self.d.max_batch
-        self.stats.tokens_padded += self.d.max_batch - len(active)
-        self.stats.occupancy.append(len(active) / self.d.max_batch)
-        self._note_resident()
-        for s in active:
-            rid = self._slot_req[s]
-            tok = int(nxt[s])
-            self._outputs[rid].append(tok)
-            self.stats.tokens += 1
-            if self.trace is not None:
-                self._trace_progress(rid)
-            self._lens[s] += 1
-            self._tokens[s] = tok
-            done = (self.eos is not None and tok == self.eos) or \
-                len(self._outputs[rid]) >= self.max_new
-            if done:
-                self._retire(s)
+        S = self.d.max_batch
+        with _Phase("engine.plan", rec, "plan_s", seq=seq):
+            if self._table_cache is None:    # slots changed since last tick
+                self._table_cache = self._table(self._slot_pages, self.d)
+        program = self.d.program_name("tick", 1, 1, self.d.max_pages)
+        with _Phase("engine.dispatch", rec, "dispatch_s", seq=seq,
+                    program=program):
+            nxt = self.d.decode(self._tokens, self._lens,
+                                self._table_cache, kids=self._kids,
+                                aids=self._aids)
+        if rec is not None:
+            self._horizon_dispatched(
+                rec, ("tick", 1, 1), program, k=1, w=1, t_tokens=None,
+                decode_rows=len(active), prefill_rows=0, disp_toks=S,
+                priced=not prefilled)
+        with _Phase("engine.fetch", rec, "fetch_wait_s", seq=seq,
+                    horizon=seq):
+            nxt = np.asarray(nxt)
+        if rec is not None:
+            rec["t_fetched"] = time.perf_counter()
+        with _Phase("engine.bookkeep", rec, "book_s", seq=seq,
+                    horizon=seq):
+            self.steps += 1
+            self.stats.ticks += 1
+            self.stats.decode_syncs += 1
+            # pad ledger: the tick computed every batch row (one
+            # position each); only the active rows' positions were
+            # real work
+            self.stats.tokens_dispatched += S
+            self.stats.tokens_padded += S - len(active)
+            self.stats.occupancy.append(len(active) / S)
+            self._note_resident()
+            for s in active:
+                rid = self._slot_req[s]
+                tok = int(nxt[s])
+                self._outputs[rid].append(tok)
+                self.stats.tokens += 1
+                if self.trace is not None:
+                    self._trace_progress(rid)
+                self._lens[s] += 1
+                self._tokens[s] = tok
+                done = (self.eos is not None and tok == self.eos) or \
+                    len(self._outputs[rid]) >= self.max_new
+                if done:
+                    self._retire(s)
+            if rec is not None:
+                rec.update(tokens=len(active),
+                           tokens_padded=S - len(active))
         return len(active)
 
     def run(self, step_times=None, on_sync=None):
@@ -1062,21 +1223,26 @@ class ContinuousBatchingEngine:
         loop (prompt chunks ride the decode horizon, no host-blocking
         prefill); `ragged=False` keeps the dispatch-separate
         baseline."""
-        if self.ragged:
-            # an EXPLICIT ragged=True is honored even at k_max=1 (the
-            # horizons are just one tick long): the user asked for
-            # no-stall admission, silently downgrading to the
-            # blocking-prefill per-tick loop would betray that
-            return self._run_ragged(step_times, on_sync)
-        if self.k_max <= 1:
-            return self._run_per_tick(step_times, on_sync)
-        return self._run_multi(step_times, on_sync)
+        try:
+            if self.ragged:
+                # an EXPLICIT ragged=True is honored even at k_max=1
+                # (the horizons are just one tick long): the user asked
+                # for no-stall admission, silently downgrading to the
+                # blocking-prefill per-tick loop would betray that
+                return self._run_ragged(step_times, on_sync)
+            if self.k_max <= 1:
+                return self._run_per_tick(step_times, on_sync)
+            return self._run_multi(step_times, on_sync)
+        finally:
+            self._open = None    # no round is open outside a run loop
 
     def serve_schedule(self):
         """The recent scheduling-decision trace (bounded window): one
         event per host-blocking prefill dispatch ("prefill_sync", with
-        the decode slots it stalled) and per ragged horizon
-        ("horizon", with its k/w and row mix). The
+        the decode slots it stalled) and per dispatched horizon
+        ("horizon": the horizon's one record, `_begin_round` — its k/w
+        and row mix, its program, phase times and emitted work; the
+        very dicts, so a horizon still in flight fills in later). The
         SERVE-PREFILL-STALL rule (`analysis.analyzers
         .PrefillStallAnalyzer`) audits this — a prefill_sync with
         decode_active > 0 is the stall the ragged path exists to
@@ -1084,48 +1250,40 @@ class ContinuousBatchingEngine:
         return list(self._sched_events)
 
     def _run_per_tick(self, step_times=None, on_sync=None):
-        """Legacy loop: one compiled tick, one host sync per token."""
+        """Legacy loop: one compiled tick, one host sync per token. A
+        round is `step()`: it fills the round's record itself."""
         while self._queue or any(r is not None for r in self._slot_req):
-            t0 = time.perf_counter()
-            before = self.stats.tokens
-            before_p = self.stats.prefill_syncs
-            active = self.step()
-            dt = time.perf_counter() - t0
-            if step_times is not None:
-                step_times.append(dt)
-            n = self.stats.tokens - before
-            # tiered-KV: drain any restore price — on this blocking
-            # path a restore always rides a prefill-polluted window,
-            # which the drift ledger excludes anyway
-            self._take_restore_s()
-            if self.trace is not None and active:
-                # a step that contained a blocking prefill is not a
-                # decode tick: price it as None so the drift ledger
-                # stays a tick-roofline comparison (same exclusion as
-                # token_time_s below)
-                clean = self.stats.prefill_syncs == before_p
-                warm = self._trace_shape_warm(("tick",))
-                self.trace.tick(
-                    "serve", ("tick", 1, 1), dt, ts=t0,
-                    predicted_s=(self._price_horizon(
-                        1, 1, 0, decode_rows=active)
-                                 if clean else None),
-                    predicted_serial_s=(self._price_horizon(
-                        1, 1, 0, decode_rows=active, serial=True)
-                                 if clean else None),
-                    drift=clean and warm, k=1, w=1,
-                    decode_rows=active, prefill_rows=0, tokens=n,
-                    tokens_dispatched=self.d.max_batch,
-                    tokens_padded=self.d.max_batch - active,
-                    pool=self._trace_pool_delta())
-            # token_time_s is the STEADY-STATE decode latency: a sync
-            # that contained a prefill is dominated by it (orders of
-            # magnitude more work than a tick) and would turn p99 into
-            # a prefill number — keep it out of the percentiles
-            if n and self.stats.prefill_syncs == before_p:
-                self.stats.token_time_s.extend([dt / n] * n)
-            if on_sync is not None:
-                on_sync(self)
+            rec = self._begin_round()
+            with span("engine.round", seq=rec["seq"]):
+                before_p = self.stats.prefill_syncs
+                self.step()
+                if "k" in rec:                   # the round ran a tick
+                    # a step that contained a blocking prefill is not
+                    # a decode tick: its record is unpriced and the
+                    # drift ledger stays a tick-roofline comparison
+                    # (same exclusion as token_time_s below)
+                    clean = self.stats.prefill_syncs == before_p
+                    dt = self._horizon_booked(rec, step_times, drift=clean)
+                    # token_time_s is the STEADY-STATE decode latency:
+                    # a sync that contained a prefill is dominated by
+                    # it (orders of magnitude more work than a tick)
+                    # and would turn p99 into a prefill number — keep
+                    # it out of the percentiles
+                    n = rec["tokens"]
+                    if n and clean:
+                        self.stats.token_time_s.extend([dt / n] * n)
+                else:
+                    # tiered-KV: drain any restore price — on this
+                    # blocking path a restore always rides a prefill-
+                    # polluted window, which the ledger excludes anyway
+                    self._take_restore_s()
+                    if step_times is not None:
+                        step_times.append(
+                            time.perf_counter() - rec["t_round"])
+                if on_sync is not None:
+                    with _Phase("engine.on_sync", rec, "on_sync_s",
+                                seq=rec["seq"], horizon=rec["seq"]):
+                        on_sync(self)
         return dict(self._outputs)
 
     def _budget_left(self, slot):
@@ -1166,65 +1324,66 @@ class ContinuousBatchingEngine:
             [self._budget_left(s) for s in admitted], jnp.int32))
         return tokens, lens, done, rem
 
-    def _process_block(self, meta, inflight, step_times,
-                       prefilled_since=False, trace_ev=None):
+    def _process_block(self, meta, inflight, step_times, seq,
+                       prefilled_since=False, polluted=False):
         """Fetch + bookkeep one finished horizon. Called AFTER the next
-        horizon is dispatched, so the device→host wait overlaps it."""
-        block_d, done_before_d, k, rids, t0, had_prefill = meta
-        block = np.asarray(block_d)
-        done_before = np.asarray(done_before_d)
-        self.stats.decode_syncs += 1
-        # pad ledger: the fused loop computed k*S positions; frozen
-        # rows' ticks (done_before True) were filler — the device mask
-        # is the one exact source (EOS freezes mid-horizon)
-        disp_toks = k * self.d.max_batch
-        pad_toks = int(done_before.sum())
-        self.stats.tokens_dispatched += disp_toks
-        self.stats.tokens_padded += pad_toks
-        emitted = 0
-        for s, rid in rids.items():
-            inflight[s] = max(0, inflight[s] - k)
-            if self._slot_req[s] != rid:
-                continue
-            for j in range(k):
-                if done_before[j, s]:
-                    break
-                tok = int(block[j, s])
-                self._outputs[rid].append(tok)
-                self.stats.tokens += 1
-                emitted += 1
-                if self.trace is not None:
-                    self._trace_progress(rid)
-                self._lens[s] += 1
-                self._tokens[s] = tok
-                if (self.eos is not None and tok == self.eos) or \
-                        len(self._outputs[rid]) >= self.max_new:
-                    self._retire(s)
-                    break
-        dt = time.perf_counter() - t0
-        if step_times is not None:
-            step_times.append(dt)
-        if trace_ev is not None:
-            # a window containing a prefill, the shape's first
-            # (compiling) dispatch, or another shape's compile landing
-            # inside this still-open window, is excluded from the
-            # drift ledger (same pollution rule as the token
+        horizon is dispatched, so the device→host wait overlaps it.
+        `seq` is the round this runs in (its spans nest under that
+        round's); what it measures goes into the FETCHED horizon's
+        record. `polluted`: another program's first (compiling)
+        dispatch landed inside this horizon's still-open window."""
+        block_d, done_before_d, k, rids, had_prefill, rec = meta
+        ids = {"seq": seq, "horizon": rec["seq"]}
+        with _Phase("engine.fetch", rec, "fetch_wait_s", **ids):
+            block = np.asarray(block_d)
+            done_before = np.asarray(done_before_d)
+        rec["t_fetched"] = time.perf_counter()
+        with _Phase("engine.bookkeep", rec, "book_s", **ids):
+            self.stats.decode_syncs += 1
+            # pad ledger: the fused loop computed k*S positions; frozen
+            # rows' ticks (done_before True) were filler — the device
+            # mask is the one exact source (EOS freezes mid-horizon)
+            pad_toks = int(done_before.sum())
+            self.stats.tokens_dispatched += rec["tokens_dispatched"]
+            self.stats.tokens_padded += pad_toks
+            n_emitted = 0
+            for s, rid in rids.items():
+                inflight[s] = max(0, inflight[s] - k)
+                if self._slot_req[s] != rid:
+                    continue
+                for j in range(k):
+                    if done_before[j, s]:
+                        break
+                    tok = int(block[j, s])
+                    self._outputs[rid].append(tok)
+                    self.stats.tokens += 1
+                    n_emitted += 1
+                    if self.trace is not None:
+                        self._trace_progress(rid)
+                    self._lens[s] += 1
+                    self._tokens[s] = tok
+                    if (self.eos is not None and tok == self.eos) or \
+                            len(self._outputs[rid]) >= self.max_new:
+                        self._retire(s)
+                        break
+            # a window containing a prefill, the program's first
+            # (compiling) dispatch, or another program's compile
+            # landing inside this still-open window, is excluded from
+            # the drift ledger (same pollution rule as the token
             # percentiles)
-            self.trace.tick_complete(
-                trace_ev, dt, tokens=emitted,
-                tokens_dispatched=disp_toks, tokens_padded=pad_toks,
-                drift=(not (had_prefill or prefilled_since)
-                       and trace_ev.get("warm_shape", True)
-                       and not trace_ev.get("compiled_in_window")),
-                pool=self._trace_pool_delta())
-        # steady-state decode latency only: the block's dt window spans
-        # its dispatch iteration AND the next iteration up to this
-        # call, so a prefill in either (had_prefill at dispatch,
-        # prefilled_since at processing) would make p99 a prefill
-        # number — exclude such blocks from the percentiles (see
-        # _run_per_tick)
-        if emitted and not had_prefill and not prefilled_since:
-            self.stats.token_time_s.extend([dt / emitted] * emitted)
+            dt = self._horizon_booked(
+                rec, step_times,
+                drift=not (had_prefill or prefilled_since or polluted),
+                tokens=n_emitted, tokens_padded=pad_toks)
+            # steady-state decode latency only: the block's dt window
+            # spans its dispatch iteration AND the next iteration up to
+            # this call, so a prefill in either (had_prefill at
+            # dispatch, prefilled_since at processing) would make p99
+            # a prefill number — exclude such blocks from the
+            # percentiles (see _run_per_tick)
+            if n_emitted and not had_prefill and not prefilled_since:
+                self.stats.token_time_s.extend(
+                    [dt / n_emitted] * n_emitted)
 
     def _run_multi(self, step_times=None, on_sync=None):
         """Horizon-scheduled drain: dispatch a K-tick device-resident
@@ -1241,92 +1400,96 @@ class ContinuousBatchingEngine:
         horizon can never read a page that was re-written under it."""
         S = self.d.max_batch
         pending = None               # the in-flight horizon's meta
-        pending_ev = None            # its open tick record (trace on)
         carry = None                 # device (tokens, lens, done, rem)
         inflight = [0] * S           # dispatched-not-yet-processed ticks
         while (self._queue or pending is not None
                or any(r is not None for r in self._slot_req)):
-            t0 = time.perf_counter()
-            before_p = self.stats.prefill_syncs
-            admitted = self._admit()
-            # a prefill ran iff the sync counter moved — NOT iff any
-            # request entered decode: a round whose every admission
-            # finishes AT prefill (EOS on the first token) returns an
-            # empty `admitted` but still paid a prefill forward, which
-            # must stay out of the steady-state token percentiles
-            # (same delta discipline as _run_per_tick)
-            prefilled = self.stats.prefill_syncs != before_p
-            for s in admitted:
-                # a freshly admitted slot starts from a clean device
-                # carry (_merge_carry), so ticks still in flight for
-                # the slot's PREVIOUS request must not gate its
-                # dispatch. Unreachable today (a fresh budget
-                # max_new-1 always exceeds the stale count, which is
-                # bounded by the retired request's remaining budget
-                # minus the processed block), but reset defensively:
-                # the rid check skips the old block's tokens and the
-                # max(0, ...) clamp absorbs the double subtraction.
-                inflight[s] = 0
-            carry = self._merge_carry(carry, admitted)
-            # invariant: for a live non-admitted slot, the device-side
-            # `remaining` equals budget_left - inflight exactly (both
-            # count init budget minus dispatched ticks), so a slot
-            # excluded here is always already frozen on device — its
-            # ticks in another slot's block are filler, never lost
-            # tokens
-            disp = [s for s in range(S) if self._slot_req[s] is not None
-                    and self._budget_left(s) - inflight[s] > 0]
-            meta = None
-            meta_ev = None
-            if disp:
-                k = self._horizon(disp, inflight)
-                if self._table_cache is None:
-                    self._table_cache = self._table(self._slot_pages,
-                                                    self.d)
-                tokens_d, lens_d, done_d, rem_d = carry
-                out = self.d.decode_multi(
-                    tokens_d, lens_d, self._table_cache, k,
-                    kids=self._kids, done=done_d, remaining=rem_d,
-                    eos=self.eos, aids=self._aids)
-                carry = (out.tokens, out.lens, out.done, out.remaining)
-                self.steps += k
-                self.stats.ticks += k
-                self.stats.occupancy.append(len(disp) / S)
-                self._note_resident()
-                for s in disp:
-                    inflight[s] += k
-                meta = (out.tokens_block, out.done_before, k,
-                        {s: self._slot_req[s] for s in disp}, t0,
-                        prefilled)
-                # tiered-KV: the H2D of any restore dispatched this
-                # round lands inside THIS horizon's measured window —
-                # its price rides the prediction (drained even when
-                # untraced so it can't accumulate)
-                restore_s = self._take_restore_s()
-                if self.trace is not None:
-                    meta_ev = self.trace.tick_dispatch(
-                        "serve", ("decode", k, 1), ts=t0,
-                        predicted_s=self._price_horizon(
-                            k, 1, 0, decode_rows=len(disp)) + restore_s,
-                        predicted_serial_s=self._price_horizon(
-                            k, 1, 0, decode_rows=len(disp), serial=True)
-                        + restore_s,
-                        k=k, w=1, decode_rows=len(disp), prefill_rows=0,
-                        warm_shape=self._trace_shape_warm(("decode", k)))
-                    if pending_ev is not None and \
-                            not meta_ev["warm_shape"]:
-                        # THIS dispatch's compile ran inside the
-                        # PENDING tick's still-open measured window
-                        # (processing closes after the next dispatch)
-                        pending_ev["compiled_in_window"] = True
-            if pending is not None:
-                self._process_block(pending, inflight, step_times,
-                                    prefilled_since=prefilled,
-                                    trace_ev=pending_ev)
-                if on_sync is not None:
-                    on_sync(self)
-            pending = meta
-            pending_ev = meta_ev
+            rec = self._begin_round()
+            seq = rec["seq"]
+            with span("engine.round", seq=seq):
+                with _Phase("engine.admit", rec, "admit_s", seq=seq,
+                            n=len(self._queue)):
+                    before_p = self.stats.prefill_syncs
+                    admitted = self._admit()
+                    # a prefill ran iff the sync counter moved — NOT
+                    # iff any request entered decode: a round whose
+                    # every admission finishes AT prefill (EOS on the
+                    # first token) returns an empty `admitted` but
+                    # still paid a prefill forward, which must stay out
+                    # of the steady-state token percentiles (same delta
+                    # discipline as _run_per_tick)
+                    prefilled = self.stats.prefill_syncs != before_p
+                    for s in admitted:
+                        # a freshly admitted slot starts from a clean
+                        # device carry (_merge_carry), so ticks still
+                        # in flight for the slot's PREVIOUS request
+                        # must not gate its dispatch. Unreachable today
+                        # (a fresh budget max_new-1 always exceeds the
+                        # stale count, which is bounded by the retired
+                        # request's remaining budget minus the
+                        # processed block), but reset defensively: the
+                        # rid check skips the old block's tokens and
+                        # the max(0, ...) clamp absorbs the double
+                        # subtraction.
+                        inflight[s] = 0
+                    carry = self._merge_carry(carry, admitted)
+                with _Phase("engine.plan", rec, "plan_s", seq=seq):
+                    # invariant: for a live non-admitted slot, the
+                    # device-side `remaining` equals budget_left -
+                    # inflight exactly (both count init budget minus
+                    # dispatched ticks), so a slot excluded here is
+                    # always already frozen on device — its ticks in
+                    # another slot's block are filler, never lost
+                    # tokens
+                    disp = [s for s in range(S)
+                            if self._slot_req[s] is not None
+                            and self._budget_left(s) - inflight[s] > 0]
+                    if disp:
+                        k = self._horizon(disp, inflight)
+                        if self._table_cache is None:
+                            self._table_cache = self._table(
+                                self._slot_pages, self.d)
+                meta = None
+                if disp:
+                    tokens_d, lens_d, done_d, rem_d = carry
+                    shape = ("decode", k, 1)
+                    program = self.d.program_name(*shape, self.d.max_pages)
+                    with _Phase("engine.dispatch", rec, "dispatch_s",
+                                seq=seq, program=program):
+                        out = self.d.decode_multi(
+                            tokens_d, lens_d, self._table_cache, k,
+                            kids=self._kids, done=done_d,
+                            remaining=rem_d, eos=self.eos,
+                            aids=self._aids)
+                    carry = (out.tokens, out.lens, out.done,
+                             out.remaining)
+                    self.steps += k
+                    self.stats.ticks += k
+                    self.stats.occupancy.append(len(disp) / S)
+                    self._note_resident()
+                    for s in disp:
+                        inflight[s] += k
+                    self._horizon_dispatched(
+                        rec, shape, program, k=k, w=1, t_tokens=None,
+                        decode_rows=len(disp), prefill_rows=0,
+                        disp_toks=k * S)
+                    meta = (out.tokens_block, out.done_before, k,
+                            {s: self._slot_req[s] for s in disp},
+                            prefilled, rec)
+                if pending is not None:
+                    # a first use dispatched THIS round compiled inside
+                    # the PENDING horizon's still-open measured window
+                    # (processing closes after the next dispatch)
+                    self._process_block(
+                        pending, inflight, step_times, seq,
+                        prefilled_since=prefilled,
+                        polluted=meta is not None and rec["first_use"])
+                    if on_sync is not None:
+                        prev = pending[-1]
+                        with _Phase("engine.on_sync", prev, "on_sync_s",
+                                    seq=seq, horizon=prev["seq"]):
+                            on_sync(self)
+                pending = meta
         return dict(self._outputs)
 
     # -- ragged scheduling (chunked prefill INSIDE the decode horizon) --
@@ -1340,13 +1503,7 @@ class ContinuousBatchingEngine:
         admitted = self._gather_admissions()
         if not admitted:
             return []
-        now = time.perf_counter()
-        for _, rid, _, _ in admitted:
-            t0 = self._submit_t.get(rid)
-            if t0 is not None:
-                self._note_queue_wait(rid, now - t0)
-        if self.trace is not None:
-            self._trace_admits(admitted, now)
+        self._note_admitted(admitted, time.perf_counter())
         self._table_cache = None
         plans = []
         for slot, rid, ids, pages in admitted:
@@ -1376,7 +1533,7 @@ class ContinuousBatchingEngine:
         if t0 is not None:
             self._note_ttft(rid, time.perf_counter() - t0)
         if self.trace is not None:
-            self.trace.record("first_token", rid=rid)
+            self._trace_event("first_token", rid=rid)
         self._publish_blocks(rid, slot)
         # prompt fully consumed; the emitted token is not consumed yet
         self._lens[slot] = self._prompt_len[slot]
@@ -1420,8 +1577,8 @@ class ContinuousBatchingEngine:
         pend_n = pend_n.at[idx].set(jnp.asarray(ns))
         return tokens, lens, done, rem, pend, pend_n
 
-    def _process_ragged_block(self, meta, inflight, step_times,
-                              trace_ev=None):
+    def _process_ragged_block(self, meta, inflight, step_times, seq,
+                              polluted=False):
         """Fetch + bookkeep one finished mixed horizon (called AFTER
         the next horizon is dispatched, so the device->host wait
         overlaps it). The per-tick `emitted` mask separates real
@@ -1431,74 +1588,76 @@ class ContinuousBatchingEngine:
         path is a decode-path sync by construction — chunk ticks are
         budgeted small enough to ride inside it, and their cost
         SHOULD show in the per-token tail (that honesty is what the
-        stall bench measures)."""
-        block_d, emitted_d, real_d, disp_toks, k, rids, emit_ticks, t0 = \
-            meta
-        block = np.asarray(block_d)
-        emitted = np.asarray(emitted_d)
-        # pad ledger: dispatched is the horizon's layout cost (k * the
-        # packed t_tokens bucket, or k*S*w dense); real is the device's
-        # per-tick consumed-position count — exact even when EOS froze
-        # a slot mid-horizon
-        pad_toks = disp_toks - int(np.asarray(real_d).sum())
-        self.stats.tokens_dispatched += disp_toks
-        self.stats.tokens_padded += pad_toks
-        self.stats.decode_syncs += 1
-        n_emitted = 0
-        for s, (rid, gen) in rids.items():
-            if self._slot_req[s] != rid or self._slot_gen[s] != gen:
-                # stale block of a retired/re-admitted slot: its emit
-                # ticks were already DISCARDED by the inflight reset at
-                # re-admission — subtracting them again would understate
-                # the new request's in-flight emissions, and unlike
-                # _run_multi's harmless scheduling slack, here inflight
-                # feeds _table_width's correctness-critical position
-                # bound. The GENERATION stamp matters beyond the rid:
-                # preemption (tenancy) can resume the SAME rid into the
-                # same slot while its pre-preemption block is still in
-                # flight — those tokens are regenerated post-resume and
-                # must not double-append
-                continue
-            inflight[s] = max(0, inflight[s] - emit_ticks.get(s, 0))
-            for j in range(k):
-                if not emitted[j, s]:
+        stall bench measures). `seq` and `polluted` as in
+        `_process_block`."""
+        block_d, emitted_d, real_d, k, rids, emit_ticks, rec = meta
+        ids = {"seq": seq, "horizon": rec["seq"]}
+        with _Phase("engine.fetch", rec, "fetch_wait_s", **ids):
+            block = np.asarray(block_d)
+            emitted = np.asarray(emitted_d)
+            real = np.asarray(real_d)
+        rec["t_fetched"] = time.perf_counter()
+        with _Phase("engine.bookkeep", rec, "book_s", **ids):
+            # pad ledger: dispatched is the horizon's layout cost (k *
+            # the packed t_tokens bucket, or k*S*w dense); real is the
+            # device's per-tick consumed-position count — exact even
+            # when EOS froze a slot mid-horizon
+            disp_toks = rec["tokens_dispatched"]
+            pad_toks = disp_toks - int(real.sum())
+            self.stats.tokens_dispatched += disp_toks
+            self.stats.tokens_padded += pad_toks
+            self.stats.decode_syncs += 1
+            n_emitted = 0
+            for s, (rid, gen) in rids.items():
+                if self._slot_req[s] != rid or self._slot_gen[s] != gen:
+                    # stale block of a retired/re-admitted slot: its
+                    # emit ticks were already DISCARDED by the inflight
+                    # reset at re-admission — subtracting them again
+                    # would understate the new request's in-flight
+                    # emissions, and unlike _run_multi's harmless
+                    # scheduling slack, here inflight feeds
+                    # _table_width's correctness-critical position
+                    # bound. The GENERATION stamp matters beyond the
+                    # rid: preemption (tenancy) can resume the SAME rid
+                    # into the same slot while its pre-preemption block
+                    # is still in flight — those tokens are regenerated
+                    # post-resume and must not double-append
                     continue
-                tok = int(block[j, s])
-                if len(self._outputs[rid]) == self._emit_base.get(rid, 0):
-                    # first token of THIS admission: TTFT (fresh
-                    # requests only — a resume's _submit_t is long
-                    # popped), cache publishing, the lens jump to the
-                    # admitted prompt length
-                    self._first_token(rid, s)
-                else:
-                    self._lens[s] += 1
-                self._outputs[rid].append(tok)
-                self.stats.tokens += 1
-                n_emitted += 1
-                if self.trace is not None:
-                    self._trace_progress(rid)
-                self._tokens[s] = tok
-                if (self.eos is not None and tok == self.eos) or \
-                        len(self._outputs[rid]) >= self.max_new:
-                    self._retire(s)
-                    break
-        dt = time.perf_counter() - t0
-        if step_times is not None:
-            step_times.append(dt)
-        if trace_ev is not None:
-            # a compiling dispatch (this shape's first, or another
-            # shape's compile landing inside this still-open window)
+                inflight[s] = max(0, inflight[s] - emit_ticks.get(s, 0))
+                for j in range(k):
+                    if not emitted[j, s]:
+                        continue
+                    tok = int(block[j, s])
+                    if len(self._outputs[rid]) == \
+                            self._emit_base.get(rid, 0):
+                        # first token of THIS admission: TTFT (fresh
+                        # requests only — a resume's _submit_t is long
+                        # popped), cache publishing, the lens jump to
+                        # the admitted prompt length
+                        self._first_token(rid, s)
+                    else:
+                        self._lens[s] += 1
+                    self._outputs[rid].append(tok)
+                    self.stats.tokens += 1
+                    n_emitted += 1
+                    if self.trace is not None:
+                        self._trace_progress(rid)
+                    self._tokens[s] = tok
+                    if (self.eos is not None and tok == self.eos) or \
+                            len(self._outputs[rid]) >= self.max_new:
+                        self._retire(s)
+                        break
+            # a compiling dispatch (this program's first, or another
+            # program's compile landing inside this still-open window)
             # stays out of the drift ledger; steady ragged windows ARE
             # the honest tick (chunk cost included by design — see
             # token_time_s above)
-            self.trace.tick_complete(
-                trace_ev, dt, tokens=n_emitted,
-                tokens_dispatched=disp_toks, tokens_padded=pad_toks,
-                drift=(trace_ev.get("warm_shape", True)
-                       and not trace_ev.get("compiled_in_window")),
-                pool=self._trace_pool_delta())
-        if n_emitted:
-            self.stats.token_time_s.extend([dt / n_emitted] * n_emitted)
+            dt = self._horizon_booked(
+                rec, step_times, drift=not polluted, tokens=n_emitted,
+                tokens_padded=pad_toks)
+            if n_emitted:
+                self.stats.token_time_s.extend(
+                    [dt / n_emitted] * n_emitted)
 
     def _table_width(self, live, plan, inflight):
         """Page-table columns this horizon can actually touch: the max
@@ -1558,108 +1717,97 @@ class ContinuousBatchingEngine:
         S = self.d.max_batch
         sched = self.scheduler
         pending = None               # the in-flight horizon's meta
-        pending_ev = None            # its open tick record (trace on)
         carry = None                 # (tokens, lens, done, rem, pend, pend_n)
         inflight = [0] * S           # in-flight EMISSION ticks per slot
         while (self._queue or pending is not None
                or any(r is not None for r in self._slot_req)):
-            t0 = time.perf_counter()
-            plans = self._admit_ragged()
-            for slot, _, _ in plans:
-                # fresh request in a recycled slot: stale in-flight
-                # ticks belong to the PREVIOUS request (the rid check
-                # skips its tokens) and must not gate this one
-                inflight[slot] = 0
-            carry = self._merge_carry_ragged(carry, plans)
-            live = {s: self._slot_req[s] for s in range(S)
-                    if self._slot_req[s] is not None}
-            meta = None
-            meta_ev = None
-            plan = sched.plan(live,
-                              {s: self._budget_left(s) for s in live},
-                              inflight) if live else None
-            if plan is not None:
-                if self._table_cache is None:
-                    self._table_cache = self._table(self._slot_pages,
-                                                    self.d)
-                tokens_d, lens_d, done_d, rem_d, pend_d, pend_n_d = carry
-                width = self._table_width(live, plan, inflight)
-                t_tokens = plan.t_tokens
-                if self.packed and t_tokens is None:
-                    # a custom scheduler may build HorizonPlan without
-                    # t_tokens: fall back to the dense-equivalent
-                    # bucket here so the dispatch and the pad ledger
-                    # below price the SAME layout
-                    from .decoder import pow2_at_least
-                    t_tokens = pow2_at_least(S * max(plan.w, 1))
-                out = self.d.ragged_multi(
-                    tokens_d, lens_d, self._table_cache[:, :width],
-                    plan.k, plan.w, pend_d, pend_n_d, kids=self._kids,
-                    done=done_d, remaining=rem_d, eos=self.eos,
-                    packed=self.packed, t_tokens=t_tokens,
-                    aids=self._aids)
-                carry = (out.tokens, out.lens, out.done, out.remaining,
-                         out.pend, out.pend_n)
-                self.steps += plan.k
-                self.stats.ticks += plan.k
-                self.stats.prefill_chunks += plan.n_chunks
-                self.stats.occupancy.append(len(live) / S)
-                self._note_resident()
-                for s, e in plan.emit_ticks.items():
-                    inflight[s] += e
-                # layout cost of this dispatch: the packed path pays
-                # the total-token bucket per tick, the dense twin the
-                # full [S, w] window grid
-                disp_toks = plan.k * (t_tokens if self.packed
-                                      else S * plan.w)
-                self._sched_events.append(
-                    {"kind": "horizon", "k": plan.k, "w": plan.w,
-                     "t_tokens": t_tokens if self.packed else None,
-                     "decode_rows": len(live) - plan.prefill_rows,
-                     "prefill_rows": plan.prefill_rows})
-                meta = (out.tokens_block, out.emitted, out.real,
-                        disp_toks, plan.k,
-                        {s: (rid, self._slot_gen[s])
-                         for s, rid in live.items()},
-                        plan.emit_ticks, t0)
-                # tiered-KV: restores dispatched at this round's
-                # admission are functionally ordered before this
-                # horizon's reads — their priced H2D belongs to this
-                # window's prediction (drained even when untraced)
-                restore_s = self._take_restore_s()
-                if self.trace is not None:
-                    shape = (("packed", plan.k, t_tokens)
-                             if self.packed
+            rec = self._begin_round()
+            seq = rec["seq"]
+            with span("engine.round", seq=seq):
+                with _Phase("engine.admit", rec, "admit_s", seq=seq,
+                            n=len(self._queue)):
+                    plans = self._admit_ragged()
+                    for slot, _, _ in plans:
+                        # fresh request in a recycled slot: stale
+                        # in-flight ticks belong to the PREVIOUS
+                        # request (the rid check skips its tokens) and
+                        # must not gate this one
+                        inflight[slot] = 0
+                    carry = self._merge_carry_ragged(carry, plans)
+                with _Phase("engine.plan", rec, "plan_s", seq=seq):
+                    live = {s: self._slot_req[s] for s in range(S)
+                            if self._slot_req[s] is not None}
+                    plan = sched.plan(
+                        live, {s: self._budget_left(s) for s in live},
+                        inflight) if live else None
+                    if plan is not None:
+                        if self._table_cache is None:
+                            self._table_cache = self._table(
+                                self._slot_pages, self.d)
+                        width = self._table_width(live, plan, inflight)
+                        t_tokens = plan.t_tokens
+                        if self.packed and t_tokens is None:
+                            # a custom scheduler may build HorizonPlan
+                            # without t_tokens: fall back to the
+                            # dense-equivalent bucket here so the
+                            # dispatch and the pad ledger below price
+                            # the SAME layout
+                            from .decoder import pow2_at_least
+                            t_tokens = pow2_at_least(S * max(plan.w, 1))
+                meta = None
+                if plan is not None:
+                    tokens_d, lens_d, done_d, rem_d, pend_d, pend_n_d = \
+                        carry
+                    # the jit key is (k, t-or-w, table width): a fresh
+                    # combination compiles inside this window
+                    shape = (("packed", plan.k, t_tokens) if self.packed
                              else ("ragged", plan.k, plan.w))
-                    meta_ev = self.trace.tick_dispatch(
-                        "serve", shape, ts=t0,
-                        predicted_s=self._price_horizon(
-                            plan.k, plan.w, plan.prefill_rows,
-                            decode_rows=len(live) - plan.prefill_rows)
-                        + restore_s,
-                        predicted_serial_s=self._price_horizon(
-                            plan.k, plan.w, plan.prefill_rows,
-                            decode_rows=len(live) - plan.prefill_rows,
-                            serial=True) + restore_s,
-                        k=plan.k, w=plan.w,
+                    program = self.d.program_name(*shape, width)
+                    with _Phase("engine.dispatch", rec, "dispatch_s",
+                                seq=seq, program=program):
+                        out = self.d.ragged_multi(
+                            tokens_d, lens_d,
+                            self._table_cache[:, :width], plan.k, plan.w,
+                            pend_d, pend_n_d, kids=self._kids,
+                            done=done_d, remaining=rem_d, eos=self.eos,
+                            packed=self.packed, t_tokens=t_tokens,
+                            aids=self._aids)
+                    carry = (out.tokens, out.lens, out.done,
+                             out.remaining, out.pend, out.pend_n)
+                    self.steps += plan.k
+                    self.stats.ticks += plan.k
+                    self.stats.prefill_chunks += plan.n_chunks
+                    self.stats.occupancy.append(len(live) / S)
+                    self._note_resident()
+                    for s, e in plan.emit_ticks.items():
+                        inflight[s] += e
+                    # layout cost of this dispatch: the packed path
+                    # pays the total-token bucket per tick, the dense
+                    # twin the full [S, w] window grid
+                    self._horizon_dispatched(
+                        rec, shape, program, k=plan.k, w=plan.w,
+                        t_tokens=t_tokens if self.packed else None,
                         decode_rows=len(live) - plan.prefill_rows,
                         prefill_rows=plan.prefill_rows,
-                        # the jit key is (k, w-or-t, table width): a
-                        # fresh combination compiles inside this window
-                        warm_shape=self._trace_shape_warm(
-                            shape + (width,)))
-                    if pending_ev is not None and \
-                            not meta_ev["warm_shape"]:
-                        # see _run_multi: the compile lands in the
-                        # pending tick's still-open window
-                        pending_ev["compiled_in_window"] = True
-            if pending is not None:
-                self._process_ragged_block(pending, inflight, step_times,
-                                           trace_ev=pending_ev)
-                if on_sync is not None:
-                    on_sync(self)
-            pending = meta
-            pending_ev = meta_ev
+                        disp_toks=plan.k * (t_tokens if self.packed
+                                            else S * plan.w))
+                    meta = (out.tokens_block, out.emitted, out.real,
+                            plan.k,
+                            {s: (rid, self._slot_gen[s])
+                             for s, rid in live.items()},
+                            plan.emit_ticks, rec)
+                if pending is not None:
+                    # see _run_multi: a first use dispatched this
+                    # round compiled in the pending horizon's window
+                    self._process_ragged_block(
+                        pending, inflight, step_times, seq,
+                        polluted=meta is not None and rec["first_use"])
+                    if on_sync is not None:
+                        prev = pending[-1]
+                        with _Phase("engine.on_sync", prev, "on_sync_s",
+                                    seq=seq, horizon=prev["seq"]):
+                            on_sync(self)
+                pending = meta
         return dict(self._outputs)
 
 
@@ -1790,16 +1938,17 @@ class SpeculativeEngine(ContinuousBatchingEngine):
         super()._retire(slot)
 
     def step(self):
-        self._admit()
-        active = [s for s in range(self.d.max_batch)
-                  if self._slot_req[s] is not None]
+        rec, seq = self._open, self._seq
+        active, prefilled = self._step_admit(rec, seq)
         if not active:
             return 0
         k = self.k
-        if self._table_cache is None:        # slots changed since last tick
-            self._table_cache = (self._table(self._slot_pages, self.d),
-                                 self._table(self._draft_pages, self.draft))
-        ttable, dtable = self._table_cache
+        with _Phase("engine.plan", rec, "plan_s", seq=seq):
+            if self._table_cache is None:    # slots changed since last tick
+                self._table_cache = (
+                    self._table(self._slot_pages, self.d),
+                    self._table(self._draft_pages, self.draft))
+            ttable, dtable = self._table_cache
 
         sampled = self.d.sampling is not None
 
@@ -1808,73 +1957,101 @@ class SpeculativeEngine(ContinuousBatchingEngine):
         # on device, so the k cheap ticks cost one dispatch + one fetch
         # instead of k host round-trips
         qrows = None
-        out = self.draft.decode_multi(self._tokens, self._dlens, dtable,
-                                      k, kids=self._kids,
-                                      return_logits=sampled)
-        proposals = np.asarray(out.tokens_block).T.astype(np.int32)
-        if sampled and k > 1:
-            # the k-th draft's distribution is never judged (acceptance
-            # is capped at k-1): skip its transfer
-            qp = self.draft._probs_of(out.logits_block[:k - 1])
-            qrows = np.moveaxis(qp, 0, 1)          # [S, k-1, V]
-        self.stats.ticks += k
-        self.stats.decode_syncs += 1
-
-        # target verifies [cur, d1..dk] in one forward
-        window = np.concatenate(
-            [self._tokens[:, None], proposals[:, :k]], axis=1)  # [S, k+1]
-        if sampled:
-            tgt, prows = self.d.verify(window, self._lens, ttable,
-                                       return_probs=True)
-        else:
-            tgt = self.d.verify(window, self._lens, ttable)     # [S, k+1]
-        self.target_calls += 1
-        self.steps += 1
-        self.stats.ticks += 1
-        self.stats.decode_syncs += 1
-        # pad ledger: one spec step computes k draft positions plus a
-        # (k+1)-wide verify window per batch row; rows with no request
-        # were padding (speculated-then-rejected drafts are real work,
-        # not padding — they're the engine's gamble, not the layout's)
+        # one spec step runs two programs (the draft's `decode_multi`
+        # and the target's `verify_step`): the step has a name of its own
+        program = f"spec_step_k{k}"
+        with _Phase("engine.dispatch", rec, "dispatch_s", seq=seq,
+                    program=program):
+            out = self.draft.decode_multi(self._tokens, self._dlens,
+                                          dtable, k, kids=self._kids,
+                                          return_logits=sampled)
         S_all = self.d.max_batch
-        self.stats.tokens_dispatched += S_all * (2 * k + 1)
-        self.stats.tokens_padded += (S_all - len(active)) * (2 * k + 1)
-        self.stats.occupancy.append(len(active) / self.d.max_batch)
-        self._note_resident()
-
-        for s in active:
-            rid = self._slot_req[s]
+        if rec is not None:
+            # one spec step is this engine's horizon: k draft ticks and
+            # a (k+1)-wide verify window, priced by `_price_horizon`
+            self._horizon_dispatched(
+                rec, ("tick", 1, 1), program, k=1, w=1, t_tokens=None,
+                decode_rows=len(active), prefill_rows=0,
+                disp_toks=S_all * (2 * k + 1), priced=not prefilled)
+        # the draft fetch and the verify forward both block: the host
+        # waits for the device through all of this phase
+        with _Phase("engine.fetch", rec, "fetch_wait_s", seq=seq,
+                    horizon=seq):
+            proposals = np.asarray(out.tokens_block).T.astype(np.int32)
+            if sampled and k > 1:
+                # the k-th draft's distribution is never judged
+                # (acceptance is capped at k-1): skip its transfer
+                qp = self.draft._probs_of(out.logits_block[:k - 1])
+                qrows = np.moveaxis(qp, 0, 1)          # [S, k-1, V]
+            # target verifies [cur, d1..dk] in one forward
+            window = np.concatenate(
+                [self._tokens[:, None], proposals[:, :k]],
+                axis=1)                                # [S, k+1]
             if sampled:
-                rng = np.random.default_rng(
-                    (self.d.seed * 1000003 + self.target_calls) * 4093 + s)
-                a, tok = _spec_accept(
-                    prows[s, :k],
-                    qrows[s] if qrows is not None else
-                    np.zeros((0, prows.shape[-1])),
-                    proposals[s, :k - 1], rng)
-                emitted = [int(t) for t in proposals[s, :a]] + [tok]
+                tgt, prows = self.d.verify(window, self._lens, ttable,
+                                           return_probs=True)
             else:
-                a = 0
-                while a < k - 1 and proposals[s, a] == tgt[s, a]:
-                    a += 1
-                emitted = [int(t) for t in proposals[s, :a]] + \
-                    [int(tgt[s, a])]
-            L = int(self._lens[s])
-            self._lens[s] = L + a + 1
-            self._dlens[s] = L + a + 1
-            self._tokens[s] = emitted[-1]
-            done = False
-            for t in emitted:
-                self._outputs[rid].append(t)
-                self.stats.tokens += 1
-                if self.trace is not None:
-                    self._trace_progress(rid)
-                if (self.eos is not None and t == self.eos) or \
-                        len(self._outputs[rid]) >= self.max_new:
-                    done = True      # tokens speculated past the stop
-                    break            # point are simply never appended
-            if done:
-                self._retire(s)
+                tgt = self.d.verify(window, self._lens, ttable)  # [S, k+1]
+        if rec is not None:
+            rec["t_fetched"] = time.perf_counter()
+        with _Phase("engine.bookkeep", rec, "book_s", seq=seq,
+                    horizon=seq):
+            self.stats.ticks += k
+            self.stats.decode_syncs += 1
+            self.target_calls += 1
+            self.steps += 1
+            self.stats.ticks += 1
+            self.stats.decode_syncs += 1
+            # pad ledger: one spec step computes k draft positions plus
+            # a (k+1)-wide verify window per batch row; rows with no
+            # request were padding (speculated-then-rejected drafts are
+            # real work, not padding — they're the engine's gamble, not
+            # the layout's)
+            pad_toks = (S_all - len(active)) * (2 * k + 1)
+            self.stats.tokens_dispatched += S_all * (2 * k + 1)
+            self.stats.tokens_padded += pad_toks
+            self.stats.occupancy.append(len(active) / S_all)
+            self._note_resident()
+
+            n_emitted = 0
+            for s in active:
+                rid = self._slot_req[s]
+                if sampled:
+                    rng = np.random.default_rng(
+                        (self.d.seed * 1000003 + self.target_calls)
+                        * 4093 + s)
+                    a, tok = _spec_accept(
+                        prows[s, :k],
+                        qrows[s] if qrows is not None else
+                        np.zeros((0, prows.shape[-1])),
+                        proposals[s, :k - 1], rng)
+                    emitted = [int(t) for t in proposals[s, :a]] + [tok]
+                else:
+                    a = 0
+                    while a < k - 1 and proposals[s, a] == tgt[s, a]:
+                        a += 1
+                    emitted = [int(t) for t in proposals[s, :a]] + \
+                        [int(tgt[s, a])]
+                L = int(self._lens[s])
+                self._lens[s] = L + a + 1
+                self._dlens[s] = L + a + 1
+                self._tokens[s] = emitted[-1]
+                done = False
+                n0 = len(self._outputs[rid])
+                for t in emitted:
+                    self._outputs[rid].append(t)
+                    self.stats.tokens += 1
+                    if self.trace is not None:
+                        self._trace_progress(rid)
+                    if (self.eos is not None and t == self.eos) or \
+                            len(self._outputs[rid]) >= self.max_new:
+                        done = True      # tokens speculated past the stop
+                        break            # point are simply never appended
+                n_emitted += len(self._outputs[rid]) - n0
+                if done:
+                    self._retire(s)
+            if rec is not None:
+                rec.update(tokens=n_emitted, tokens_padded=pad_toks)
         return len(active)
 
     def _price_horizon(self, k, w, prefill_rows, decode_rows=0,

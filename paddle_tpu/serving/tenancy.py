@@ -407,7 +407,7 @@ class TenantEngine(ContinuousBatchingEngine):
                 self.stats.resumes += 1
                 self._tenant_of(rid).resumes += 1
                 if self.trace is not None:
-                    self.trace.record(
+                    self._trace_event(
                         "resume", rid=rid, slot=slot,
                         tokens=len(self._outputs.get(rid, ())))
         if hasattr(sched, "note_queue"):
@@ -542,7 +542,7 @@ class TenantEngine(ContinuousBatchingEngine):
         ts = self._tenant_of(rid)
         ts.preemptions += 1
         if self.trace is not None:
-            self.trace.record("preempt", rid=rid, slot=slot,
+            self._trace_event("preempt", rid=rid, slot=slot,
                               tenant=self._rid_tenant[rid][0],
                               tokens=len(outputs), parked=parked,
                               freed=len(freed))

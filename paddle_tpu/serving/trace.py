@@ -9,10 +9,13 @@ the way it was and what one request experienced:
   mount detail) → first token → per-N-token progress → retire, keyed
   by request id;
 - **per-tick scheduler decision records** — one event per dispatched
-  horizon with its row composition (k, w, decode/prefill rows), the
+  horizon: the engine's OWN always-on horizon record (`kind="horizon"`:
+  identity, row composition, phase times, emitted work — the dict
+  `serve_schedule()` returns), taken into this log as it is with the
   roofline-PREDICTED cost (`cost_model.ragged_tick_roofline_s` per
-  tick plus one host sync) and the MEASURED wall time, with pool
-  events (CoW copies, evictions) folded in;
+  tick plus one host sync), the MEASURED wall time and the pool
+  events (CoW copies, evictions) added to it; the trainer's and the
+  restore path's records are built here (`kind="tick"`);
 - **drift accounting** — a rolling predicted-vs-measured ratio per
   dispatch shape (`drift_report()`), the data behind the Graph
   Doctor's `ROOFLINE-DRIFT` rule and `debug.serving_report()`: a
@@ -32,11 +35,15 @@ tracing off every hook is a dead `if engine.trace is not None` branch
 total_events`). Memory is O(1): events and per-shape drift samples
 live in bounded deques.
 
-Timestamps are raw `time.perf_counter()` seconds — the same clock the
-`profiler` module stamps `RecordEvent` regions with — so
+Timestamps are raw `time.perf_counter()` seconds — the clock the
+`profiler` module's own host timeline uses — so
 `export_chrome_trace(path, recorders=..., profiler=...)` merges
 request spans, tick records and profiler regions onto ONE
-Perfetto-viewable timeline with no re-basing. Token VALUES are never
+Perfetto-viewable timeline with no re-basing. The device trace has
+another clock: there the engine's `profiler.span`s (`engine.round` and
+its children) stand for the same rounds, and every record here — a
+horizon and each lifecycle event — carries the `seq` of its round, so
+the two join by id and no clock is converted. Token VALUES are never
 recorded (counts and ids only): traces are shareable without leaking
 prompt content.
 """
@@ -97,17 +104,27 @@ class FlightRecorder:
     # ------------------------------------------------- scheduler ticks
 
     def tick_dispatch(self, track, shape, predicted_s=None, ts=None,
-                      **fields):
+                      ev=None, **fields):
         """Open one scheduler decision record at dispatch time.
         `track` names the timeline ("serve"/"train"), `shape` the
         dispatch shape the drift accounting keys on (e.g.
         ("ragged", k, w)), `predicted_s` the roofline-priced horizon
-        cost. Complete it with `tick_complete` once the measured wall
-        time is known (the engines call complete at block-processing
-        time, where the fetch-overlap window closes)."""
-        return self.record("tick", ts=ts, track=str(track),
-                           shape=list(shape), predicted_s=predicted_s,
-                           measured_s=None, **fields)
+        cost. `ev` is the caller's own record of the horizon (the
+        engines' always-on "horizon" dict): it is taken into the log
+        as it is, with these fields added, so one horizon is one dict;
+        without it a "tick" record is built here. Complete it with
+        `tick_complete` once the measured wall time is known (the
+        engines call complete at block-processing time, where the
+        fetch-overlap window closes)."""
+        fields.update(track=str(track), shape=list(shape),
+                      predicted_s=predicted_s, measured_s=None)
+        if ev is None:
+            return self.record("tick", ts=ts, **fields)
+        ev["ts"] = time.perf_counter() if ts is None else float(ts)
+        ev.update(fields)
+        self.events.append(ev)
+        FlightRecorder.total_events += 1
+        return ev
 
     def tick_complete(self, ev, measured_s, drift=True, **fields):
         """Close a dispatched tick record with its measured wall
@@ -231,8 +248,10 @@ class FlightRecorder:
         ticks = []
         rid_tenant = {}                  # rid -> tenant (span grouping)
         for ev in self.events:
-            kind = ev["kind"]
-            if kind == "tick":
+            if "track" in ev:
+                # a scheduler decision record (`tick_dispatch` gave it
+                # its track): an engine's own "horizon" dict, or a
+                # "tick" built here (trainer, h2d_restore)
                 ticks.append(ev)
             elif "rid" in ev:
                 spans.setdefault(ev["rid"], []).append(ev)

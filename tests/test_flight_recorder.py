@@ -65,11 +65,12 @@ def test_traced_streams_byte_identical_under_churn(tiny_model, seed):
                              k_max=k_max, chunk_tokens=8,
                              trace=FlightRecorder())
         assert ragged == base, (seed, k_max, "ragged traced")
-        # the recorders really recorded: full lifecycles + priced ticks
+        # the recorders really recorded: full lifecycles + priced
+        # ticks (a serving tick is the engine's own horizon record)
         for eng in (eb, er):
             kinds = {ev["kind"] for ev in eng.trace.events}
             assert {"submit", "admit", "first_token",
-                    "retire", "tick"} <= kinds
+                    "retire", "horizon"} <= kinds
 
 
 def test_tracing_off_is_dead_branch(tiny_model):
@@ -160,8 +161,10 @@ def test_tick_records_price_and_measure(tiny_model):
     # cold dispatch compiled inside, stay OUT of the drift ledger)
     outs, eng = _stream(tiny_model, [list(range(1, 30)), [3, 4, 5]],
                         24, k_max=4, chunk_tokens=8, trace=rec)
-    ticks = [ev for ev in rec.events if ev["kind"] == "tick"]
+    ticks = [ev for ev in rec.events if ev["kind"] == "horizon"]
     assert ticks
+    # one horizon, one dict: the recorder's tick IS the engine's record
+    assert all(a is b for a, b in zip(ticks, eng.serve_schedule()))
     for ev in ticks:
         assert ev["track"] == "serve"
         # the default engine dispatches the PACKED token-stream layout
@@ -183,7 +186,7 @@ def test_tick_records_price_and_measure(tiny_model):
     # summary view
     s = rec.summary()
     assert s["events"] == len(rec.events)
-    assert s["kinds"]["tick"] == len(ticks)
+    assert s["kinds"]["horizon"] == len(ticks)
     assert s["meta"]["engine"] == "ContinuousBatchingEngine"
 
 
@@ -195,7 +198,7 @@ def test_drift_ledger_excludes_prefill_polluted_blocks(tiny_model):
     rec = FlightRecorder()
     outs, eng = _stream(tiny_model, [[3, 141, 59], [7, 8, 9, 10]],
                         12, k_max=4, ragged=False, trace=rec)
-    ticks = [ev for ev in rec.events if ev["kind"] == "tick"]
+    ticks = [ev for ev in rec.events if ev["kind"] == "horizon"]
     assert ticks and all(ev["shape"][0] == "decode" for ev in ticks)
     ledger_n = sum(d["n"] for d in rec.drift_report())
     assert ledger_n < len(ticks) or eng.stats.prefill_syncs == 0
@@ -223,9 +226,9 @@ def test_serving_report_front_door(tiny_model):
     assert entry["schedule"]["stalled_prefill_syncs"] == 0
     assert entry["drift"] and entry["drifting_shapes"]
     assert entry["trace_events"] == len(rec.events)
-    # the pad ledger rides the tick records into the report: the
-    # before/after evidence for the packed ragged layout comes from
-    # our own tracer
+    # the pad ledger rides the engine's horizon records into the
+    # report: the before/after evidence for the packed ragged layout
+    # comes from our own records
     assert entry["pad"]["tokens_dispatched"] > 0
     assert entry["pad"]["pad_fraction"] == pytest.approx(
         entry["pad"]["tokens_padded"] / entry["pad"]["tokens_dispatched"],
@@ -305,8 +308,9 @@ def test_speculative_engine_traces_lifecycle_and_ticks(tiny_model):
     res = eng.run()
     assert len(res[rid]) == 8
     kinds = {ev["kind"] for ev in rec.events}
-    assert {"submit", "admit", "first_token", "retire", "tick"} <= kinds
-    ticks = [ev for ev in rec.events if ev["kind"] == "tick"]
+    assert {"submit", "admit", "first_token", "retire",
+            "horizon"} <= kinds
+    ticks = [ev for ev in rec.events if ev["kind"] == "horizon"]
     assert ticks and all(ev["measured_s"] > 0 for ev in ticks)
     assert rec.meta["engine"] == "SpeculativeEngine"
     # a spec step is priced as its REAL work (k draft ticks + one
