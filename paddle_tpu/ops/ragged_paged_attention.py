@@ -138,11 +138,14 @@ def _dequant_page_int4(packed, gscale, heads):
 _UNROLL_PAGES = 32
 
 
-def _ragged_ref(q, k_pages, v_pages, page_table, start, scale,
+def _ragged_ref(q, k_pages, v_pages, page_table, qpos, scale,
                 k_scale=None, v_scale=None, int4=False):
     """jnp reference: the kernel's page loop as an unrolled loop (small
     tables) or a lax.scan — the same per-page update in the same order
-    either way (see _page_update). With an int8 pool, `k_scale`/
+    either way (see _page_update). `qpos` [n, W] is every query's
+    absolute position: `start + arange(W)` for the dense entry point,
+    the stream's own `pos` laid out by row for the packed one — the ONE
+    program both layouts run. With an int8 pool, `k_scale`/
     `v_scale` [P, ps] carry the per-token write-time scales; the gather
     stays int8 and only one page dequantizes per step. With an int4
     pool (`int4=True`) the payload is nibble-packed [P, ps, PB] and
@@ -154,8 +157,8 @@ def _ragged_ref(q, k_pages, v_pages, page_table, start, scale,
     MP = page_table.shape[1]
     safe = jnp.maximum(page_table, 0)
     quantized = k_scale is not None and not int4
-    # named for the trace: the gather of every row's whole page table
-    # is most of a serving tick's device time (PERF.md, S1b)
+    # named for the trace: ONE copy of each row's pages, whatever the
+    # number of queries in the row's window
     with jax.named_scope("paged_gather"):
         if int4:
             # packed payload [n, MP, ps, PB] -> per-page [MP][n, ps, PB];
@@ -173,7 +176,7 @@ def _ragged_ref(q, k_pages, v_pages, page_table, start, scale,
                 ksg = jnp.moveaxis(k_scale[safe], 1, 0)
                 vsg = jnp.moveaxis(v_scale[safe], 1, 0)
     qf = (q.astype(jnp.float32) * scale).transpose(0, 2, 1, 3)  # [n,H,W,D]
-    qpos = (start[:, None] + jnp.arange(W))[:, None, :]         # [n,1,W]
+    qpos = qpos[:, None, :]                                     # [n,1,W]
 
     def page_step(carry, inputs):
         m, s, acc = carry
@@ -228,7 +231,39 @@ def _ragged_ref(q, k_pages, v_pages, page_table, start, scale,
 # lowering, and the bit-identity contract with the interpret-mode
 # kernel (which runs compiled) is pinned at the compiled semantics.
 # Inside a jitted caller (the decoder's programs) this inlines away.
-_ragged_ref_jit = jax.jit(_ragged_ref, static_argnames=("scale", "int4"))
+@functools.partial(jax.jit, static_argnames=("scale", "int4"))
+def _dense_ref(q, k_pages, v_pages, page_table, start, scale,
+               k_scale=None, v_scale=None, int4=False):
+    """The dense entry point's reference: row i's window sits at
+    positions start[i] .. start[i] + W - 1."""
+    qpos = start[:, None] + jnp.arange(q.shape[1], dtype=jnp.int32)
+    return _ragged_ref(q, k_pages, v_pages, page_table, qpos, scale,
+                       k_scale=k_scale, v_scale=v_scale, int4=int4)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "int4"))
+def _packed_ref(q, k_pages, v_pages, page_table, row_ids, pos, scale,
+                window, k_scale=None, v_scale=None, int4=False):
+    """The packed entry point's reference: lay the stream out BY ROW and
+    run the dense reference once. A row's tokens are contiguous in the
+    stream, so row r's window is the `window` stream slots from its
+    first token on: two gathers of q-sized arrays (in, out) and ONE
+    gather of the row's pages, shared by every token of the row — never
+    a per-token copy of the page table or of the pages. Window slots
+    past a row's last token hold the stream's next tokens (another
+    row's, or the padded tail): row-local garbage, like the dense
+    path's padded queries, that no token gathers back."""
+    T = q.shape[0]
+    n = page_table.shape[0]
+    # first stream slot of each row (0 for a row with no token: its
+    # window is computed and never read)
+    first = jnp.argmax(row_ids[None, :] == jnp.arange(n)[:, None],
+                       axis=1).astype(jnp.int32)               # [n]
+    slot = jnp.minimum(first[:, None] + jnp.arange(window), T - 1)
+    out = _ragged_ref(q[slot], k_pages, v_pages, page_table, pos[slot],
+                      scale, k_scale=k_scale, v_scale=v_scale, int4=int4)
+    within = jnp.clip(jnp.arange(T) - first[row_ids], 0, window - 1)
+    return out[row_ids, within]
 
 
 def _ragged_kernel(pt_ref, start_ref, q_ref, k_ref, v_ref, *rest,
@@ -439,7 +474,8 @@ def _packed_kernel_call(q2, k_pages, v_pages, page_table, row_ids, pos,
 
 def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
                                   row_ids, pos, scale=None,
-                                  use_kernel=False, interpret=None):
+                                  use_kernel=False, interpret=None,
+                                  window=None):
     """PACKED-layout causal attention over paged KV: q [T, H, D] is a
     flat stream of new tokens — token t belongs to batch row
     `row_ids[t]` (its row in `page_table` [n, max_pages]) and sits at
@@ -448,17 +484,25 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
     batch pays exactly its token total (the Ragged Paged Attention
     layout, arxiv 2604.15464 — pay for tokens, not windows).
 
-    Per-token math is EXACTLY the dense path's: each token runs the
-    same per-page `_page_update` walk over its row's pages that a W=1
-    window would (padded internally to the same 2-wide window the
-    dense W=1 path uses), so a token's output is bit-identical to the
-    dense `ragged_paged_attention` computing the same position inside
-    any window width — the packed/dense byte-identity the serving
-    engine's A/B twin pins. The Pallas kernel scalar-prefetches
-    `row_ids` and `pos` next to the page table and resolves
-    `page_table[row_ids[t], j]` inside the BlockSpec index maps (see
-    `_packed_kernel_call`). int8/int4 pools pass as (pages, scales)
-    tuples exactly like the dense entry point. Returns [T, H, D]."""
+    The stream is ROW-CONTIGUOUS: a row's tokens follow one another
+    (rows in any order, a padded tail under any row id). `window`
+    (static; default T) bounds the tokens one row may hold — a token
+    further than that from its row's first gets garbage, which is what
+    the decoder's padded tail is for.
+
+    Per-token math is EXACTLY the dense path's: the reference gathers
+    each ROW's pages once, lays the row's tokens out as that row's
+    window and runs the dense reference's per-page `_page_update` walk
+    (`_packed_ref`; a 1-wide window is padded to the same 2-wide one
+    the dense W=1 path uses), so a token's output is bit-identical to
+    the dense `ragged_paged_attention` computing the same position
+    inside any window width — the packed/dense byte-identity the
+    serving engine's A/B twin pins. The Pallas kernel walks per token
+    instead: it scalar-prefetches `row_ids` and `pos` next to the page
+    table and resolves `page_table[row_ids[t], j]` inside the BlockSpec
+    index maps (see `_packed_kernel_call`). int8/int4 pools pass as
+    (pages, scales) tuples exactly like the dense entry point. Returns
+    [T, H, D]."""
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
     row_ids = jnp.asarray(row_ids, jnp.int32)
@@ -469,20 +513,24 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
         k_pages, ks = k_pages
         v_pages, vs = v_pages
         int4 = k_pages.dtype == jnp.uint8    # nibble-packed payload
-    # the same 2-wide padding the dense W=1 path uses (degenerate
-    # matvec lowering drifts a ulp at W=1): one zero query per token,
-    # discarded — bit-identity with the dense path rides on both
-    # layouts running the identical W=2 program shape per position
-    q2 = jnp.stack([q, jnp.zeros_like(q)], axis=1)      # [T, 2, H, D]
+    T = q.shape[0]
+    # at least 2 wide: the degenerate matvec lowering of a 1-wide
+    # window drifts a ulp, so the dense W=1 path pads to 2 as well and
+    # both layouts run the identical program shape per position
+    window = max(2, T if window is None else min(int(window), T))
+
+    def reference():
+        return _packed_ref(q, k_pages, v_pages, page_table, row_ids,
+                           pos, scale=float(scale), window=window,
+                           k_scale=ks, v_scale=vs, int4=int4)
+
     if not use_kernel:
-        # reference: per-token table rows via one gather; the page walk
-        # is the dense reference's (`_page_update` via _ragged_ref)
-        table_tok = page_table[row_ids]                 # [T, max_pages]
-        return _ragged_ref_jit(q2, k_pages, v_pages, table_tok, pos,
-                               scale=float(scale), k_scale=ks,
-                               v_scale=vs, int4=int4)[:, 0]
+        return reference()
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
+    # the kernel's grid is per token: one zero query beside each, for
+    # the same 2-wide shape
+    q2 = jnp.stack([q, jnp.zeros_like(q)], axis=1)      # [T, 2, H, D]
     try:
         return _packed_kernel_call(q2, k_pages, v_pages, page_table,
                                    row_ids, pos, scale, interpret,
@@ -490,10 +538,7 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
                                    int4=int4)[:, 0]
     except Exception as e:
         kernel_fallback("ragged_paged_attention_packed", e)
-        table_tok = page_table[row_ids]
-        return _ragged_ref_jit(q2, k_pages, v_pages, table_tok, pos,
-                               scale=float(scale), k_scale=ks,
-                               v_scale=vs, int4=int4)[:, 0]
+        return reference()
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, start,
@@ -535,9 +580,9 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, start,
         v_pages, vs = v_pages
         int4 = k_pages.dtype == jnp.uint8    # nibble-packed payload
     if not use_kernel:
-        return _ragged_ref_jit(q, k_pages, v_pages, page_table, start,
-                               scale=float(scale), k_scale=ks,
-                               v_scale=vs, int4=int4)
+        return _dense_ref(q, k_pages, v_pages, page_table, start,
+                          scale=float(scale), k_scale=ks, v_scale=vs,
+                          int4=int4)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     try:
@@ -546,6 +591,6 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, start,
                                    k_scale=ks, v_scale=vs, int4=int4)
     except Exception as e:
         kernel_fallback("ragged_paged_attention", e)
-        return _ragged_ref_jit(q, k_pages, v_pages, page_table, start,
-                               scale=float(scale), k_scale=ks,
-                               v_scale=vs, int4=int4)
+        return _dense_ref(q, k_pages, v_pages, page_table, start,
+                          scale=float(scale), k_scale=ks, v_scale=vs,
+                          int4=int4)

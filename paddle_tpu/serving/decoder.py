@@ -85,6 +85,15 @@ def pow2_at_least(n):
     return p
 
 
+def packed_window(w, t):
+    """Static bound on the tokens ONE row holds in a packed stream of
+    `t` whose rows carry at most `w` each: the pow2 bucket of w, so the
+    attention's per-row window (`ops.ragged_paged_attention_packed`)
+    is at most twice the widest row and never wider than the stream.
+    Part of a packed program's key and name."""
+    return min(pow2_at_least(w), int(t))
+
+
 def _ln(x, w, b):
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, -1, keepdims=True)
@@ -302,8 +311,9 @@ def _lora_delta(wl, y, aids):
     [n_a, h, r] / `wl["lora_B"]` [n_a, r, 3*H*D] this layer's stacked
     adapter banks (alpha/r scaling folded into B at attach time).
 
-    The adapter is resolved by a per-TOKEN gather — exactly how the
-    packed layout resolves pages via `row_ids` — so each token's delta
+    The adapter is resolved by a per-TOKEN gather of the small banks
+    through `row_ids` (pages are NOT resolved so: the packed attention
+    gathers them once a row) — so each token's delta
     is (y_t @ A_{a_t}) @ B_{a_t}: row-local math that never sees batch
     composition. A mixed-adapter horizon therefore emits bit-identical
     streams to per-adapter engines over the same bank (test-pinned),
@@ -507,12 +517,12 @@ class PagedGPTDecoder:
         # it compiled a program of its own before as well), so that each
         # program carries its whole key in its name
         self._raggeds = {}    # (k, w, width) -> jitted mixed ragged horizon
-        self._packeds = {}    # (k, t, width) -> jitted PACKED mixed horizon
+        self._packeds = {}    # (k, t, window, width) -> jitted PACKED horizon
         # (w rides as a traced scalar — per-dispatch width changes
         # never compile a new program; dispatches bucket by total
         # token count t alone)
         self._used = set()    # program names dispatched so far (first_use)
-        self._packed_prefills = {}   # t -> jitted packed prefill
+        self._packed_prefills = {}   # (t, window) -> jitted packed prefill
         self._verify = None   # jitted lazily (speculative decoding only)
         self._probs = None    # jitted lazily (sampled speculation)
         self._suffix_prefill = None   # jitted lazily (chunked prefill)
@@ -546,18 +556,20 @@ class PagedGPTDecoder:
 
     @staticmethod
     @functools.lru_cache(maxsize=None)
-    def program_name(kind, k, x, width):
+    def program_name(kind, k, x, width, window=None):
         """The ONE name of a horizon's program, made of the key the
         decoder memoizes it by — the dispatch shape (`kind`, k ticks,
-        `x` = the packed token bucket t or the ragged window w) and the
-        page table's `width` in columns: `jit_<name>` on a trace's "XLA
-        Modules" line, `program` on the engine's `engine.dispatch` span
+        `x` = the packed token bucket t or the ragged window w), the
+        page table's `width` in columns and, packed, the `window` one
+        row's tokens are laid out in (`packed_window`): `jit_<name>` on
+        a trace's "XLA Modules" line, `program` on the engine's
+        `engine.dispatch` span
         and on the horizon's record, and what `first_use` is asked
         about. Kinds: "packed", "ragged", "decode" (`decode_multi`, on
         the whole table) and "tick" (the per-tick `decode`). Cached: a
         round pays a lookup, not a format."""
         if kind == "packed":
-            return f"packed_multi_k{k}_t{x}_p{width}"
+            return f"packed_multi_k{k}_t{x}_w{window}_p{width}"
         if kind == "ragged":
             return f"ragged_multi_k{k}_w{x}_p{width}"
         return f"decode_multi_k{k}" if kind == "decode" else "decode_step"
@@ -575,8 +587,8 @@ class PagedGPTDecoder:
         0.0). `alpha` scales every delta by alpha/r, folded into B at
         attach time (default: alpha == r, scale 1).
 
-        Rows gather the bank per TOKEN (`_lora_delta` — the packed
-        layout's row-id idiom applied to weights), so one ragged
+        Rows gather the bank per TOKEN (`_lora_delta`, through the
+        packed layout's row ids), so one ragged
         horizon serves every variant through one compiled program; the
         jit wrappers retrace automatically (the weights pytree gains
         the bank leaves and an `aids` input). Per-adapter
@@ -1104,14 +1116,17 @@ class PagedGPTDecoder:
         return (outs[0], outs[1], outs[2], tokens, lens, done, remaining,
                 pend, pend_n, k_pages, v_pages)
 
-    def _packed_layer(self, rows, pos, pids, offs, table, aids=None):
+    def _packed_layer(self, rows, pos, pids, offs, table, aids=None,
+                      window=None):
         """ONE transformer layer over the PACKED token stream: x is
         [T, h] flat new tokens (token t of batch row `rows[t]` at
         absolute position `pos[t]`); K/V writes land at (pids, offs) —
         the caller routes padded/frozen/overflow tokens to scratch —
         and attention runs through the packed ragged primitive
-        (`ops.ragged_paged_attention_packed`), which resolves each
-        token's pages via its row id. Per-token math is the dense
+        (`ops.ragged_paged_attention_packed`), which gathers each
+        ROW's pages once and lays the row's tokens out as one window
+        of at most `window` queries over them. Per-token math is the
+        dense
         `_windowed_layer`'s exactly (row-local matmuls, the same
         per-page attention walk), so a real position's bytes are
         bit-identical packed vs dense — the A/B-twin guarantee."""
@@ -1126,8 +1141,7 @@ class PagedGPTDecoder:
             qkv = _mm_heads(y, wl["qkv_w"], wl["qkv_b"],
                             quant)                       # [T, 3, H, D]
             if aids is not None:
-                # per-token adapter resolution via the row id — the
-                # same idiom the packed attention uses for pages
+                # per-token adapter resolution via the row id
                 qkv = qkv + _lora_delta(wl, y, aids[rows]).reshape(
                     T, 3, H, D).astype(qkv.dtype)
             q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
@@ -1137,7 +1151,7 @@ class PagedGPTDecoder:
             from ..ops.ragged_paged_attention import \
                 ragged_paged_attention_packed
             attn = ragged_paged_attention_packed(
-                q, kp, vp, table, rows, pos,
+                q, kp, vp, table, rows, pos, window=window,
                 use_kernel=self.use_kernel).astype(x.dtype)
             x = x + _mm(attn.reshape(T, H * D), wl["proj_w"],
                         wl["proj_b"], quant)
@@ -1151,12 +1165,14 @@ class PagedGPTDecoder:
 
     def _packed_forward(self, weights, k_pages, v_pages, ptok, pos, rows,
                         write_ok, table, last_idx, sample_pos, kids,
-                        live, aids=None):
+                        live, aids=None, window=None):
         """The shared PACKED forward: consume the flat token stream
         `ptok` [T] (token t = row `rows[t]`, position `pos[t]`),
         writing real tokens' K/V into the pages (`write_ok` [T] False
         routes to scratch: padded tail, frozen rows, table overflow)
-        and attending each token over its own row's pages. `last_idx`
+        and attending each token over its own row's pages (a row's
+        tokens are contiguous in the stream, at most `window` of them:
+        None = the whole stream). `last_idx`
         [S] indexes each row's LAST stream token (garbage for rows with
         no tokens — masked by `live`), whose hidden state prices the
         row's logits; `sample_pos` [S] is the sampling position
@@ -1169,16 +1185,14 @@ class PagedGPTDecoder:
         x = (self.wte[ptok] +
              self.wpe[jnp.clip(pos, 0, cfg.max_seq_len - 1)]
              ).astype(self.compute_dtype)                 # [T, h]
-        pids = jnp.take_along_axis(
-            table[rows], jnp.minimum(pos // ps, MP - 1)[:, None],
-            axis=1)[:, 0]                                 # [T]
+        pids = table[rows, jnp.minimum(pos // ps, MP - 1)]    # [T]
         pids = jnp.where(write_ok, pids, self.num_pages - 1)
         offs = pos % ps
 
         with jax.named_scope("layers"):
             x, (k_pages, v_pages) = jax.lax.scan(
                 self._packed_layer(rows, pos, pids, offs, table,
-                                   aids=aids),
+                                   aids=aids, window=window),
                 x, (weights, k_pages, v_pages))
         x = _ln(x, self.ln_f_w, self.ln_f_b)
         last = x[jnp.clip(last_idx, 0, x.shape[0] - 1)]   # [S, h]
@@ -1193,7 +1207,7 @@ class PagedGPTDecoder:
 
     def _packed_multi_step(self, weights, k_pages, v_pages, tokens, lens,
                            table, kids, done, remaining, eos, pend,
-                           pend_n, w, aids=None, *, k, t):
+                           pend_n, w, aids=None, *, k, t, window=None):
         """K MIXED ticks over the PACKED [t] token stream — the
         tentpole layout (Ragged Paged Attention, arxiv 2604.15464): a
         tick's stream concatenates every live row's new tokens (decode
@@ -1202,9 +1216,11 @@ class PagedGPTDecoder:
         padding — the dense twin (`_ragged_multi_step`) dispatches
         S*w positions per tick, this dispatches at most t, bucketed by
         total token count alone. `w` is a TRACED scalar (the per-row
-        chunk cap): per-dispatch width changes recompile nothing; the
-        jit key is (k, t) — fewer compiled variants than the dense
-        (k, w) grid by construction. The layout (cumsum + searchsorted
+        chunk cap); `window` (static, >= w; None = t) is the bound the
+        attention lays one row's tokens out in — `packed_window(w, t)`,
+        the pow2 bucket of w, so the jit key is (k, t, window): w's
+        pow2 steps between t/S and t, at most log2(S)+1 per t. The
+        layout (cumsum + searchsorted
         over per-row token counts) is built on device each tick from
         the carry, so the program stays one host-sync-free lax.scan
         (SERVE-HOST-SYNC-DECODE gates it like the dense twin).
@@ -1249,7 +1265,8 @@ class PagedGPTDecoder:
             live = ~done & (nl > 0)
             nxt, kp, vp = self._packed_forward(
                 weights, kp, vp, ptok, pos, rows, write_ok, table,
-                last_idx, true - 1, kids, live, aids=aids)
+                last_idx, true - 1, kids, live, aids=aids,
+                window=window)
             emit = ~done & (pend_n <= w)
             nxt = jnp.where(emit, nxt, tokens)
             rem = jnp.where(emit, remaining - 1, remaining)
@@ -1277,7 +1294,7 @@ class PagedGPTDecoder:
 
     def _prefill_packed_step(self, weights, k_pages, v_pages, ptok, pos,
                              rows, write_ok, table, last_idx, sample_pos,
-                             kids, live, aids=None):
+                             kids, live, aids=None, *, window=None):
         """PACKED chunked prefill: one forward over the flat suffix
         stream of a whole admission batch — mixed suffix lengths share
         ONE compiled program per total-token bucket instead of one per
@@ -1287,7 +1304,7 @@ class PagedGPTDecoder:
         return self._packed_forward(weights, k_pages, v_pages, ptok,
                                     pos, rows, write_ok, table,
                                     last_idx, sample_pos, kids, live,
-                                    aids=aids)
+                                    aids=aids, window=window)
 
     # -- host-side API -----------------------------------------------------
 
@@ -1392,8 +1409,9 @@ class PagedGPTDecoder:
         """PACKED prefill dispatch (see `prefill_suffix_batch`): the
         layout — flat tokens, per-token row ids and positions — is
         built host-side (all lengths are known here), bucketed to a
-        pow2 total-token count, and jitted once per bucket
-        (`_packed_prefills`)."""
+        pow2 total-token count and a pow2 longest suffix (the
+        attention's per-row window, `packed_window`), and jitted once
+        per pair (`_packed_prefills`)."""
         results = [None] * len(requests)
         if kids is None:
             kids = list(range(len(requests)))
@@ -1403,8 +1421,10 @@ class PagedGPTDecoder:
         todo = list(enumerate(requests))
         while todo:
             chunk, todo = todo[:S], todo[S:]
-            t = pow2_at_least(sum(len(np.asarray(ids).reshape(-1))
-                                  for _, (ids, _, _) in chunk))
+            counts = [len(np.asarray(ids).reshape(-1))
+                      for _, (ids, _, _) in chunk]
+            t = pow2_at_least(sum(counts))
+            window = packed_window(max(counts), t)
             ptok = np.zeros(t, np.int32)
             pos = np.zeros(t, np.int32)
             rows = np.zeros(t, np.int32)
@@ -1431,12 +1451,14 @@ class PagedGPTDecoder:
                 kd[r] = kids[i]
                 ad[r] = aids[i]
                 cur += n
-            fn = self._packed_prefills.get(t)
+            fn = self._packed_prefills.get((t, window))
             if fn is None:
-                fn = _named_jit(self._prefill_packed_step,
-                                f"prefill_packed_t{t}",
-                                donate_argnums=(1, 2))
-                self._packed_prefills[t] = fn
+                fn = _named_jit(
+                    functools.partial(self._prefill_packed_step,
+                                      window=window),
+                    f"prefill_packed_t{t}_w{window}",
+                    donate_argnums=(1, 2))
+                self._packed_prefills[t, window] = fn
             self._draws += 1
             call = (jnp.asarray(ptok), jnp.asarray(pos),
                     jnp.asarray(rows), jnp.asarray(ok), jnp.asarray(tbl),
@@ -1791,12 +1813,14 @@ class PagedGPTDecoder:
                 # the PACKED horizon program: t = the pow2 total-token
                 # bucket of one full-chunk prefill row riding next to
                 # S-1 decode rows (the canonical mixed tick); w is a
-                # TRACED input, not part of the program identity
+                # TRACED input, its pow2 bucket the attention's static
+                # per-row window, as `ragged_multi` keys it
                 t = pow2_at_least(S - 1 + rw)
                 w_in = jnp.asarray(rw, jnp.int32)
                 inputs.append(("w", w_in))
                 fn = jax.jit(functools.partial(self._packed_multi_step,
-                                               k=rk, t=t),
+                                               k=rk, t=t,
+                                               window=packed_window(rw, t)),
                              donate_argnums=(1, 2) if donate else ())
                 traced = fn.trace(W_ALL, self.k_pages,
                                   self.v_pages, tokens, lens, table,
@@ -1832,7 +1856,8 @@ class PagedGPTDecoder:
                           ("kids", kids), ("live", live)]
                 if aid_in is not None:
                     inputs.append(("aids", aid_in))
-                fn = jax.jit(self._prefill_packed_step,
+                fn = jax.jit(functools.partial(self._prefill_packed_step,
+                                               window=packed_window(W, t)),
                              donate_argnums=(1, 2) if donate else ())
                 traced = fn.trace(W_ALL, self.k_pages,
                                   self.v_pages, ptok, pos, rows, ok,
@@ -2039,10 +2064,10 @@ class PagedGPTDecoder:
         PACKED (the default, `packed=None` -> the decoder's `packed`
         flag): each tick dispatches the flat [t_tokens] token stream
         (`_packed_multi_step`) — decode rows pay ONE token, not a
-        w-wide window — jitted per (k, t_tokens) with w riding as a
-        traced scalar, so dispatches bucket by TOTAL token count
-        (pow2; the scheduler's `HorizonPlan.t_tokens` prices it) and
-        per-dispatch width changes never compile a new variant.
+        w-wide window — jitted per (k, t_tokens, `packed_window(w,
+        t_tokens)`, table width) with w riding as a traced scalar, so
+        dispatches bucket by TOTAL token count (pow2; the scheduler's
+        `HorizonPlan.t_tokens` prices it) and by the pow2 step of w.
         `t_tokens` must cover the largest per-tick total (live rows +
         chunk shares; defaults to the dense-equivalent S*w bound when
         the caller doesn't supply the tight bucket). `packed=False`
@@ -2084,12 +2109,14 @@ class PagedGPTDecoder:
                     f"t_tokens {t} < max_batch {S}: the packed bucket "
                     "must cover at least one token per slot")
             width = args[2].shape[1]
-            key = (k, t, width)
+            window = packed_window(w, t)
+            key = (k, t, window, width)
             fn = self._packeds.get(key)
             if fn is None:
                 fn = _named_jit(
-                    functools.partial(self._packed_multi_step, k=k, t=t),
-                    self.program_name("packed", k, t, width),
+                    functools.partial(self._packed_multi_step, k=k, t=t,
+                                      window=window),
+                    self.program_name("packed", k, t, width, window),
                     donate_argnums=(1, 2))
                 self._packeds[key] = fn
             call = args + (jnp.asarray(w, jnp.int32),)

@@ -10,7 +10,8 @@ import numpy as np
 
 from ..framework.core import Tensor
 from ..profiler import span
-from .decoder import PagedGPTDecoder, _spec_accept
+from .decoder import (PagedGPTDecoder, _spec_accept, packed_window,
+                      pow2_at_least)
 from .stats import _ENGINES, ServeStats
 
 __all__ = ["ContinuousBatchingEngine", "SpeculativeEngine"]
@@ -379,6 +380,15 @@ class ContinuousBatchingEngine:
         times     t_round, t_fetched; admit_s, plan_s, dispatch_s,
                   fetch_wait_s, book_s, on_sync_s
         work      tokens emitted, tokens_dispatched, tokens_padded
+        pages     pages_gathered, the page copies ONE tick of the
+                  program makes for K in one layer (slots x the table
+                  width it was handed: a row's pages once, whatever
+                  its tokens); pages_live, the pages the rows' contexts
+                  fill, sum of ceil(_lens / page_size) over rows
+                  holding a request — the host's view at dispatch, not
+                  fetched: it trails the device by the horizon in
+                  flight, and a row still in prefill counts only its
+                  cached prefix
 
         Every field has a reader, named in PERF.md section 3: a field
         nothing reads is not stamped."""
@@ -405,12 +415,13 @@ class ContinuousBatchingEngine:
 
     def _horizon_dispatched(self, rec, shape, program, k, w, t_tokens,
                             decode_rows, prefill_rows, disp_toks,
-                            priced=True):
+                            width, priced=True):
         """The open round has dispatched its horizon: stamp what it is
         and append the record to the schedule. `shape` is the dispatch
         shape the drift ledger keys on, `program` the name of the
         compiled program it ran (the shape with the table's width:
-        `PagedGPTDecoder.program_name`). With a recorder attached the
+        `PagedGPTDecoder.program_name`), `width` the columns of the
+        page table handed to it. With a recorder attached the
         SAME dict becomes its tick, the price added (`priced=False`:
         a window polluted by a blocking prefill is recorded unpriced
         and stays out of the ledger). The pending tiered-KV restore
@@ -418,7 +429,11 @@ class ContinuousBatchingEngine:
         accumulate: the H2D of a restore dispatched at this round's
         admission lands inside THIS horizon's window."""
         restore_s = self._take_restore_s()
+        ps = self.d.page_size
         rec.update(
+            pages_gathered=self.d.max_batch * width,
+            # a free slot's length is 0 (`_release_slot`)
+            pages_live=int(((self._lens + ps - 1) // ps).sum()),
             k=k, w=w, t_tokens=t_tokens, decode_rows=decode_rows,
             prefill_rows=prefill_rows, slots=self.d.max_batch,
             program=program, first_use=self.d.first_use(program),
@@ -1174,7 +1189,7 @@ class ContinuousBatchingEngine:
             self._horizon_dispatched(
                 rec, ("tick", 1, 1), program, k=1, w=1, t_tokens=None,
                 decode_rows=len(active), prefill_rows=0, disp_toks=S,
-                priced=not prefilled)
+                width=self.d.max_pages, priced=not prefilled)
         with _Phase("engine.fetch", rec, "fetch_wait_s", seq=seq,
                     horizon=seq):
             nxt = np.asarray(nxt)
@@ -1472,7 +1487,7 @@ class ContinuousBatchingEngine:
                     self._horizon_dispatched(
                         rec, shape, program, k=k, w=1, t_tokens=None,
                         decode_rows=len(disp), prefill_rows=0,
-                        disp_toks=k * S)
+                        disp_toks=k * S, width=self.d.max_pages)
                     meta = (out.tokens_block, out.done_before, k,
                             {s: self._slot_req[s] for s in disp},
                             prefilled, rec)
@@ -1752,7 +1767,6 @@ class ContinuousBatchingEngine:
                             # dense-equivalent bucket here so the
                             # dispatch and the pad ledger below price
                             # the SAME layout
-                            from .decoder import pow2_at_least
                             t_tokens = pow2_at_least(S * max(plan.w, 1))
                 meta = None
                 if plan is not None:
@@ -1762,7 +1776,10 @@ class ContinuousBatchingEngine:
                     # combination compiles inside this window
                     shape = (("packed", plan.k, t_tokens) if self.packed
                              else ("ragged", plan.k, plan.w))
-                    program = self.d.program_name(*shape, width)
+                    program = self.d.program_name(
+                        *shape, width,
+                        packed_window(plan.w, t_tokens) if self.packed
+                        else None)
                     with _Phase("engine.dispatch", rec, "dispatch_s",
                                 seq=seq, program=program):
                         out = self.d.ragged_multi(
@@ -1790,7 +1807,8 @@ class ContinuousBatchingEngine:
                         decode_rows=len(live) - plan.prefill_rows,
                         prefill_rows=plan.prefill_rows,
                         disp_toks=plan.k * (t_tokens if self.packed
-                                            else S * plan.w))
+                                            else S * plan.w),
+                        width=width)
                     meta = (out.tokens_block, out.emitted, out.real,
                             plan.k,
                             {s: (rid, self._slot_gen[s])
@@ -1972,7 +1990,8 @@ class SpeculativeEngine(ContinuousBatchingEngine):
             self._horizon_dispatched(
                 rec, ("tick", 1, 1), program, k=1, w=1, t_tokens=None,
                 decode_rows=len(active), prefill_rows=0,
-                disp_toks=S_all * (2 * k + 1), priced=not prefilled)
+                disp_toks=S_all * (2 * k + 1), width=self.d.max_pages,
+                priced=not prefilled)
         # the draft fetch and the verify forward both block: the host
         # waits for the device through all of this phase
         with _Phase("engine.fetch", rec, "fetch_wait_s", seq=seq,

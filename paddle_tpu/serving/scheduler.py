@@ -48,8 +48,9 @@ class HorizonPlan:
     tick 0 is the max — per-row shares only shrink as prompts drain),
     floored at the slot count so pure-decode horizons always dispatch
     one stable [S] bucket. The packed engine's jit key is (k,
-    t_tokens); the dense twin's is (k, w) — total-token bucketing is
-    what collapses the 2-D (S, w) dispatch grid."""
+    t_tokens, the pow2 bucket of w, table width); the dense twin's is
+    (k, w, table width) — total-token bucketing is what collapses the
+    2-D (S, w) dispatch grid."""
 
     __slots__ = ("k", "w", "emit_ticks", "n_chunks", "prefill_rows",
                  "t_tokens")

@@ -228,8 +228,11 @@ def two_layer_decoder():
 
 
 def compile_horizon(one_chip, d, k, t, width):
-    """One packed ragged horizon of decoder `d`, as `ragged_multi` jits it."""
+    """One packed ragged horizon of decoder `d`, as `ragged_multi` jits it
+    for the smoke's chunks of 128 tokens (1 on the decode bucket t=8)."""
     import functools
+
+    from paddle_tpu.serving.decoder import packed_window
 
     def shapes(tree):
         return jax.tree_util.tree_map(
@@ -242,7 +245,8 @@ def compile_horizon(one_chip, d, k, t, width):
         return spec(one_chip, shape, jnp.bool_)
 
     return jax.jit(
-        functools.partial(d._packed_multi_step, k=k, t=t),
+        functools.partial(d._packed_multi_step, k=k, t=t,
+                          window=packed_window(1 if t == B else 128, t)),
         donate_argnums=(1, 2),
     ).lower(shapes(d._w()), shapes(d.k_pages), shapes(d.v_pages),
             i32(B), i32(B), i32(B, width), i32(B), flags(B), i32(B), i32(),

@@ -30,7 +30,7 @@ PHASES = ("admit_s", "plan_s", "dispatch_s", "fetch_wait_s", "book_s",
 FIELDS = {"kind", "seq", "k", "w", "t_tokens", "decode_rows", "prefill_rows",
           "slots", "program", "first_use", "queue_depth", "admit_waits_s",
           "t_round", "t_fetched", "tokens", "tokens_dispatched",
-          "tokens_padded"} | set(PHASES)
+          "tokens_padded", "pages_gathered", "pages_live"} | set(PHASES)
 CHILDREN = {"engine.admit", "engine.plan", "engine.dispatch",
             "engine.fetch", "engine.bookkeep", "engine.on_sync"}
 # (k, w, t_tokens, decode_rows, prefill_rows) of every horizon of `_serve`,
@@ -42,6 +42,26 @@ WHAT_THEY_WERE = {
     "multi": [(4, 1, None, 2, 0), (1, 1, None, 2, 0), (4, 1, None, 1, 0),
               (1, 1, None, 1, 0)],
     "per_tick": [(1, 1, None, 2, 0)] * 5 + [(1, 1, None, 1, 0)] * 5,
+}
+
+
+# (pages_gathered, pages_live) of the same horizons, counted by hand: two
+# slots, pages of 16, prompts of 3, 29 and 2 tokens, 6 tokens an answer.
+# Gathered is slots x the table's columns handed to the program (the whole
+# table of 8 off the ragged loop, its live power-of-two width on it).
+# Live is the host's `_lens` at dispatch: per tick the contexts are 3+i
+# and 29+i (1 + 2 pages; 7 and 33 at the fifth tick: 1 + 3), then the
+# third request alone (2-6 tokens: 1 page). The ragged loop dispatches
+# horizon r before it books block r-1, and books a row's prompt with its
+# first token: the first two horizons see nothing booked; the third the
+# first block (3+3 and 29 tokens: 1 + 2); the fourth the second (row 0
+# retired and given to the third request, nothing cached: 0, beside 29+2:
+# 2); the fifth 0 beside 29+4 (3); the last, row 1 retired, the third
+# request's 2 tokens (1) in a table 2 wide.
+WHAT_PAGES = {
+    "ragged": [(8, 0), (8, 0), (8, 3), (8, 2), (8, 3), (4, 1)],
+    "multi": [(16, 3), (16, 3), (16, 1), (16, 1)],
+    "per_tick": [(16, 3)] * 4 + [(16, 4)] + [(16, 1)] * 5,
 }
 
 
@@ -117,7 +137,7 @@ def test_profiled_engine_nests_its_spans_under_the_round(tiny_model, loop,
             ev = records[stats["seq"]]
             assert stats["program"] == ev["program"]
             assert ev["program"].startswith({
-                "ragged": f"packed_multi_k{ev['k']}_t{ev['t_tokens']}_p",
+                "ragged": f"packed_multi_k{ev['k']}_t{ev['t_tokens']}_w",
                 "multi": f"decode_multi_k{ev['k']}",
                 "per_tick": "decode_step"}[loop])
         if name in ("engine.fetch", "engine.bookkeep", "engine.on_sync"):
@@ -162,6 +182,8 @@ def test_one_record_a_horizon(tiny_model, loop):
         sorted(eng0.stats.queue_wait_s)
     assert sum(ev["tokens"] for ev in hz) == \
         sum(map(len, plain)) - (0 if loop == "ragged" else len(PROMPTS))
+    assert [(ev["pages_gathered"], ev["pages_live"]) for ev in hz] == \
+        WHAT_PAGES[loop]
     assert [ev["seq"] for ev in hz] == sorted({ev["seq"] for ev in hz})
     # the recorder's tick is the same dict, the price fields added
     ticks = [ev for ev in rec.events if ev["kind"] == "horizon"]
@@ -236,9 +258,9 @@ def test_serving_report_reads_queue_and_pad_off_the_records(tiny_model):
 def test_a_program_name_is_made_once(tiny_model):
     """One key, one name: the round looks the name up (`lru_cache`), the
     record, the span and `first_use` share the very string."""
-    name = PagedGPTDecoder.program_name("packed", 2, 256, 64)
-    assert name == "packed_multi_k2_t256_p64"
-    assert PagedGPTDecoder.program_name("packed", 2, 256, 64) is name
+    name = PagedGPTDecoder.program_name("packed", 2, 256, 64, 128)
+    assert name == "packed_multi_k2_t256_w128_p64"
+    assert PagedGPTDecoder.program_name("packed", 2, 256, 64, 128) is name
     assert PagedGPTDecoder.program_name("ragged", 4, 8, 2) == \
         "ragged_multi_k4_w8_p2"
     assert PagedGPTDecoder.program_name("decode", 4, 1, 8) == \
@@ -252,17 +274,18 @@ def test_every_serving_program_carries_its_key(tiny_model):
     _, eng, _ = _serve(tiny_model, "ragged")
     dec = eng.d
     assert dec._packeds
-    for (k, t, width), fn in dec._packeds.items():
-        assert fn.__name__ == f"packed_multi_k{k}_t{t}_p{width}"
-        assert fn.__name__ == dec.program_name("packed", k, t, width)
+    for (k, t, window, width), fn in dec._packeds.items():
+        assert fn.__name__ == f"packed_multi_k{k}_t{t}_w{window}_p{width}"
+        assert fn.__name__ == dec.program_name("packed", k, t, width,
+                                               window)
     _, eng, _ = _serve(tiny_model, "multi")
     assert {fn.__name__ for fn in eng.d._multis.values()} == \
         {"decode_multi_k4", "decode_multi_k1"}
     assert eng.d._decode.__name__ == "decode_step"
-    (k, t, width), fn = next(iter(dec._packeds.items()))
+    (k, t, window, width), fn = next(iter(dec._packeds.items()))
     module = fn.lower(*_packed_args(dec, t, width)).as_text().split(
         "\n", 1)[0]
-    assert f"@jit_packed_multi_k{k}_t{t}_p{width}" in module
+    assert f"@jit_packed_multi_k{k}_t{t}_w{window}_p{width}" in module
 
 
 def _packed_args(dec, t, width):

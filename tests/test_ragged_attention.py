@@ -292,21 +292,29 @@ def _pools(case_seed, P, ps, H, D, pool):
 # position (the A/B-twin guarantee: the same position computed inside
 # any dense window is the same bytes): a single token (T=1 — the
 # one-live-slot tick), pure decode (every row one token), pure prefill
-# (one row's whole chunk), a chunk exactly filling a page, and a
-# stream exactly at its pow2 bucket boundary with zero padding slack.
+# (one row's whole chunk), a chunk exactly filling a page, a stream
+# exactly at its pow2 bucket boundary with zero padding slack, and the
+# late joiners' tick: five decode rows and three chunk rows of unequal
+# length in one stream, no chunk page-aligned, every row laid out in the
+# window of 8 the decoder would key the program by (`packed_window`).
 @pytest.mark.parametrize("pool", ["bf16", "int8", "int4"])
 @pytest.mark.parametrize("case", ["single_token", "all_decode",
                                   "all_prefill", "page_exact",
-                                  "bucket_boundary"])
+                                  "bucket_boundary", "late_joiners"])
 def test_packed_degenerate_shapes_bit_identical(case, pool):
     import zlib
     rng = np.random.RandomState(zlib.crc32(case.encode()) % (2 ** 31))
     H, D, P, ps, MP = 2, 8, 10, 4, 5
     kp, vp = _pools(zlib.crc32((case + pool).encode()) % (2 ** 31),
                     P, ps, H, D, pool)
-    n = 3
+    n = 8 if case == "late_joiners" else 3
     table = jnp.asarray(rng.randint(0, P, (n, MP)).astype(np.int32))
-    if case == "single_token":
+    window = None
+    if case == "late_joiners":
+        layout = [(0, 3, 1), (1, 9, 1), (2, 0, 1), (3, 17, 1), (4, 6, 1),
+                  (5, 1, 5), (6, 2, 7), (7, 3, 6)]
+        window = 8
+    elif case == "single_token":
         layout = [(1, 7, 1)]
     elif case == "all_decode":
         layout = [(0, 3, 1), (1, 0, 1), (2, 11, 1)]
@@ -322,7 +330,7 @@ def test_packed_degenerate_shapes_bit_identical(case, pool):
         q = q.astype(jnp.bfloat16)
 
     ref = np.asarray(ragged_paged_attention_packed(
-        q, kp, vp, table, rows, pos).astype(jnp.float32))
+        q, kp, vp, table, rows, pos, window=window).astype(jnp.float32))
     ker = np.asarray(ragged_paged_attention_packed(
         q, kp, vp, table, rows, pos, use_kernel=True,
         interpret=True).astype(jnp.float32))
@@ -346,7 +354,8 @@ def test_packed_degenerate_shapes_bit_identical(case, pool):
         vf = jnp.asarray(np.asarray(_dequant_page_int4(vp[0], vp[1],
                                                        (H, D))))
         twin = np.asarray(ragged_paged_attention_packed(
-            q, kf, vf, table, rows, pos).astype(jnp.float32))
+            q, kf, vf, table, rows, pos, window=window
+        ).astype(jnp.float32))
         np.testing.assert_array_equal(ref, twin, err_msg=str(case))
         t0 = 0
         for r, start, cnt in layout:
@@ -421,3 +430,44 @@ def test_packed_attention_int8_tracks_dense_oracle():
         q[3:][None], kp, vp, table[1:], jnp.asarray([20], jnp.int32)))[0]
     assert np.array_equal(packed[:3], dense0)
     assert np.array_equal(packed[3:], dense1)
+
+
+def _sub_jaxprs(jaxpr):
+    """`jaxpr` and every jaxpr nested in its equations' parameters."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for v in eqn.params.values():
+            for x in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(x, "jaxpr", x)
+                if hasattr(inner, "eqns"):
+                    yield from _sub_jaxprs(inner)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8", "int4"])
+@pytest.mark.parametrize("window", [None, 32])
+def test_packed_reference_gathers_by_row_not_by_token(pool, window):
+    """The packed reference copies a ROW's pages once and every token of
+    the row reads that copy: no value of the traced program is larger
+    than the per-row gather (n x width x ps x H x D elements) times 2 —
+    the float32 window of queries a row-wide stream needs is the one
+    thing of that order (here `T` queries a row, bounded by `window`).
+    A per-token page table (`page_table[row_ids]`: T x width x ps x H x D,
+    16 times the bound at this shape) cannot come back unseen."""
+    T, n, MP, ps, H, D, P = 64, 4, 8, 4, 2, 8, 40
+    rng = np.random.RandomState(29)
+    kp, vp = _pools(29, P, ps, H, D, pool)
+    table = jnp.asarray(rng.randint(0, P, (n, MP)).astype(np.int32))
+    rows, pos = _pack([(0, 0, 20), (1, 3, 1), (2, 5, 30), (3, 9, 13)])
+    q = jnp.asarray(rng.randn(T, H, D).astype(np.float32))
+    jaxpr = jax.make_jaxpr(
+        lambda *a: ragged_paged_attention_packed(*a, window=window))(
+        q, kp, vp, table, rows, pos).jaxpr
+    row_gather = n * MP * ps * H * D
+    sizes = [(int(np.prod(v.aval.shape)), eqn.primitive.name, v.aval.shape)
+             for j in _sub_jaxprs(jaxpr) for eqn in j.eqns
+             for v in eqn.outvars if hasattr(v.aval, "shape")]
+    assert max(sizes)[0] >= row_gather, "the per-row gather is gone?"
+    assert max(sizes)[0] <= 2 * row_gather, max(sizes)
+    # and no value carries the stream's T and the table's width together
+    assert not [s for s in sizes if len(s[2]) >= 2
+                and s[2][0] == T and s[2][1] == MP], sizes

@@ -933,9 +933,10 @@ def test_packed_prefill_batches_mixed_lengths_in_one_bucket(tiny_model):
     first_d = dec_d.prefill_suffix_batch([tuple(r) for r in reqs],
                                          kids=[0, 1, 2])
     assert first_p == first_d
-    # 5+17+2 = 24 tokens -> ONE t=32 packed program; the dense twin
+    # 5+17+2 = 24 tokens -> ONE t=32 packed program (rows laid out in
+    # windows of 32, the longest suffix's bucket); the dense twin
     # buckets per (W, nb): W=8 x1, W=32 x1, W=4 x1 = three programs
-    assert list(dec_p._packed_prefills) == [32]
+    assert list(dec_p._packed_prefills) == [(32, 32)]
     assert dec_p._suffix_prefill is None
     assert dec_d._suffix_prefill is not None
 
