@@ -1052,6 +1052,8 @@ def audit_kv_scale_planes(decoder, pages):
     consistent)."""
     import numpy as np
     findings = []
+    if not hasattr(decoder, "k_pages"):
+        return findings     # a latent pool (PagedMLADecoder): never quantized
     k_pool, v_pool = decoder.k_pages, decoder.v_pages
     if not isinstance(k_pool, tuple):
         return findings                  # unquantized pool: nothing to check

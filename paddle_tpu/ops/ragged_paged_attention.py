@@ -47,7 +47,8 @@ import numpy as np
 
 from ._fallback import kernel_fallback
 
-__all__ = ["ragged_paged_attention", "ragged_paged_attention_packed"]
+__all__ = ["ragged_paged_attention", "ragged_paged_attention_packed",
+           "mla_paged_attention_packed"]
 
 # softmax-denominator floor shared by reference and kernel: a row whose
 # every key is masked (possible only for padded queries past true_len —
@@ -594,3 +595,125 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, start,
         return _dense_ref(q, k_pages, v_pages, page_table, start,
                           scale=float(scale), k_scale=ks, v_scale=vs,
                           int4=int4)
+
+
+# ------------------------------------------------- latent (MLA) attention
+# keys of one block of the materialised walk: a multiple of the page size
+# that divides the gathered context (see `_mla_key_block`)
+_MLA_KEY_PAGES = 8
+
+
+def _mla_key_block(max_pages, page_size):
+    import math
+    return math.gcd(int(max_pages), _MLA_KEY_PAGES) * int(page_size)
+
+
+def mla_paged_attention_packed(q_nope, q_rope, latent_pages, layer, w_kvb,
+                               page_table, row_ids, pos, row_new,
+                               materialise, scale, window=None):
+    """PACKED-layout causal multi-head LATENT attention over a paged
+    latent cache: H query heads over ONE cached head a token, whose row
+    [rank + dr] holds the normed latent (what every head's keys and
+    values are projected from) beside the rotary key all heads share.
+    The same function of the same cache in two forms, chosen BY ROW:
+
+    * `materialise[row]` False — ABSORBED, for a row with one new token
+      (a decode row): the key up-projection is folded into the query
+      (`q~ = q_nope W_uk^T`, [H, rank]) so scores are `q~ . latent +
+      q_rope . k_rope` over the latent rows themselves, the weighted sum
+      is taken over the latent rows too and only then projected up
+      through W_uv. Nothing per head is built for the context; the
+      row's `rank + dr` bytes a key are all that is read.
+    * `materialise[row]` True — MATERIALISED, for a row with a chunk of
+      new tokens (a prefill row): the row's latent context is projected
+      up, a block of keys at a time, to per-head keys [dn] and values
+      [dv], and the row's `window` of queries runs ordinary
+      online-softmax attention over them (`_page_update`, the math the
+      GPT walk uses). The projection is paid once a block whatever the
+      number of queries, and a row's walk stops at its last position.
+
+    q_nope [T, H, dn], q_rope [T, H, dr] (already rotated): the flat
+    token stream, ROW-CONTIGUOUS as in `ragged_paged_attention_packed`
+    (rows in increasing order; `row_new[r]` tokens of row r from its
+    first stream slot on, at most `window`). latent_pages
+    [L, P, page_size, rank + dr] with `layer` the layer to read (the
+    new tokens' rows already written); w_kvb [rank, H, dn + dv];
+    page_table [n, max_pages]; pos [T] absolute positions. Returns
+    [T, H, dv]; a token past its row's `row_new` gets garbage."""
+    T, H, dn = q_nope.shape
+    dr = q_rope.shape[-1]
+    n, MP = page_table.shape
+    ps, C = latent_pages.shape[-2:]
+    r = C - dr
+    dv = w_kvb.shape[-1] - dn
+    ctx = MP * ps
+    dt = q_nope.dtype
+    W = max(1, T if window is None else min(int(window), T))
+    row_ids = jnp.asarray(row_ids, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    with jax.named_scope("paged_gather"):
+        # ONE copy of each row's latent pages, shared by both forms
+        lat = latent_pages[layer, jnp.maximum(page_table, 0)].reshape(
+            n, ctx, C)
+    first = jnp.argmax(row_ids[None, :] == jnp.arange(n)[:, None],
+                       axis=1).astype(jnp.int32)               # [n]
+    kpos = jnp.arange(ctx)
+
+    with jax.named_scope("mla_absorbed"):
+        qn, qr, qp = q_nope[first], q_rope[first], pos[first]  # [n, H, .]
+        qt = jnp.einsum("nhd,rhd->nhr", qn, w_kvb[..., :dn],
+                        preferred_element_type=jnp.float32).astype(dt)
+        s = (jnp.einsum("nhr,ncr->nhc", qt, lat[..., :r],
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("nhd,ncd->nhc", qr, lat[..., r:],
+                          preferred_element_type=jnp.float32)) * scale
+        s = jnp.where((kpos[None, :] <= qp[:, None])[:, None, :], s, _MASK)
+        p = jax.nn.softmax(s, axis=-1)
+        ol = jnp.einsum("nhc,ncr->nhr", p.astype(dt), lat[..., :r],
+                        preferred_element_type=jnp.float32).astype(dt)
+        out_abs = jnp.einsum("nhr,rhd->nhd", ol, w_kvb[..., dn:],
+                             preferred_element_type=jnp.float32).astype(dt)
+
+    with jax.named_scope("mla_materialised"):
+        kb = _mla_key_block(MP, ps)
+        # padded by a window, so that a row's slice never clamps
+        pad = ((0, W), (0, 0), (0, 0))
+        qn_p, qr_p = jnp.pad(q_nope, pad), jnp.pad(q_rope, pad)
+        pos_p = jnp.pad(pos, (0, W))
+
+        def one_row(out, row):
+            def run(out):
+                lo = first[row]
+                qn = jax.lax.dynamic_slice_in_dim(qn_p, lo, W, 0)
+                qr = jax.lax.dynamic_slice_in_dim(qr_p, lo, W, 0)
+                qpos = jax.lax.dynamic_slice_in_dim(pos_p, lo, W, 0)
+                qn = (qn.astype(jnp.float32) * scale).transpose(1, 0, 2)
+                qr = (qr.astype(jnp.float32) * scale).transpose(1, 0, 2)
+                c = lat[row]                                 # [ctx, C]
+                last = pos[lo] + row_new[row] - 1
+
+                def block(j, carry):
+                    cb = jax.lax.dynamic_slice_in_dim(c, j * kb, kb, 0)
+                    kv = jnp.einsum("cr,rhd->hcd", cb[:, :r], w_kvb,
+                                    preferred_element_type=jnp.float32)
+                    logits = (jnp.einsum("hwd,hcd->hwc", qn, kv[..., :dn])
+                              + jnp.einsum("hwd,cd->hwc", qr,
+                                           cb[:, r:].astype(jnp.float32)))
+                    return _page_update(*carry, logits, kv[..., dn:],
+                                        j * kb + jnp.arange(kb), qpos)
+
+                m, s_, acc = jax.lax.fori_loop(
+                    0, jnp.minimum(last // kb + 1, ctx // kb), block,
+                    (jnp.full((H, W, 1), _MASK, jnp.float32),
+                     jnp.zeros((H, W, 1), jnp.float32),
+                     jnp.zeros((H, W, dv), jnp.float32)))
+                o = (acc / jnp.maximum(s_, _DENOM_EPS)).transpose(1, 0, 2)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    out, o.astype(dt), lo, 0)
+            return jax.lax.cond(materialise[row], run, lambda o: o, out), \
+                None
+
+        out_mat, _ = jax.lax.scan(one_row, jnp.zeros((T + W, H, dv), dt),
+                                  jnp.arange(n))
+    return jnp.where(materialise[row_ids][:, None, None], out_mat[:T],
+                     out_abs[row_ids])

@@ -94,6 +94,119 @@ def packed_window(w, t):
     return min(pow2_at_least(w), int(t))
 
 
+PackedLayout = collections.namedtuple(
+    "PackedLayout", "ptok pos rows write_ok last_idx true live nl is_pf")
+
+PackedPrefill = collections.namedtuple(
+    "PackedPrefill",
+    "t window ptok pos rows ok table last_idx sample_pos live new")
+
+
+def packed_tick(carry, w, eos, *, t, capacity, forward):
+    """One MIXED tick over the PACKED [t] token stream, whatever the
+    decoder: the layout built on device from the carry (cumsum +
+    searchsorted over per-row token counts), the decoder's `forward`
+    over it, and every per-row rule of the schedule (emit condition,
+    freeze and budget updates, scratch routing, the dynamic shift of the
+    prompt suffixes). ONE definition, so decoders cannot part on a rule.
+
+    `carry` = (tokens, lens, done, remaining, pend, pend_n, *pools);
+    `w` the traced per-row chunk cap; `capacity` the positions a row's
+    table covers (past it a write goes to scratch).
+    `forward(layout, pools)` -> (next [S], pools, counters): the
+    decoder's model over the `PackedLayout` (token t = row `rows[t]`,
+    position `pos[t]`; `true` [S] the rows' lengths after the tick) and
+    int32 scalars that ride the tick's `real` output as further columns
+    (none: `real` is the real token count alone).
+    Returns (carry, (next, emit, real)) as a `lax.scan` body does."""
+    tokens, lens, done, remaining, pend, pend_n = carry[:6]
+    S = tokens.shape[0]
+    P = pend.shape[1]
+    is_pf = pend_n > 0
+    # per-row stream share: decode 1, prefill min(pend_n, w),
+    # frozen 0 (the packed layout simply skips frozen rows —
+    # the dense twin computes their scratch-routed windows)
+    nl = jnp.where(done, 0,
+                   jnp.where(is_pf, jnp.minimum(pend_n, w), 1))
+    csum = jnp.cumsum(nl)
+    total = csum[-1]
+    starts = csum - nl
+    ti = jnp.arange(t)
+    rows = jnp.clip(
+        jnp.searchsorted(csum, ti, side="right"), 0, S - 1
+    ).astype(jnp.int32)
+    within = (ti - starts[rows]).astype(jnp.int32)
+    valid = ti < total
+    pos = lens[rows] + within                     # [t]
+    ptok = jnp.where(
+        is_pf[rows], pend[rows, jnp.clip(within, 0, P - 1)],
+        tokens[rows])
+    ptok = jnp.where(valid, ptok, 0)
+    write_ok = valid & ~done[rows] & (pos < capacity)
+    true = lens + nl                              # [S]
+    last_idx = jnp.clip(csum - 1, 0, t - 1)
+    live = ~done & (nl > 0)
+    nxt, pools, counters = forward(
+        PackedLayout(ptok, pos, rows, write_ok, last_idx, true, live, nl,
+                     is_pf), tuple(carry[6:]))
+    emit = ~done & (pend_n <= w)
+    nxt = jnp.where(emit, nxt, tokens)
+    rem = jnp.where(emit, remaining - 1, remaining)
+    new_done = done | (emit & ((nxt == eos) | (rem <= 0)))
+    new_lens = jnp.where(done, lens, lens + nl)
+    real = total.astype(jnp.int32)
+    if counters:
+        real = jnp.stack([real, *counters]).astype(jnp.int32)
+    # shift each row's suffix by the DYNAMIC w (a gather — the
+    # dense twin's static concatenate+slice can't take a traced
+    # width); over-shift past pend_n clears like the dense path
+    idx = jnp.arange(P)[None, :] + w
+    pend = jnp.where(idx < P,
+                     pend[jnp.arange(S)[:, None],
+                          jnp.clip(idx, 0, P - 1)], 0)
+    pend_n = jnp.maximum(pend_n - w, 0)
+    return (nxt, new_lens, new_done, rem, pend, pend_n) + tuple(pools), \
+        (nxt, emit, real)
+
+
+def packed_prefill_layout(chunk, slots, max_pages, page_size, scratch):
+    """Host-side PACKED prefill layout of up to `slots` requests
+    [(suffix_ids, start, pages), ...], whatever the decoder: flat tokens
+    with per-token row ids and positions, bucketed to a pow2 total-token
+    count `t` and a pow2 longest suffix (`packed_window`); each row's
+    page table (the rest on the `scratch` page), last stream index,
+    sampling position, liveness and token count. numpy throughout."""
+    counts = [len(np.asarray(ids).reshape(-1)) for ids, _, _ in chunk]
+    t = pow2_at_least(sum(counts))
+    window = packed_window(max(counts), t)
+    ptok = np.zeros(t, np.int32)
+    pos = np.zeros(t, np.int32)
+    rows = np.zeros(t, np.int32)
+    ok = np.zeros(t, bool)
+    last_idx = np.zeros(slots, np.int32)
+    spos = np.zeros(slots, np.int32)
+    live = np.zeros(slots, bool)
+    new = np.zeros(slots, np.int32)
+    tbl = np.full((slots, max_pages), scratch, np.int32)
+    cur = 0
+    for r, (ids, start, pages) in enumerate(chunk):
+        ids = np.asarray(ids, np.int32).reshape(-1)
+        n = len(ids)
+        ptok[cur:cur + n] = ids
+        pos[cur:cur + n] = int(start) + np.arange(n)
+        rows[cur:cur + n] = r
+        ok[cur:cur + n] = pos[cur:cur + n] < max_pages * page_size
+        last_idx[r] = max(cur + n - 1, 0)
+        spos[r] = int(start) + n - 1
+        live[r] = n > 0
+        new[r] = n
+        m = min(len(pages), max_pages)
+        tbl[r, :m] = pages[:m]       # rest stays on scratch
+        cur += n
+    return PackedPrefill(t, window, ptok, pos, rows, ok, tbl, last_idx,
+                         spos, live, new)
+
+
 def _ln(x, w, b):
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, -1, keepdims=True)
@@ -370,6 +483,13 @@ def _mm(x, w, b, quant):
 
 class PagedGPTDecoder:
     """Stacked-weight GPT decode executor over paged KV pools."""
+
+    # the interface both decoders keep towards the engine (see
+    # `serving/mla_decoder.py`): counters that ride `ragged_multi`'s
+    # `real` block beside the real token count (none here), and the
+    # engine options this decoder cannot serve (none)
+    horizon_counters = ()
+    engine_refusals = {}
 
     def __init__(self, model, num_pages=128, page_size=16, max_batch=8,
                  max_pages_per_seq=None, quant=None, kv_quant=None,
@@ -1232,57 +1352,17 @@ class PagedGPTDecoder:
         bytes are byte-identical to the dense twin and the per-tick
         engine (test-pinned). Returns the RaggedMultiOut tuple layout
         (tokens_block [k, S], emitted [k, S], real [k], finals...)."""
-        S = tokens.shape[0]
-        P = pend.shape[1]
-        MP = table.shape[1]
-        ps = self.page_size
+        def forward(lay, pools):
+            nxt, kp, vp = self._packed_forward(
+                weights, *pools, lay.ptok, lay.pos, lay.rows, lay.write_ok,
+                table, lay.last_idx, lay.true - 1, kids, lay.live,
+                aids=aids, window=window)
+            return nxt, (kp, vp), ()
 
         def tick(carry, _):
-            tokens, lens, done, remaining, pend, pend_n, kp, vp = carry
-            is_pf = pend_n > 0
-            # per-row stream share: decode 1, prefill min(pend_n, w),
-            # frozen 0 (the packed layout simply skips frozen rows —
-            # the dense twin computes their scratch-routed windows)
-            nl = jnp.where(done, 0,
-                           jnp.where(is_pf, jnp.minimum(pend_n, w), 1))
-            csum = jnp.cumsum(nl)
-            total = csum[-1]
-            starts = csum - nl
-            ti = jnp.arange(t)
-            rows = jnp.clip(
-                jnp.searchsorted(csum, ti, side="right"), 0, S - 1
-            ).astype(jnp.int32)
-            within = (ti - starts[rows]).astype(jnp.int32)
-            valid = ti < total
-            pos = lens[rows] + within                     # [t]
-            ptok = jnp.where(
-                is_pf[rows], pend[rows, jnp.clip(within, 0, P - 1)],
-                tokens[rows])
-            ptok = jnp.where(valid, ptok, 0)
-            write_ok = valid & ~done[rows] & (pos < MP * ps)
-            true = lens + nl                              # [S]
-            last_idx = jnp.clip(csum - 1, 0, t - 1)
-            live = ~done & (nl > 0)
-            nxt, kp, vp = self._packed_forward(
-                weights, kp, vp, ptok, pos, rows, write_ok, table,
-                last_idx, true - 1, kids, live, aids=aids,
-                window=window)
-            emit = ~done & (pend_n <= w)
-            nxt = jnp.where(emit, nxt, tokens)
-            rem = jnp.where(emit, remaining - 1, remaining)
-            new_done = done | (emit & ((nxt == eos) | (rem <= 0)))
-            new_lens = jnp.where(done, lens, lens + nl)
-            real = total.astype(jnp.int32)
-            # shift each row's suffix by the DYNAMIC w (a gather — the
-            # dense twin's static concatenate+slice can't take a traced
-            # width); over-shift past pend_n clears like the dense path
-            idx = jnp.arange(P)[None, :] + w
-            pend = jnp.where(idx < P,
-                             pend[jnp.arange(S)[:, None],
-                                  jnp.clip(idx, 0, P - 1)], 0)
-            pend_n = jnp.maximum(pend_n - w, 0)
-            return (nxt, new_lens, new_done, rem, pend, pend_n, kp, vp), \
-                (nxt, emit, real)
+            return packed_tick(carry, w, eos, t=t,
+                               capacity=table.shape[1] * self.page_size,
+                               forward=forward)
 
         carry = (tokens, lens, done, remaining, pend, pend_n,
                  k_pages, v_pages)
@@ -1421,36 +1501,14 @@ class PagedGPTDecoder:
         todo = list(enumerate(requests))
         while todo:
             chunk, todo = todo[:S], todo[S:]
-            counts = [len(np.asarray(ids).reshape(-1))
-                      for _, (ids, _, _) in chunk]
-            t = pow2_at_least(sum(counts))
-            window = packed_window(max(counts), t)
-            ptok = np.zeros(t, np.int32)
-            pos = np.zeros(t, np.int32)
-            rows = np.zeros(t, np.int32)
-            ok = np.zeros(t, bool)
-            last_idx = np.zeros(S, np.int32)
-            spos = np.zeros(S, np.int32)
-            live = np.zeros(S, bool)
-            tbl = np.full((S, MP), self.num_pages - 1, np.int32)
+            lay = packed_prefill_layout([req for _, req in chunk], S, MP,
+                                        ps, self.num_pages - 1)
+            t, window = lay.t, lay.window
             kd = np.zeros(S, np.int32)
             ad = np.zeros(S, np.int32)
-            cur = 0
-            for r, (i, (ids, start, pages)) in enumerate(chunk):
-                ids = np.asarray(ids, np.int32).reshape(-1)
-                n = len(ids)
-                ptok[cur:cur + n] = ids
-                pos[cur:cur + n] = int(start) + np.arange(n)
-                rows[cur:cur + n] = r
-                ok[cur:cur + n] = pos[cur:cur + n] < MP * ps
-                last_idx[r] = max(cur + n - 1, 0)
-                spos[r] = int(start) + n - 1
-                live[r] = n > 0
-                m = min(len(pages), MP)
-                tbl[r, :m] = pages[:m]       # rest stays on scratch
+            for r, (i, _) in enumerate(chunk):
                 kd[r] = kids[i]
                 ad[r] = aids[i]
-                cur += n
             fn = self._packed_prefills.get((t, window))
             if fn is None:
                 fn = _named_jit(
@@ -1460,10 +1518,11 @@ class PagedGPTDecoder:
                     donate_argnums=(1, 2))
                 self._packed_prefills[t, window] = fn
             self._draws += 1
-            call = (jnp.asarray(ptok), jnp.asarray(pos),
-                    jnp.asarray(rows), jnp.asarray(ok), jnp.asarray(tbl),
-                    jnp.asarray(last_idx), jnp.asarray(spos),
-                    jnp.asarray(kd), jnp.asarray(live))
+            call = (jnp.asarray(lay.ptok), jnp.asarray(lay.pos),
+                    jnp.asarray(lay.rows), jnp.asarray(lay.ok),
+                    jnp.asarray(lay.table), jnp.asarray(lay.last_idx),
+                    jnp.asarray(lay.sample_pos), jnp.asarray(kd),
+                    jnp.asarray(lay.live))
             if self.lora is not None:
                 call += (jnp.asarray(ad),)
             nxt, self.k_pages, self.v_pages = fn(

@@ -249,6 +249,11 @@ class ContinuousBatchingEngine:
         # on THIS engine regardless of the decoder default (the
         # pad-fraction bench runs both off one decoder).
         self.packed = bool(decoder.packed if packed is None else packed)
+        _refuse_unserved(decoder, {
+            "prefix_cache": self.cache is not None,
+            "host_tier": self.tier is not None,
+            "ragged=False": not self.ragged,
+            "packed=False": not self.packed})
         self._prompt_len = [0] * S           # admitted prompt length/slot
         # THE record of every dispatched horizon (one "horizon" dict
         # each, always on: `_begin_round`), beside the "prefill_sync"
@@ -1612,6 +1617,12 @@ class ContinuousBatchingEngine:
             emitted = np.asarray(emitted_d)
             real = np.asarray(real_d)
         rec["t_fetched"] = time.perf_counter()
+        if real.ndim == 2:
+            # the decoder's own counters ride the block, a column each
+            # beside the real token count (`horizon_counters`)
+            rec.update(zip(self.d.horizon_counters,
+                           (int(c) for c in real[:, 1:].sum(axis=0))))
+            real = real[:, 0]
         with _Phase("engine.bookkeep", rec, "book_s", **ids):
             # pad ledger: dispatched is the horizon's layout cost (k *
             # the packed t_tokens bucket, or k*S*w dense); real is the
@@ -1829,6 +1840,16 @@ class ContinuousBatchingEngine:
         return dict(self._outputs)
 
 
+def _refuse_unserved(decoder, asked):
+    """Raise for an engine option (`asked`: {option: wanted}) that the
+    decoder says it cannot serve (`engine_refusals`: {option: why}). The
+    engine knows no decoder by name: it asks."""
+    for option, why in decoder.engine_refusals.items():
+        if asked.get(option):
+            raise NotImplementedError(
+                f"{type(decoder).__name__} does not serve {option}: {why}")
+
+
 class SpeculativeEngine(ContinuousBatchingEngine):
     """Speculative decoding over the paged engine: a small DRAFT model
     proposes k tokens with k cheap decode ticks; the TARGET model scores
@@ -1848,6 +1869,8 @@ class SpeculativeEngine(ContinuousBatchingEngine):
 
     def __init__(self, decoder, draft_decoder, eos_token_id=None,
                  max_new_tokens=64, k=4, trace=None):
+        for d in (decoder, draft_decoder):
+            _refuse_unserved(d, {"speculation": True})
         if decoder.sampling != draft_decoder.sampling:
             raise ValueError(
                 "speculative decoding needs the SAME sampling config on "

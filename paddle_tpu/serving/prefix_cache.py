@@ -72,6 +72,16 @@ def unpack_array(raw, meta):
     ).reshape(meta["shape"]).copy()
 
 
+def _refuse_without_kv_pools(decoder):
+    """Saving and loading move `k_pages`/`v_pages` (`pool_state`): a
+    decoder over another kind of pool (the latent one of
+    `PagedMLADecoder`) is refused, not half-saved."""
+    if not hasattr(decoder, "k_pages"):
+        raise NotImplementedError(
+            f"{type(decoder).__name__} has no k_pages/v_pages: the prefix "
+            "cache cannot save or load its pool")
+
+
 @dataclass
 class _Entry:
     key: bytes
@@ -316,6 +326,7 @@ class PrefixCache:
                 "PrefixCache.save needs the decoder whose pool holds "
                 "the cached pages — pass decoder=, or attach the cache "
                 "to an engine first")
+        _refuse_without_kv_pools(dec)
         live = sum(1 for e in self._entries.values() if e.refs)
         if live:
             raise RuntimeError(
@@ -385,6 +396,7 @@ class PrefixCache:
         the save carried host entries). Returns the cache — hand it to
         `ContinuousBatchingEngine(prefix_cache=...)`, whose free list
         excludes the cache-owned pages."""
+        _refuse_without_kv_pools(decoder)
         import json
         import os
         with open(os.path.join(path, "index.json")) as f:

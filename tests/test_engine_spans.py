@@ -325,6 +325,61 @@ def test_serving_program_names_its_parts(tiny_model):
         assert scope in text, scope
 
 
+@pytest.fixture(scope="module")
+def mla_decoder():
+    from paddle_tpu.models.deepseek_v2 import DeepSeekV2, deepseek_v2_tiny
+    from paddle_tpu.serving.mla_decoder import PagedMLADecoder
+    model = DeepSeekV2(deepseek_v2_tiny(experts_held=4, expert_offset=4))
+    return PagedMLADecoder(model, num_pages=2 * 8 + 2, page_size=8,
+                           max_batch=2, max_pages_per_seq=8)
+
+
+def test_the_latent_decoders_record_adds_its_four_counters(mla_decoder):
+    """The MLA decoder's horizons carry the GPT record's fields and the
+    four counters that ride its block (`horizon_counters`), and nothing
+    else; the GPT decoder's record has none of them (FIELDS above)."""
+    eng = ContinuousBatchingEngine(mla_decoder, max_new_tokens=4,
+                                   chunk_tokens=8)
+    for p in PROMPTS:
+        eng.submit(p)
+    eng.run()
+    hz = eng.serve_schedule()
+    assert mla_decoder.horizon_counters == (
+        "expert_assignments", "experts_hit", "absorbed_rows",
+        "materialised_tokens")
+    assert PagedGPTDecoder.horizon_counters == ()
+    for ev in hz:
+        assert set(ev) == FIELDS | set(mla_decoder.horizon_counters)
+        assert ev["program"].startswith("mla_packed_multi_k")
+        assert all(isinstance(ev[c], int) and ev[c] >= 0
+                   for c in mla_decoder.horizon_counters)
+    assert sum(ev["materialised_tokens"] for ev in hz) == \
+        sum(map(len, PROMPTS))
+    assert sum(ev["absorbed_rows"] for ev in hz) == 3 * len(PROMPTS)
+
+
+def test_the_latent_program_names_its_parts_and_its_kind(mla_decoder):
+    import jax.numpy as jnp
+    dec, S = mla_decoder, 2
+    fn = dec._packeds and next(iter(dec._packeds.values()))
+    name = dec.program_name("packed", 2, 16, 8, 8)
+    assert name == "mla_packed_multi_k2_t16_w8_p8"
+    text = jax.jit(lambda *a: dec._packed_multi_step(
+        *a, k=2, t=16, window=8)).lower(
+        dec.weights, dec.latent_pages, jnp.zeros(S, jnp.int32),
+        jnp.zeros(S, jnp.int32), jnp.zeros((S, 8), jnp.int32),
+        jnp.zeros(S, bool), jnp.full(S, 2, jnp.int32),
+        jnp.asarray(-1, jnp.int32),
+        jnp.zeros((S, dec.pend_capacity), jnp.int32),
+        jnp.zeros(S, jnp.int32), jnp.asarray(8, jnp.int32)).as_text(
+        debug_info=True)
+    for scope in ("layers", "mla_q", "latent_write", "paged_gather",
+                  "mla_absorbed", "mla_materialised", "moe_router",
+                  "moe_experts", "moe_shared", "lm_head"):
+        assert scope in text, scope
+    assert fn is None or fn.__name__.startswith("mla_packed_multi_")
+
+
 def _lowered_step(model, loss_fn, batch):
     from paddle_tpu.distributed import Trainer, build_mesh
     build_mesh(dp=1)
