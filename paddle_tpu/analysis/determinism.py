@@ -504,10 +504,12 @@ class _TaintEngine:
     def index_components(self, idx_var):
         """The per-operand-dim index columns of a scatter's indices
         operand, when it is structurally a `concatenate` of broadcast
-        columns (the `.at[pids, offs].set` lowering); None otherwise.
-        Column order follows `scatter_dims_to_operand_dims`, so for
-        pool buffers column 0 is the PAGE id and the last column the
-        in-page OFFSET."""
+        columns (the `.at[li, pids, offs].set` lowering); None
+        otherwise. Column order follows `scatter_dims_to_operand_dims`,
+        so for pool buffers the last column is the in-page OFFSET, the
+        one before it the PAGE id (`_page_column`), and what comes
+        before those the LAYER of the whole [L, P, ps, ...] pool (no
+        such column where a program writes one layer's slice)."""
         v = self.strip(idx_var)
         e = self.defs.get(v) if _is_var(v) else None
         if e is not None and e.primitive.name == "concatenate":
@@ -573,6 +575,12 @@ def _const_only(tags):
     return not tags
 
 
+def _page_column(comps):
+    """The PAGE-id column of a pool scatter's index columns: the one
+    before the offset (a lone column is the page)."""
+    return comps[-2] if len(comps) >= 2 else comps[0]
+
+
 def _check_kv_write(eng, site, findings):
     """KV-WRITE-NONCANONICAL for one pool scatter.  Returns True when
     the write is canonical."""
@@ -582,8 +590,17 @@ def _check_kv_write(eng, site, findings):
     problems = []
     comps = eng.index_components(idx_op) if idx_op is not None else None
     if comps and len(comps) >= 2:
-        page_t = eng.taint(comps[0])
+        page_t = eng.taint(_page_column(comps))
         off_t = eng.taint(comps[-1])
+        for layer in comps[:-2]:
+            # the layer loop's own counter (an iota) or a constant:
+            # which layer is written is the program's, never a
+            # request's or the table's
+            layer_t = eng.taint(layer)
+            if layer_t - {"iota"}:
+                problems.append(
+                    f"layer index carries {sorted(layer_t)}: only the "
+                    "layer loop's counter may pick the layer")
         if "table" not in page_t and not _const_only(page_t):
             problems.append(
                 f"page index carries {sorted(page_t)} without routing "
@@ -631,7 +648,7 @@ def _page_operand(eng, site):
     if idx_op is None:
         return None
     comps = eng.index_components(idx_op)
-    return comps[0] if comps else eng.strip(idx_op)
+    return _page_column(comps) if comps else eng.strip(idx_op)
 
 
 def _offset_operand(eng, site):

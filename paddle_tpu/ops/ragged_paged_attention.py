@@ -14,7 +14,11 @@ serving engine's byte-identical equivalence tests pin.
 
 Shapes:
   q               : (n, W, H, D)   new-token queries (row-local window)
-  k_pages/v_pages : (P, page_size, H, D)  one layer's page pool, OR an
+  k_pages/v_pages : (P, page_size, H, D)  one layer's page pool — or,
+                    with `layer=` given, the WHOLE pool (L, P, page_size,
+                    H, D), of which that layer is read through the page
+                    gather itself (`pool[layer, pages]`: never a slice
+                    of the layer, which would copy it) — OR an
                     int8 pool as the tuple (pages int8, scales f32
                     (P, page_size)) — per-token write-time scales (the
                     serving decoder's kv_quant="int8" layout), OR an
@@ -140,7 +144,7 @@ _UNROLL_PAGES = 32
 
 
 def _ragged_ref(q, k_pages, v_pages, page_table, qpos, scale,
-                k_scale=None, v_scale=None, int4=False):
+                k_scale=None, v_scale=None, int4=False, layer=None):
     """jnp reference: the kernel's page loop as an unrolled loop (small
     tables) or a lax.scan — the same per-page update in the same order
     either way (see _page_update). `qpos` [n, W] is every query's
@@ -152,30 +156,37 @@ def _ragged_ref(q, k_pages, v_pages, page_table, qpos, scale,
     pool (`int4=True`) the payload is nibble-packed [P, ps, PB] and
     `k_scale`/`v_scale` [P, ps, G] carry per-GROUP scales; each page
     dequantizes through the shared `_dequant_page_int4` before its
-    update — the gather stays packed, one page unpacks per step."""
+    update — the gather stays packed, one page unpacks per step.
+    `layer` (a traced scalar; None = the pools are one layer's) names
+    the layer of a whole [L, P, ...] pool to read: it is one more
+    index of the SAME gather, so the layer is never sliced out."""
     n, W, H, D = q.shape
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[1 if layer is None else 2]
     MP = page_table.shape[1]
     safe = jnp.maximum(page_table, 0)
     quantized = k_scale is not None and not int4
+
+    def rows_of(pool):
+        return pool[safe] if layer is None else pool[layer, safe]
+
     # named for the trace: ONE copy of each row's pages, whatever the
     # number of queries in the row's window
     with jax.named_scope("paged_gather"):
         if int4:
             # packed payload [n, MP, ps, PB] -> per-page [MP][n, ps, PB];
             # group scales [n, MP, ps, G] -> per-page [MP][n, ps, G]
-            kg = jnp.moveaxis(k_pages[safe], 1, 0)
-            vg = jnp.moveaxis(v_pages[safe], 1, 0)
-            ksg = jnp.moveaxis(k_scale[safe], 1, 0)
-            vsg = jnp.moveaxis(v_scale[safe], 1, 0)
+            kg = jnp.moveaxis(rows_of(k_pages), 1, 0)
+            vg = jnp.moveaxis(rows_of(v_pages), 1, 0)
+            ksg = jnp.moveaxis(rows_of(k_scale), 1, 0)
+            vsg = jnp.moveaxis(rows_of(v_scale), 1, 0)
         else:
             # [n, MP, ps, H, D] -> per-page [MP][n, H, ps, D]
-            kg = jnp.moveaxis(k_pages[safe], (1, 3), (0, 2))
-            vg = jnp.moveaxis(v_pages[safe], (1, 3), (0, 2))
+            kg = jnp.moveaxis(rows_of(k_pages), (1, 3), (0, 2))
+            vg = jnp.moveaxis(rows_of(v_pages), (1, 3), (0, 2))
             if quantized:
                 # [n, MP, ps] -> per-page [MP][n, ps]
-                ksg = jnp.moveaxis(k_scale[safe], 1, 0)
-                vsg = jnp.moveaxis(v_scale[safe], 1, 0)
+                ksg = jnp.moveaxis(rows_of(k_scale), 1, 0)
+                vsg = jnp.moveaxis(rows_of(v_scale), 1, 0)
     qf = (q.astype(jnp.float32) * scale).transpose(0, 2, 1, 3)  # [n,H,W,D]
     qpos = qpos[:, None, :]                                     # [n,1,W]
 
@@ -234,17 +245,18 @@ def _ragged_ref(q, k_pages, v_pages, page_table, qpos, scale,
 # Inside a jitted caller (the decoder's programs) this inlines away.
 @functools.partial(jax.jit, static_argnames=("scale", "int4"))
 def _dense_ref(q, k_pages, v_pages, page_table, start, scale,
-               k_scale=None, v_scale=None, int4=False):
+               k_scale=None, v_scale=None, int4=False, layer=None):
     """The dense entry point's reference: row i's window sits at
     positions start[i] .. start[i] + W - 1."""
     qpos = start[:, None] + jnp.arange(q.shape[1], dtype=jnp.int32)
     return _ragged_ref(q, k_pages, v_pages, page_table, qpos, scale,
-                       k_scale=k_scale, v_scale=v_scale, int4=int4)
+                       k_scale=k_scale, v_scale=v_scale, int4=int4,
+                       layer=layer)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window", "int4"))
 def _packed_ref(q, k_pages, v_pages, page_table, row_ids, pos, scale,
-                window, k_scale=None, v_scale=None, int4=False):
+                window, k_scale=None, v_scale=None, int4=False, layer=None):
     """The packed entry point's reference: lay the stream out BY ROW and
     run the dense reference once. A row's tokens are contiguous in the
     stream, so row r's window is the `window` stream slots from its
@@ -262,7 +274,8 @@ def _packed_ref(q, k_pages, v_pages, page_table, row_ids, pos, scale,
                        axis=1).astype(jnp.int32)               # [n]
     slot = jnp.minimum(first[:, None] + jnp.arange(window), T - 1)
     out = _ragged_ref(q[slot], k_pages, v_pages, page_table, pos[slot],
-                      scale, k_scale=k_scale, v_scale=v_scale, int4=int4)
+                      scale, k_scale=k_scale, v_scale=v_scale, int4=int4,
+                      layer=layer)
     within = jnp.clip(jnp.arange(T) - first[row_ids], 0, window - 1)
     return out[row_ids, within]
 
@@ -473,10 +486,20 @@ def _packed_kernel_call(q2, k_pages, v_pages, page_table, row_ids, pos,
       pos.astype(jnp.int32), *operands)
 
 
+def _one_layer(layer, *pools):
+    """The pools the Pallas kernels walk: one layer's. The kernel path
+    runs in interpret mode on the CPU alone, where slicing the layer
+    out of a whole pool costs nothing that is measured; the reference,
+    which is what the chip runs, never does (`_ragged_ref`)."""
+    if layer is None:
+        return pools
+    return tuple(None if p is None else p[layer] for p in pools)
+
+
 def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
                                   row_ids, pos, scale=None,
                                   use_kernel=False, interpret=None,
-                                  window=None):
+                                  window=None, layer=None):
     """PACKED-layout causal attention over paged KV: q [T, H, D] is a
     flat stream of new tokens — token t belongs to batch row
     `row_ids[t]` (its row in `page_table` [n, max_pages]) and sits at
@@ -502,8 +525,9 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
     instead: it scalar-prefetches `row_ids` and `pos` next to the page
     table and resolves `page_table[row_ids[t], j]` inside the BlockSpec
     index maps (see `_packed_kernel_call`). int8/int4 pools pass as
-    (pages, scales) tuples exactly like the dense entry point. Returns
-    [T, H, D]."""
+    (pages, scales) tuples exactly like the dense entry point; with
+    `layer` given the pools are whole ([L, P, ...]) and that layer is
+    read, as in the dense entry point. Returns [T, H, D]."""
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
     row_ids = jnp.asarray(row_ids, jnp.int32)
@@ -523,7 +547,7 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
     def reference():
         return _packed_ref(q, k_pages, v_pages, page_table, row_ids,
                            pos, scale=float(scale), window=window,
-                           k_scale=ks, v_scale=vs, int4=int4)
+                           k_scale=ks, v_scale=vs, int4=int4, layer=layer)
 
     if not use_kernel:
         return reference()
@@ -533,9 +557,10 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
     # the same 2-wide shape
     q2 = jnp.stack([q, jnp.zeros_like(q)], axis=1)      # [T, 2, H, D]
     try:
-        return _packed_kernel_call(q2, k_pages, v_pages, page_table,
+        kl, vl, ksl, vsl = _one_layer(layer, k_pages, v_pages, ks, vs)
+        return _packed_kernel_call(q2, kl, vl, page_table,
                                    row_ids, pos, scale, interpret,
-                                   k_scale=ks, v_scale=vs,
+                                   k_scale=ksl, v_scale=vsl,
                                    int4=int4)[:, 0]
     except Exception as e:
         kernel_fallback("ragged_paged_attention_packed", e)
@@ -543,7 +568,8 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, start,
-                           scale=None, use_kernel=False, interpret=None):
+                           scale=None, use_kernel=False, interpret=None,
+                           layer=None):
     """Causal attention of ragged new-token windows over paged KV.
 
     q (n, W, H, D): row i's new tokens at positions start[i]..start[i]+
@@ -558,7 +584,14 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, start,
     [P, ps, PB], per-group scales f32 [P, ps, G]) — kv_quant="int4".
     Both paths dequantize per page next to the shared `_page_update`
     (int8 inside it, int4 through `_dequant_page_int4` right before
-    it — group scales cannot be folded post-dot)."""
+    it — group scales cannot be folded post-dot).
+
+    `layer` (optional, a traced scalar): the pools are then the WHOLE
+    pool, [L, P, ...] in every leaf, and layer `layer` of it is read —
+    through the page gather (`pool[layer, pages]`), so that a layer
+    loop which carries the pool neither slices nor copies it
+    (`mla_paged_attention_packed` reads its latent pool the same
+    way)."""
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
     start = jnp.asarray(start, jnp.int32)
@@ -573,28 +606,32 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, start,
         return ragged_paged_attention(q2, k_pages, v_pages, page_table,
                                       start, scale=scale,
                                       use_kernel=use_kernel,
-                                      interpret=interpret)[:, :1]
+                                      interpret=interpret,
+                                      layer=layer)[:, :1]
     ks = vs = None
     int4 = False
     if isinstance(k_pages, tuple):
         k_pages, ks = k_pages
         v_pages, vs = v_pages
         int4 = k_pages.dtype == jnp.uint8    # nibble-packed payload
-    if not use_kernel:
+
+    def reference():
         return _dense_ref(q, k_pages, v_pages, page_table, start,
                           scale=float(scale), k_scale=ks, v_scale=vs,
-                          int4=int4)
+                          int4=int4, layer=layer)
+
+    if not use_kernel:
+        return reference()
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     try:
-        return _ragged_kernel_call(q, k_pages, v_pages, page_table,
+        kl, vl, ksl, vsl = _one_layer(layer, k_pages, v_pages, ks, vs)
+        return _ragged_kernel_call(q, kl, vl, page_table,
                                    start, scale, interpret,
-                                   k_scale=ks, v_scale=vs, int4=int4)
+                                   k_scale=ksl, v_scale=vsl, int4=int4)
     except Exception as e:
         kernel_fallback("ragged_paged_attention", e)
-        return _dense_ref(q, k_pages, v_pages, page_table, start,
-                          scale=float(scale), k_scale=ks, v_scale=vs,
-                          int4=int4)
+        return reference()
 
 
 # ------------------------------------------------- latent (MLA) attention

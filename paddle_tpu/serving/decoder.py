@@ -354,11 +354,15 @@ def pool_token_bytes(cfg, kv_quant=None, itemsize=2):
     return int(2 * per_tensor)
 
 
-def _kv_set(pool, pids, offs, val):
-    """Write `val` [..., H, D] at (pids, offs) of ONE layer's page pool
-    — the single KV write primitive behind every serving path (decode
-    ticks, chunked suffix prefill, the verify window, ragged horizons;
-    scratch routing is the caller's pids). A plain pool stores the
+def _kv_set(pool, li, pids, offs, val):
+    """Write `val` [..., H, D] at (pids, offs) of layer `li` of the
+    WHOLE page pool [L, P, ps, ...] — the single KV write primitive
+    behind every serving path (decode ticks, chunked suffix prefill,
+    the verify window, ragged horizons; scratch routing is the
+    caller's pids). The layer is an index of the scatter, not a slice:
+    the layer loop carries the pool (`PagedGPTDecoder._scan_layers`)
+    and this writes the new tokens' rows in place, every other byte of
+    the pool untouched. A plain pool stores the
     cast value; a quantized pool (pages, scales) quantizes from the
     token's own amax and stores bytes + scales together, so no write
     site can ever drift from the others — int8 pools (int8 payload)
@@ -370,9 +374,9 @@ def _kv_set(pool, pids, offs, val):
             q, s = _quantize_kv_int4(val)
         else:
             q, s = _quantize_kv(val)
-        return (pages.at[pids, offs].set(q),
-                scales.at[pids, offs].set(s))
-    return pool.at[pids, offs].set(val.astype(pool.dtype))
+        return (pages.at[li, pids, offs].set(q),
+                scales.at[li, pids, offs].set(s))
+    return pool.at[li, pids, offs].set(val.astype(pool.dtype))
 
 
 def _spec_accept(p_rows, q_rows, drafts, rng):
@@ -876,8 +880,9 @@ class PagedGPTDecoder:
              ).astype(self.compute_dtype)                      # [S, h]
         quant = self.quant
 
-        def layer(x, wkv):
-            wl, kp, vp = wkv
+        def layer(carry, xs):
+            x, kp, vp = carry
+            wl, li = xs
             y = _ln(x, wl["ln1_w"], wl["ln1_b"])
             qkv = _mm_heads(y, wl["qkv_w"], wl["qkv_b"], quant)  # [S,3,H,D]
             if aids is not None:
@@ -885,28 +890,50 @@ class PagedGPTDecoder:
                     S, 3, H, D).astype(qkv.dtype)
             q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
             with jax.named_scope("kv_write"):
-                kp = _kv_set(kp, pids, offs, k)
-                vp = _kv_set(vp, pids, offs, v)
+                kp = _kv_set(kp, li, pids, offs, k)
+                vp = _kv_set(vp, li, pids, offs, v)
             # the ONE ragged kernel behind every serving path (decode is
             # the W=1 row kind): causal over kpos <= lens, i.e. the
             # slot's prefix plus the key written just above
             from ..ops.ragged_paged_attention import ragged_paged_attention
             attn = ragged_paged_attention(q[:, None], kp, vp, table, lens,
-                                          use_kernel=self.use_kernel)
+                                          use_kernel=self.use_kernel,
+                                          layer=li)
             x = x + _mm(attn.reshape(S, H * D), wl["proj_w"], wl["proj_b"],
                         quant)
             y = _ln(x, wl["ln2_w"], wl["ln2_b"])
             h = jax.nn.gelu(_mm(y, wl["fc1_w"], wl["fc1_b"], quant),
                             approximate=True)
             x = x + _mm(h, wl["fc2_w"], wl["fc2_b"], quant)
-            return x, (kp, vp)
+            return (x, kp, vp), None
 
-        with jax.named_scope("layers"):
-            x, (k_pages, v_pages) = jax.lax.scan(
-                layer, x, (weights, k_pages, v_pages))
+        x, k_pages, v_pages = self._scan_layers(layer, x, weights,
+                                                k_pages, v_pages)
         x = _ln(x, self.ln_f_w, self.ln_f_b)
         logits = x.astype(jnp.float32) @ self.lm_head.astype(jnp.float32)
         return logits, k_pages, v_pages
+
+    def _scan_layers(self, layer, x, weights, k_pages, v_pages):
+        """THE layer loop of every compiled program of this decoder:
+        `layer` (one of the three blocks: `_forward_tokens`',
+        `_windowed_layer`, `_packed_layer`) runs as a `lax.scan` body
+        whose CARRY is (x, k_pages, v_pages) — the whole pools,
+        [L, P, ps, ...] in every leaf — and whose `xs` are a layer's
+        weights and its index. A block writes its layer of the pools in
+        place (`_kv_set`) and reads it through the page gather
+        (`ragged_paged_attention*(layer=)`); the loop has no `ys`.
+        Pools handed over as `xs` and taken back as `ys` are sliced out
+        of the stack and written back into a fresh one layer by layer,
+        and the fresh stack is copied into the tick scan's carry once a
+        tick: a third of a serving wave's device time on one v5e, none
+        of it arithmetic (`test_packed_horizon_moves_no_pool` holds the
+        compiled horizon to none of the three).
+        Returns (x, k_pages, v_pages)."""
+        with jax.named_scope("layers"):
+            carry, _ = jax.lax.scan(
+                layer, (x, k_pages, v_pages),
+                (weights, jnp.arange(self.cfg.num_layers)))
+        return carry
 
     def _pos_keys(self, kids, pos):
         """Per-slot PRNG keys from (seed, kid, position): draws depend
@@ -1011,8 +1038,9 @@ class PagedGPTDecoder:
         n, W = pos.shape
         quant = self.quant
 
-        def layer(x, wkv):
-            wl, kp, vp = wkv
+        def layer(carry, xs):
+            x, kp, vp = carry
+            wl, li = xs
             y = _ln(x, wl["ln1_w"], wl["ln1_b"])
             yf = y.reshape(n * W, -1)
             qkv = _mm_heads(yf, wl["qkv_w"],
@@ -1025,14 +1053,14 @@ class PagedGPTDecoder:
                     n, W, 3, H, D).astype(qkv.dtype)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             with jax.named_scope("kv_write"):
-                kp = _kv_set(kp, pids, offs, k)
-                vp = _kv_set(vp, pids, offs, v)
+                kp = _kv_set(kp, li, pids, offs, k)
+                vp = _kv_set(vp, li, pids, offs, v)
             # pos rows are contiguous windows (start + arange(W)), so
             # the row's first entry IS its cached length
             from ..ops.ragged_paged_attention import ragged_paged_attention
             attn = ragged_paged_attention(
-                q, kp, vp, table, pos[:, 0],
-                use_kernel=self.use_kernel).astype(x.dtype)
+                q, kp, vp, table, pos[:, 0], use_kernel=self.use_kernel,
+                layer=li).astype(x.dtype)
             o = _mm(attn.reshape(n * W, H * D), wl["proj_w"],
                     wl["proj_b"], quant).reshape(n, W, -1)
             x = x + o
@@ -1042,7 +1070,7 @@ class PagedGPTDecoder:
                     quant), approximate=True)
             x = x + _mm(h, wl["fc2_w"], wl["fc2_b"],
                         quant).reshape(n, W, -1)
-            return x, (kp, vp)
+            return (x, kp, vp), None
 
         return layer
 
@@ -1070,10 +1098,9 @@ class PagedGPTDecoder:
         pids = jnp.where(in_range, pids, self.num_pages - 1)
         offs = pos % ps
 
-        with jax.named_scope("layers"):
-            x, (k_pages, v_pages) = jax.lax.scan(
-                self._windowed_layer(pos, pids, offs, table), x,
-                (weights, k_pages, v_pages))
+        x, k_pages, v_pages = self._scan_layers(
+            self._windowed_layer(pos, pids, offs, table), x, weights,
+            k_pages, v_pages)
         x = _ln(x, self.ln_f_w, self.ln_f_b)
         logits = x.astype(jnp.float32) @ self.lm_head.astype(jnp.float32)
         return (jnp.argmax(logits, axis=-1).astype(jnp.int32), logits,
@@ -1134,10 +1161,9 @@ class PagedGPTDecoder:
         pids = jnp.where(in_range, pids, self.num_pages - 1)
         offs = pos % ps
 
-        with jax.named_scope("layers"):
-            x, (k_pages, v_pages) = jax.lax.scan(
-                self._windowed_layer(pos, pids, offs, table, aids=aids),
-                x, (weights, k_pages, v_pages))
+        x, k_pages, v_pages = self._scan_layers(
+            self._windowed_layer(pos, pids, offs, table, aids=aids), x,
+            weights, k_pages, v_pages)
         x = _ln(x, self.ln_f_w, self.ln_f_b)
         last = jnp.take_along_axis(
             x, jnp.clip(true_len - 1 - start, 0, W - 1)
@@ -1255,8 +1281,9 @@ class PagedGPTDecoder:
         T = rows.shape[0]
         quant = self.quant
 
-        def layer(x, wkv):
-            wl, kp, vp = wkv
+        def layer(carry, xs):
+            x, kp, vp = carry
+            wl, li = xs
             y = _ln(x, wl["ln1_w"], wl["ln1_b"])
             qkv = _mm_heads(y, wl["qkv_w"], wl["qkv_b"],
                             quant)                       # [T, 3, H, D]
@@ -1266,20 +1293,20 @@ class PagedGPTDecoder:
                     T, 3, H, D).astype(qkv.dtype)
             q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
             with jax.named_scope("kv_write"):
-                kp = _kv_set(kp, pids, offs, k)
-                vp = _kv_set(vp, pids, offs, v)
+                kp = _kv_set(kp, li, pids, offs, k)
+                vp = _kv_set(vp, li, pids, offs, v)
             from ..ops.ragged_paged_attention import \
                 ragged_paged_attention_packed
             attn = ragged_paged_attention_packed(
                 q, kp, vp, table, rows, pos, window=window,
-                use_kernel=self.use_kernel).astype(x.dtype)
+                use_kernel=self.use_kernel, layer=li).astype(x.dtype)
             x = x + _mm(attn.reshape(T, H * D), wl["proj_w"],
                         wl["proj_b"], quant)
             y = _ln(x, wl["ln2_w"], wl["ln2_b"])
             h = jax.nn.gelu(_mm(y, wl["fc1_w"], wl["fc1_b"], quant),
                             approximate=True)
             x = x + _mm(h, wl["fc2_w"], wl["fc2_b"], quant)
-            return x, (kp, vp)
+            return (x, kp, vp), None
 
         return layer
 
@@ -1309,11 +1336,10 @@ class PagedGPTDecoder:
         pids = jnp.where(write_ok, pids, self.num_pages - 1)
         offs = pos % ps
 
-        with jax.named_scope("layers"):
-            x, (k_pages, v_pages) = jax.lax.scan(
-                self._packed_layer(rows, pos, pids, offs, table,
-                                   aids=aids, window=window),
-                x, (weights, k_pages, v_pages))
+        x, k_pages, v_pages = self._scan_layers(
+            self._packed_layer(rows, pos, pids, offs, table, aids=aids,
+                               window=window),
+            x, weights, k_pages, v_pages)
         x = _ln(x, self.ln_f_w, self.ln_f_b)
         last = x[jnp.clip(last_idx, 0, x.shape[0] - 1)]   # [S, h]
         last = jnp.where(live[:, None], last, 0.0)

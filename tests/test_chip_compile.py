@@ -232,25 +232,10 @@ def compile_horizon(one_chip, d, k, t, width):
     for the smoke's chunks of 128 tokens (1 on the decode bucket t=8)."""
     import functools
 
-    from paddle_tpu.serving.decoder import packed_window
+    from tests._hlo_pool import compile_packed_horizon
 
-    def shapes(tree):
-        return jax.tree_util.tree_map(
-            lambda v: spec(one_chip, v.shape, v.dtype), tree)
-
-    def i32(*shape):
-        return spec(one_chip, shape, jnp.int32)
-
-    def flags(*shape):
-        return spec(one_chip, shape, jnp.bool_)
-
-    return jax.jit(
-        functools.partial(d._packed_multi_step, k=k, t=t,
-                          window=packed_window(1 if t == B else 128, t)),
-        donate_argnums=(1, 2),
-    ).lower(shapes(d._w()), shapes(d.k_pages), shapes(d.v_pages),
-            i32(B), i32(B), i32(B, width), i32(B), flags(B), i32(B), i32(),
-            i32(B, d.pend_capacity), i32(B), i32()).compile()
+    return compile_packed_horizon(d, k, t, width, 1 if t == B else 128,
+                                  spec=functools.partial(spec, one_chip))
 
 
 # (k, t_tokens, table width) of every packed ragged horizon the smoke's eight
@@ -269,8 +254,29 @@ ON_THE_CHIP = 8         # horizons one run of the smoke keeps loaded at once
 @pytest.mark.slow
 @pytest.mark.parametrize("k,t,width", SMOKE_HORIZONS)
 def test_serve_horizon_two_layers(one_chip, two_layer_decoder, k, t, width):
+    from tests._hlo_pool import pool_moves
+
     compiled = compile_horizon(one_chip, two_layer_decoder, k, t, width)
     assert device_bytes(compiled) < V5E_HBM
+    assert pool_moves(compiled, two_layer_decoder.k_pages) == []
+
+
+@pytest.mark.slow
+def test_decode_horizon_moves_no_pool(one_chip):
+    """The decode horizon (2, 8, 64) as the chip's compiler lays it out:
+    the pools ride the layer loop's carry, so nothing copies the pool,
+    slices a layer out of it or writes a layer back (`tests/_hlo_pool.py`;
+    the CPU twin of this gate is `test_serving.py::
+    test_packed_horizon_moves_no_pool`), and the program's temporaries
+    stay under the K pool alone: a tick's two gathers are 2/L of it, and
+    with the pools as the layer scan's `xs`/`ys` both pools were held
+    again (3.76 pools at these 4 layers)."""
+    from tests._hlo_pool import pool_moves
+
+    d = _serve_decoder(4)
+    compiled = compile_horizon(one_chip, d, 2, 8, 64)
+    assert pool_moves(compiled, d.k_pages) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < d.k_pages.nbytes
 
 
 @pytest.mark.slow

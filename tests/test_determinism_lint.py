@@ -127,6 +127,39 @@ def test_kv_write_table_keyed_twin_is_green():
         r.metrics["n_pool_writes"] == 1
 
 
+_WHOLE = np.zeros((3, 16, 8, 2, 4), np.float32)     # [L, P, ps, H, D]
+
+
+@pytest.mark.parametrize("layer_by", ["loop_counter", "request"])
+def test_kv_write_on_the_whole_pool_reads_page_and_offset_last(layer_by):
+    """The decoders' layer loops carry the WHOLE pool and write
+    `pool.at[layer, page, offset]`: the page and the offset are the last
+    two index columns and keep their rules; the layer column before them
+    may come from the loop's own counter (green: the committed form) and
+    never from a request (red: a layer picked by the position lands a
+    token's bytes in a layer the replay would not pick)."""
+    def write(pool, table, lens, val):
+        pids = jnp.take_along_axis(table, (lens // 8)[:, None],
+                                   axis=1)[:, 0]
+        if layer_by == "request":
+            return pool.at[lens % 3, pids, lens % 8].set(val)
+
+        def layer(pool, li):
+            return pool.at[li, pids, lens % 8].set(val), None
+        return jax.lax.scan(layer, pool, jnp.arange(3))[0]
+    p = lower_callable(write, _WHOLE, _TABLE, _LENS, _VAL,
+                       name=f"whole_pool_{layer_by}",
+                       arg_infos=_infos(*_POOL_INFOS))
+    r = analyze_determinism(p)
+    assert r.metrics["n_pool_writes"] == 1
+    if layer_by == "request":
+        assert [f.rule_id for f in r.findings] == ["KV-WRITE-NONCANONICAL"]
+        assert "layer index" in r.findings[0].message
+    else:
+        assert r.findings == []
+        assert r.metrics["n_canonical_writes"] == 1
+
+
 # -------------------------------------------- rule twins: RNG-KEY-TAINT
 
 

@@ -471,3 +471,85 @@ def test_packed_reference_gathers_by_row_not_by_token(pool, window):
     # and no value carries the stream's T and the table's width together
     assert not [s for s in sizes if len(s[2]) >= 2
                 and s[2][0] == T and s[2][1] == MP], sizes
+
+
+# ------------------------------------------------ the layer of a whole pool
+
+def _whole_pools(seed, L, P, ps, H, D, pool):
+    """Whole [L, P, ...] pools whose layers are drawn apart, and the list
+    of the one-layer pools they were stacked from."""
+    layers = [_pools(seed + 101 * li, P, ps, H, D, pool) for li in range(L)]
+
+    def stack(parts):
+        return jax.tree_util.tree_map(lambda *x: jnp.stack(x), *parts)
+
+    return (stack([kp for kp, _ in layers]),
+            stack([vp for _, vp in layers]), layers)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8", "int4"])
+@pytest.mark.parametrize("entry", ["dense", "packed", "packed_kernel"])
+def test_layer_of_a_whole_pool_reads_that_layer_bit_for_bit(pool, entry):
+    """`layer=` on a whole [L, P, ...] pool is the one-layer call on that
+    layer, bit for bit, for every layer, on all three pool layouts
+    (payload and scales alike), through the dense and the packed entry
+    point and through the interpret-mode kernel — with the layer a TRACED
+    scalar, as the decoder's layer loop hands it over."""
+    L, n, MP, ps, H, D, P = 3, 2, 4, 4, 2, 16, 10
+    rng = np.random.RandomState(41)
+    kw, vw, layers = _whole_pools(41, L, P, ps, H, D, pool)
+    table = jnp.asarray(rng.randint(0, P, (n, MP)).astype(np.int32))
+    if entry == "dense":
+        q = jnp.asarray(rng.randn(n, 3, H, D).astype(np.float32))
+        rest = (table, jnp.asarray([2, 9], jnp.int32))
+        fn, kw_args = ragged_paged_attention, {}
+    else:
+        rows, pos = _pack([(0, 4, 3), (1, 11, 1)])
+        q = jnp.asarray(rng.randn(len(rows), H, D).astype(np.float32))
+        rest = (table, rows, pos)
+        fn = ragged_paged_attention_packed
+        kw_args = {"use_kernel": entry == "packed_kernel"}
+    by_layer = jax.jit(lambda li: fn(q, kw, vw, *rest, layer=li, **kw_args))
+    outs = []
+    for li, (kp, vp) in enumerate(layers):
+        alone = np.asarray(fn(q, kp, vp, *rest, **kw_args)
+                           .astype(jnp.float32))
+        whole = np.asarray(by_layer(jnp.int32(li)).astype(jnp.float32))
+        assert np.array_equal(alone, whole), (pool, entry, li)
+        outs.append(whole)
+    assert not np.array_equal(outs[0], outs[1])      # the layers differ
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8", "int4"])
+@pytest.mark.parametrize("entry", ["dense", "packed"])
+def test_reference_reads_the_layer_through_the_gather(pool, entry):
+    """The reference never slices its layer out of the whole pool: no
+    value of the traced program has the size of a layer of the pool (the
+    largest is the per-row gather), and no `dynamic_slice` is taken of a
+    pool leaf. `pool[layer][pages]` would copy the layer once a layer and
+    tick — what the layer loop's carry exists to avoid."""
+    L, n, MP, ps, H, D, P = 3, 2, 4, 4, 2, 16, 64
+    rng = np.random.RandomState(43)
+    kw, vw, _ = _whole_pools(43, L, P, ps, H, D, pool)
+    table = jnp.asarray(rng.randint(0, P, (n, MP)).astype(np.int32))
+    if entry == "dense":
+        q = jnp.asarray(rng.randn(n, 3, H, D).astype(np.float32))
+        call = lambda li: ragged_paged_attention(
+            q, kw, vw, table, jnp.asarray([2, 9], jnp.int32), layer=li)
+    else:
+        rows, pos = _pack([(0, 4, 3), (1, 11, 1)])
+        q = jnp.asarray(rng.randn(len(rows), H, D).astype(np.float32))
+        call = lambda li: ragged_paged_attention_packed(
+            q, kw, vw, table, rows, pos, layer=li)
+    jaxpr = jax.make_jaxpr(call)(jnp.int32(1)).jaxpr
+    payload = jax.tree_util.tree_leaves(kw)[0]
+    layer_elems = int(np.prod(payload.shape[1:]))
+    eqns = [e for j in _sub_jaxprs(jaxpr) for e in j.eqns]
+    sizes = [(int(np.prod(v.aval.shape)), e.primitive.name)
+             for e in eqns for v in e.outvars if hasattr(v.aval, "shape")]
+    assert max(sizes)[0] < layer_elems, max(sizes)
+    leaf_shapes = {leaf.shape for leaf in jax.tree_util.tree_leaves((kw, vw))}
+    sliced = [e for e in eqns
+              if e.primitive.name in ("dynamic_slice", "slice", "squeeze")
+              and e.invars[0].aval.shape in leaf_shapes]
+    assert not sliced, sliced

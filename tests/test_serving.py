@@ -957,3 +957,115 @@ def test_scheduler_plans_pow2_token_buckets(tiny_model):
     plan2 = sched2.plan({0: 0, 1: 1, 2: 2, 3: 3},
                         {0: 8, 1: 8, 2: 8, 3: 8}, [0] * 4)
     assert plan2.w == 8 and plan2.t_tokens == 16
+
+
+# --------------------------------------------------------------------------
+# The page pool rides the layer loop's carry: written and read in place
+# --------------------------------------------------------------------------
+
+def _pool_kw(pool):
+    """Decoder arguments of the three pool layouts."""
+    import jax.numpy as jnp
+    return {"bf16": dict(dtype=jnp.bfloat16), "int8": dict(kv_quant="int8"),
+            "int4": dict(kv_quant="int4")}[pool]
+
+
+def _pool_leaves(dec):
+    """Every leaf of both pools (payload and, quantised, scales), as
+    bytes, without the scratch page (masked writes land there)."""
+    import jax
+    return [np.asarray(leaf)[:, :dec.num_pages - 1].view(np.uint8)
+            for leaf in jax.tree_util.tree_leaves((dec.k_pages,
+                                                   dec.v_pages))]
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8", "int4"])
+def test_pools_byte_identical_across_engines(tiny_model, pool):
+    """After the same requests the per-tick engine, the dense ragged
+    engine and the packed engine leave THE SAME BYTES in the same pages
+    — payload and scales, every layer — and emit the same streams: one
+    write (`_kv_set` at the layer's index of the whole pool) behind all
+    three blocks of the decoder."""
+    dec_kw = _pool_kw(pool)
+    rng = np.random.RandomState(900)
+    V = tiny_model.cfg.vocab_size
+    # as many requests as slots, sent together: every engine hands them
+    # the same pages, so the pools can be compared page for page
+    prompts = [list(rng.randint(0, V, n).astype(int)) for n in (23, 5)]
+    runs = {}
+    for name, eng_kw in (("tick", dict(k_max=1)),
+                         ("dense", dict(k_max=4, chunk_tokens=8,
+                                        packed=False)),
+                         ("packed", dict(k_max=4, chunk_tokens=8,
+                                         packed=True))):
+        streams, eng = _stream_kw(tiny_model, prompts, 9, None, dec_kw,
+                                  **eng_kw)
+        runs[name] = (streams, _pool_leaves(eng.d))
+    assert len(runs["tick"][1]) == (2 if pool == "bf16" else 4)
+    assert any(leaf.any() for leaf in runs["tick"][1])
+    for name in ("dense", "packed"):
+        assert runs[name][0] == runs["tick"][0], (pool, name)
+        for a, b in zip(runs[name][1], runs["tick"][1]):
+            assert a.shape == b.shape and np.array_equal(a, b), (pool, name)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8", "int4"])
+def test_kv_write_at_a_layer_leaves_the_other_layers_untouched(tiny_model,
+                                                                pool):
+    """`_kv_set` at layer l of the whole pool changes the written (page,
+    offset) rows of layer l and not one other byte: every other layer,
+    and every other row of layer l, keeps what it held — payload and
+    scales."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving.decoder import _kv_set
+    dec = PagedGPTDecoder(tiny_model, num_pages=6, page_size=4,
+                          max_batch=1, **_pool_kw(pool))
+    rng = np.random.RandomState(901)
+    H, D = tiny_model.cfg.num_heads, tiny_model.cfg.head_dim
+    L = tiny_model.cfg.num_layers
+
+    def filled(leaf):          # nothing is zero before the write
+        raw = rng.randint(1, 100, leaf.shape)
+        return jnp.asarray(raw).astype(leaf.dtype)
+
+    before = jax.tree_util.tree_map(filled, dec.k_pages)
+    pids = jnp.asarray([4, 1, 4], jnp.int32)
+    offs = jnp.asarray([0, 3, 2], jnp.int32)
+    val = jnp.asarray(rng.randn(3, H, D).astype(np.float32)) * 50
+    for li in range(L):
+        after = jax.jit(_kv_set)(before, jnp.int32(li), pids, offs, val)
+        for b, a in zip(jax.tree_util.tree_leaves(before),
+                        jax.tree_util.tree_leaves(after)):
+            b, a = np.asarray(b), np.asarray(a)
+            assert a.dtype == b.dtype and a.shape == b.shape
+            written = np.zeros(b.shape[:3], bool)
+            written[li, np.asarray(pids), np.asarray(offs)] = True
+            assert np.array_equal(a[~written], b[~written]), (pool, li)
+            assert not np.array_equal(a[written], b[written]), (pool, li)
+
+
+def test_packed_horizon_moves_no_pool(tiny_model):
+    """The compiled packed decode horizon, jitted as the engine jits it
+    (`_packed_multi_step`, pools donated), neither copies the page pool
+    nor slices a layer out of it nor writes a layer back into it: the
+    pools ride the layer loop's carry and the only instruction with a
+    pool-shaped result is the in-place scatter of the new tokens. With
+    the pools as the layer scan's `xs`/`ys` the program held all three
+    (a `dynamic-slice` and a `dynamic-update-slice` of a layer per layer,
+    a `copy` of the whole pool per tick) and both pools again as
+    temporaries."""
+    from tests._hlo_pool import compile_packed_horizon, pool_moves
+
+    paddle.seed(7)
+    model = GPT(gpt_tiny(max_seq_len=128, dtype="float32", remat=False,
+                         num_layers=4))
+    model.eval()
+    S = 4
+    dec = PagedGPTDecoder(model, num_pages=S * 16 + 2, page_size=16,
+                          max_batch=S)
+    compiled = compile_packed_horizon(dec, k=2, t=S, width=8, w=1)
+    assert pool_moves(compiled, dec.k_pages) == []
+    # what is left is a tick's gathers (2/L of a pool) and activations
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        dec.k_pages.nbytes
