@@ -4,7 +4,7 @@ DatasetFolder + DataLoader, comparing native libjpeg decode
 (shared-memory transport). Plus a synthetic INPUT-BOUND training
 workload comparing the synchronous feed (host batch + per-step
 float(loss)) against io.DeviceLoader + LossBuffer (async sharded
-prefetch, batched loss syncs) — printed as a bench.py-style
+prefetch, batched loss syncs) — printed as one
 {"metric": ...} JSON line.
 
 Run:  JAX_PLATFORMS=cpu PYTHONPATH=. python examples/bench_dataloader.py
@@ -84,7 +84,7 @@ def bench_device_feed(steps=60, batch=64, dim=512, hidden=2048, classes=10,
     worker processes — everything a real pipeline waits on outside the
     interpreter) plus numpy assembly. The synchronous loop serializes
     that wait with the compiled step; DeviceLoader hides it behind step
-    N's compute. Prints ONE JSON line like bench.py.
+    N's compute. Prints ONE JSON line.
 
     (On this CPU mesh the "device" step also burns host cores, so
     CPU-bound host transforms can't overlap — that half of the story

@@ -21,8 +21,7 @@ This module replaces the brute force with static search:
 Front doors: `debug.autotune(trainer, batch, hbm_budget=...)`,
 `Trainer.suggest_config(batch)`, the CLI
 (`python -m paddle_tpu.analysis --autotune`), and
-`rank_gpt_candidates` (`bench.py` reorders its headline group by it
-under `PADDLE_TPU_BENCH_ADVISE=1`).
+`rank_gpt_candidates`.
 """
 import gc
 from contextlib import contextmanager

@@ -1,6 +1,5 @@
 """Per-model lint & memory manifests — the committed, diffable face of
-the Graph Doctor (same role as perf_evidence.json for the analytic perf
-model: regenerate, diff, review).
+the Graph Doctor (regenerate, diff, review).
 
 `lint_manifests/<config>.json` pins each BASELINE config's op counts,
 collective accounting, and finding summary. The graph-shape analyzer
@@ -42,7 +41,7 @@ _DETERMINISM_SCHEMA = 1
 
 
 def manifest_dir():
-    """Repo-root lint_manifests/ (next to perf_evidence.json)."""
+    """Repo-root lint_manifests/."""
     here = os.path.dirname(os.path.abspath(__file__))
     repo = os.path.dirname(os.path.dirname(here))
     return os.path.join(repo, "lint_manifests")

@@ -63,7 +63,7 @@ _ALIASES = {
     "everything_saveable": "none",
 }
 
-# bench.py / GPTConfig.remat_policy vocabulary -> advisor policy names
+# GPTConfig.remat_policy vocabulary -> advisor policy names
 # (the model's 'dots' maps to jax dots_with_no_batch_dims_saveable —
 # see models/gpt._remat_policy)
 BENCH_POLICY_NAMES = {

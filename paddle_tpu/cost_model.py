@@ -7,8 +7,8 @@ analytically, and profile_measure times the real jitted program.
 This module also hosts the OFFLINE half of config selection:
 
   * `ChipSpec` / `chip_spec` — the per-generation peak FLOP/s, HBM
-    bandwidth/size and interconnect numbers bench.py uses for MFU and
-    roofline framing, in one queryable table;
+    bandwidth/size and interconnect numbers the pricing below uses,
+    in one queryable table;
   * `eqn_flops` / `jaxpr_flops` — analytic FLOPs of a traced jaxpr
     (dot/conv priced exactly from shapes, elementwise at 1 flop/elem,
     scan multiplied by trip count) — the compute numerator no chip is
@@ -43,8 +43,8 @@ __all__ = ["CostModel", "collective_wire_bytes", "collective_wire_split",
 #
 # Per-chip peak numbers (bf16 MXU FLOP/s, HBM bytes/s and capacity,
 # aggregate one-direction ICI bytes/s, per-chip share of the host DCN
-# NIC). The flops/HBM columns are the same table bench.py has always
-# used for MFU; ICI/DCN are approximate public figures — they feed
+# NIC). The flops/HBM columns are the published peaks (the benchmark's
+# own: peaks.json); ICI/DCN are approximate public figures — they feed
 # RELATIVE ranking and the wire-bound roofline leg, not accounting.
 
 @dataclass(frozen=True)

@@ -2,7 +2,7 @@
 
 Decode reads every weight byte each step; int4 halves that traffic vs
 int8 (a8w8) and quarters it vs bf16 — the HBM roofline moves up
-accordingly (bench.decode_roofline_tok_s). Storage: per-out-channel
+accordingly (`PagedGPTDecoder.step_hbm_bytes`). Storage: per-out-channel
 symmetric int4 (q in [-7, 7], scale = amax/7), two nibbles packed per
 int8 byte along the IN dim with a +8 offset (nibble value 1..15).
 

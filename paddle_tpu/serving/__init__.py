@@ -22,15 +22,15 @@ decoding).  TPU-native design, split across this package:
   instead of once per token (cf. Ragged Paged Attention, arXiv
   2604.15464; T3's overlap analysis, arXiv 2401.16677).  Mixed
   horizons and the chunked prefill dispatch the PACKED
-  [total_new_tokens] token-stream layout by default (per-token row
+  [total_new_tokens] token-stream layout (per-token row
   ids, pow2 total-token buckets — docs/serving.md "Packed ragged
-  layout"); `packed=False` keeps the dense [S, w] window twin for
-  byte-identity A/B.
+  layout"): the one layout.
 - `engine.py` — `ContinuousBatchingEngine.run()` schedules horizons of
   `k = min(K_max, smallest remaining budget)` ticks and overlaps each
   block's host fetch with the NEXT block's dispatch (one-horizon-
   delayed retirement); `cost_model.decode_horizon` prices the default
-  K from the chip's tick roofline vs the measured host sync cost.
+  K from the chip's tick roofline vs the measured host sync cost;
+  an explicit `k_max=1` runs the per-tick loop, the tests' reference.
   `SpeculativeEngine` layers draft-propose/target-verify decoding on
   top.
 - `prefix_cache.py` — content-addressed KV page sharing: hash (token

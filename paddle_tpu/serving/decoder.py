@@ -28,12 +28,11 @@ def clear_compiled_memos():
     still live recompiles on its next call. Returns entries dropped."""
     n = 0
     for dec in list(_LIVE_DECODERS):
-        for memo in (dec._multis, dec._raggeds, dec._packeds,
+        for memo in (dec._multis, dec._packeds,
                      dec._packed_prefills, dec._mount_multi):
             n += len(memo)
             memo.clear()
-        for attr in ("_verify", "_probs", "_suffix_prefill", "_copy",
-                     "_mount"):
+        for attr in ("_verify", "_probs", "_copy", "_mount"):
             if getattr(dec, attr) is not None:
                 n += 1
                 setattr(dec, attr, None)
@@ -124,8 +123,7 @@ def packed_tick(carry, w, eos, *, t, capacity, forward):
     P = pend.shape[1]
     is_pf = pend_n > 0
     # per-row stream share: decode 1, prefill min(pend_n, w),
-    # frozen 0 (the packed layout simply skips frozen rows —
-    # the dense twin computes their scratch-routed windows)
+    # frozen 0 (the packed layout simply skips frozen rows)
     nl = jnp.where(done, 0,
                    jnp.where(is_pf, jnp.minimum(pend_n, w), 1))
     csum = jnp.cumsum(nl)
@@ -157,9 +155,7 @@ def packed_tick(carry, w, eos, *, t, capacity, forward):
     real = total.astype(jnp.int32)
     if counters:
         real = jnp.stack([real, *counters]).astype(jnp.int32)
-    # shift each row's suffix by the DYNAMIC w (a gather — the
-    # dense twin's static concatenate+slice can't take a traced
-    # width); over-shift past pend_n clears like the dense path
+    # shift each row's suffix by the DYNAMIC w (a gather)
     idx = jnp.arange(P)[None, :] + w
     pend = jnp.where(idx < P,
                      pend[jnp.arange(S)[:, None],
@@ -262,15 +258,15 @@ def _pack_int4(q):
     return lo | (hi << 4)
 
 
-def _unpack_int4(packed):
+def _unpack_int4(nibbles):
     """Inverse of `_pack_int4`: uint8 nibble pairs -> int8 values in
     [-8, 7] (sign-extended), last dim doubled."""
-    lo = (packed & 0xF).astype(jnp.int8)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int8)
+    lo = (nibbles & 0xF).astype(jnp.int8)
+    hi = ((nibbles >> 4) & 0xF).astype(jnp.int8)
     lo = jnp.where(lo >= 8, lo - 16, lo)
     hi = jnp.where(hi >= 8, hi - 16, hi)
     return jnp.stack([lo, hi], axis=-1).reshape(
-        packed.shape[:-1] + (packed.shape[-1] * 2,))
+        nibbles.shape[:-1] + (nibbles.shape[-1] * 2,))
 
 
 def _quantize_kv_int4(val, group=INT4_GROUP):
@@ -311,11 +307,11 @@ def _quantize_kv_int4(val, group=INT4_GROUP):
     return _pack_int4(q), scale.astype(jnp.float32)
 
 
-def _dequantize_kv_int4(packed, scale, heads_shape, group=INT4_GROUP):
+def _dequantize_kv_int4(nibbles, scale, heads_shape, group=INT4_GROUP):
     """Inverse of `_quantize_kv_int4` up to quantization error:
     unpack nibbles, multiply each group by its scale, reshape back to
     [..., H, D] (`heads_shape` = (H, D))."""
-    q = _unpack_int4(packed).astype(jnp.float32)
+    q = _unpack_int4(nibbles).astype(jnp.float32)
     hd = int(heads_shape[0]) * int(heads_shape[1])
     group = min(int(group), hd)
     n_groups = scale.shape[-1]
@@ -331,9 +327,8 @@ def pool_token_bytes(cfg, kv_quant=None, itemsize=2):
     write-time scale per plane; int4 pools pay 0.5 B/elem packed
     nibbles + one f32 scale per `INT4_GROUP` elements (per-group
     scales — see `_quantize_kv_int4`). THE byte model behind
-    `PagedGPTDecoder.kv_token_bytes` / `step_hbm_bytes` and the
-    capacity bench (`bench.run_decode_capacity`) — one definition, so
-    the bench can price big-model shapes without building the model
+    `PagedGPTDecoder.kv_token_bytes` / `step_hbm_bytes` — one definition,
+    so a caller can price big-model shapes without building the model
     and can never drift from what the decoder reports."""
     if kv_quant not in (None, "int8", "int4"):
         raise ValueError(
@@ -498,7 +493,7 @@ class PagedGPTDecoder:
     def __init__(self, model, num_pages=128, page_size=16, max_batch=8,
                  max_pages_per_seq=None, quant=None, kv_quant=None,
                  use_kernel=False, dtype=None, temperature=0.0, top_k=0,
-                 top_p=1.0, seed=0, mesh=None, packed=True):
+                 top_p=1.0, seed=0, mesh=None):
         cfg = model.cfg
         self.cfg = cfg
         self.page_size = page_size
@@ -509,14 +504,6 @@ class PagedGPTDecoder:
         self.quant = quant
         self.kv_quant = kv_quant
         self.use_kernel = use_kernel
-        # PACKED token-stream layout (default): ragged horizons and
-        # chunked prefill dispatch flat [total_new_tokens] streams with
-        # per-token row ids instead of dense [S, w] windows — decode
-        # rows pay one token per tick, not w. packed=False keeps the
-        # dense window layout end to end: the A/B twin the
-        # byte-identity tests (and the pad-fraction bench) compare
-        # against.
-        self.packed = bool(packed)
         assert quant in (None, "a8w8", "w4a16"), quant
         assert kv_quant in (None, "int8", "int4"), kv_quant
         # temperature 0 = greedy (reference decode convention)
@@ -640,7 +627,6 @@ class PagedGPTDecoder:
         # the mixed horizons are memoized per table width too (a shape:
         # it compiled a program of its own before as well), so that each
         # program carries its whole key in its name
-        self._raggeds = {}    # (k, w, width) -> jitted mixed ragged horizon
         self._packeds = {}    # (k, t, window, width) -> jitted PACKED horizon
         # (w rides as a traced scalar — per-dispatch width changes
         # never compile a new program; dispatches bucket by total
@@ -649,7 +635,6 @@ class PagedGPTDecoder:
         self._packed_prefills = {}   # (t, window) -> jitted packed prefill
         self._verify = None   # jitted lazily (speculative decoding only)
         self._probs = None    # jitted lazily (sampled speculation)
-        self._suffix_prefill = None   # jitted lazily (chunked prefill)
         self._copy = None     # jitted lazily (copy-on-write page copy)
         self._mount = None    # jitted lazily (host-tier page restore)
         self._mount_multi = {}   # span length -> jitted batched restore
@@ -683,19 +668,17 @@ class PagedGPTDecoder:
     def program_name(kind, k, x, width, window=None):
         """The ONE name of a horizon's program, made of the key the
         decoder memoizes it by — the dispatch shape (`kind`, k ticks,
-        `x` = the packed token bucket t or the ragged window w), the
+        `x` = the packed token bucket t), the
         page table's `width` in columns and, packed, the `window` one
         row's tokens are laid out in (`packed_window`): `jit_<name>` on
         a trace's "XLA Modules" line, `program` on the engine's
         `engine.dispatch` span
         and on the horizon's record, and what `first_use` is asked
-        about. Kinds: "packed", "ragged", "decode" (`decode_multi`, on
+        about. Kinds: "packed", "decode" (`decode_multi`, on
         the whole table) and "tick" (the per-tick `decode`). Cached: a
         round pays a lookup, not a format."""
         if kind == "packed":
             return f"packed_multi_k{k}_t{x}_w{window}_p{width}"
-        if kind == "ragged":
-            return f"ragged_multi_k{k}_w{x}_p{width}"
         return f"decode_multi_k{k}" if kind == "decode" else "decode_step"
 
     # ---------------------------------------------------- multi-LoRA
@@ -1021,18 +1004,13 @@ class PagedGPTDecoder:
         return ret
 
     def _windowed_layer(self, pos, pids, offs, table, aids=None):
-        """ONE ragged-attention transformer layer shared by the verify
-        window (`_verify_step`), the chunked prefill
-        (`_prefill_suffix_step`) and every tick of the mixed ragged
-        horizon (`_ragged_multi_step`): write each position's K/V at
+        """ONE ragged-attention transformer layer over [n, W] windows,
+        the verify window's (`_verify_step`): write each position's K/V at
         (pids, offs) — callers route out-of-range/padded positions to
         the scratch page — attend over the row's pages with
         per-position causality (kpos <= pos) through the shared
         `ops.ragged_paged_attention` primitive, then residual proj +
-        FFN. A single body means a masking or scratch-routing fix can
-        never diverge the programs (the byte-identical cache-on/off and
-        ragged-vs-per-tick guarantees ride on every path computing
-        exactly the same per-position bytes)."""
+        FFN."""
         cfg = self.cfg
         H, D = cfg.num_heads, cfg.head_dim
         n, W = pos.shape
@@ -1119,149 +1097,6 @@ class PagedGPTDecoder:
             return np.asarray(out), self._probs_of(logits)
         return np.asarray(out)
 
-    def _ragged_forward(self, weights, k_pages, v_pages, ids, start,
-                        true_len, table, kids, frozen=None, aids=None):
-        """The shared RAGGED chunk forward: consume each row's [W]-wide
-        window of new tokens at positions start..true_len-1, attending
-        against the row's paged prefix. ids [n, W] window tokens
-        (zero-padded), start [n] positions already in the pages (cached
-        prefix + previously consumed chunks; = the decode position for
-        a decode row), true_len [n] position count after this window,
-        table [n, max_pages], kids [n] sampling key ids, `frozen` [n]
-        routes EVERY write of a frozen row to scratch (the fused
-        horizon's done mask).
-
-        K/V is written at positions start..true_len-1 — padded
-        positions (pos >= true_len) and table overflow route to the
-        reserved scratch page, so real pages hold ONLY real KV (full
-        blocks become content-addressable cache entries). Per-position
-        computations are independent of the padded width W and the
-        batch rows (matmuls are row-local, attention reduces over the
-        row's own page gather), so a position's bytes are identical
-        whether it was computed alone, in a batch, as a decode tick
-        (W=1 window) or inside any chunking of its prompt — the
-        property every byte-identical equivalence test pins. The layer
-        body is `_windowed_layer`, shared with `_verify_step`. Returns
-        (next token [n] — sampled at position true_len-1 with the
-        standard (seed, kid, position) key — k_pages, v_pages)."""
-        cfg, ps = self.cfg, self.page_size
-        n, W = ids.shape
-        pos = start[:, None] + jnp.arange(W)[None, :]           # [n, W]
-        x = (self.wte[ids] +
-             self.wpe[jnp.clip(pos, 0, cfg.max_seq_len - 1)]
-             ).astype(self.compute_dtype)                       # [n, W, h]
-        MP = table.shape[1]
-        # scratch-route every write that isn't a real position: the
-        # padded tail (pos >= true_len), table overflow, frozen rows
-        in_range = (pos < true_len[:, None]) & (pos < MP * ps)
-        if frozen is not None:
-            in_range = in_range & ~frozen[:, None]
-        pids = jnp.take_along_axis(table, jnp.minimum(pos // ps, MP - 1),
-                                   axis=1)                      # [n, W]
-        pids = jnp.where(in_range, pids, self.num_pages - 1)
-        offs = pos % ps
-
-        x, k_pages, v_pages = self._scan_layers(
-            self._windowed_layer(pos, pids, offs, table, aids=aids), x,
-            weights, k_pages, v_pages)
-        x = _ln(x, self.ln_f_w, self.ln_f_b)
-        last = jnp.take_along_axis(
-            x, jnp.clip(true_len - 1 - start, 0, W - 1)
-            [:, None, None].astype(jnp.int32), axis=1)[:, 0]    # [n, h]
-        logits = last.astype(jnp.float32) @ \
-            self.lm_head.astype(jnp.float32)
-        keys = None
-        if self.sampling is not None:
-            # same (seed, kid, position) key walk as decode: the
-            # window's last token sits at true_len-1, whatever span of
-            # the prompt was cache-mounted or chunked before it
-            keys = self._pos_keys(kids, true_len - 1)
-        return _sample_tokens(logits, self.sampling, keys), \
-            k_pages, v_pages
-
-    def _prefill_suffix_step(self, weights, k_pages, v_pages, ids, start,
-                             true_len, table, kids, aids=None):
-        """Chunked prefill: consume the UNCACHED suffix of each prompt
-        in one forward, attending against the paged prefix (the
-        prefix-cache mounts cached pages into `table` host-side; a
-        `start=0` row is simply a full, uncached prompt). The body is
-        `_ragged_forward` — the same program shape as a decode tick,
-        which is its W=1 special case."""
-        return self._ragged_forward(weights, k_pages, v_pages, ids,
-                                    start, true_len, table, kids,
-                                    aids=aids)
-
-    def _ragged_multi_step(self, weights, k_pages, v_pages, tokens, lens,
-                           table, kids, done, remaining, eos, pend,
-                           pend_n, aids=None, *, k, w):
-        """K MIXED ragged ticks inside ONE compiled program: every tick
-        serves decode rows and prefill-chunk rows together through the
-        same `_ragged_forward` body (Ragged Paged Attention, arxiv
-        2604.15464) — so a prompt streams into the KV pool w tokens per
-        tick WITHOUT a separate host-blocking prefill dispatch, and
-        running decode slots keep emitting a token per tick alongside
-        it.
-
-        Carry per slot: `tokens` [S] last emitted token, `lens` [S]
-        positions consumed so far (mounted prefix + chunks + decode
-        appends), `done`/`remaining` as in `_decode_multi_step`, and
-        the device-resident prompt suffix `pend` [S, P] with its length
-        `pend_n` [S] (P static = the pool's token capacity). A tick's
-        window for slot s is its next min(pend_n, w) suffix tokens
-        while prefilling (new_len up to w), or its one sampled token
-        once decoding (new_len=1) — the ragged row kinds of the paper.
-        A prefill row emits nothing until the tick that consumes its
-        last suffix token, which samples the first generated token at
-        position true_len-1 with the standard (seed, kid, position)
-        key — exactly the token the host-blocking chunked prefill
-        would have produced, so streams are byte-identical across
-        schedules. Frozen slots' writes route to scratch as in the
-        decode-only loop.
-
-        Returns (block [k, S] tokens, emitted [k, S] — True where the
-        tick really produced a token (False for filler AND mid-prefill
-        ticks) — final tokens/lens/done/remaining/pend/pend_n,
-        k_pages, v_pages)."""
-        S = tokens.shape[0]
-        P = pend.shape[1]
-
-        def tick(carry, _):
-            tokens, lens, done, remaining, pend, pend_n, kp, vp = carry
-            is_pf = pend_n > 0
-            new_len = jnp.where(is_pf, jnp.minimum(pend_n, w), 1)
-            window = jnp.concatenate(
-                [tokens[:, None],
-                 jnp.zeros((S, w - 1), jnp.int32)], axis=1) \
-                if w > 1 else tokens[:, None]
-            ids = jnp.where(is_pf[:, None], pend[:, :w], window)
-            true = lens + new_len
-            nxt, kp, vp = self._ragged_forward(
-                weights, kp, vp, ids, lens, true, table, kids,
-                frozen=done, aids=aids)
-            emit = ~done & (pend_n <= w)       # decode row, or the
-            nxt = jnp.where(emit, nxt, tokens)  # chunk finishing prefill
-            rem = jnp.where(emit, remaining - 1, remaining)
-            new_done = done | (emit & ((nxt == eos) | (rem <= 0)))
-            new_lens = jnp.where(done, lens, lens + new_len)
-            # real positions this tick consumed (the pad-fraction
-            # ledger's numerator): live rows' new_len, frozen rows 0 —
-            # the dense tick dispatched S*w positions for these
-            real = jnp.sum(jnp.where(done, 0, new_len)).astype(jnp.int32)
-            pend = jnp.concatenate(
-                [pend[:, w:], jnp.zeros((S, min(w, P)), pend.dtype)],
-                axis=1)[:, :P]
-            pend_n = jnp.maximum(pend_n - w, 0)
-            return (nxt, new_lens, new_done, rem, pend, pend_n, kp, vp), \
-                (nxt, emit, real)
-
-        carry = (tokens, lens, done, remaining, pend, pend_n,
-                 k_pages, v_pages)
-        carry, outs = jax.lax.scan(tick, carry, jnp.arange(k))
-        tokens, lens, done, remaining, pend, pend_n, k_pages, v_pages = \
-            carry
-        return (outs[0], outs[1], outs[2], tokens, lens, done, remaining,
-                pend, pend_n, k_pages, v_pages)
-
     def _packed_layer(self, rows, pos, pids, offs, table, aids=None,
                       window=None):
         """ONE transformer layer over the PACKED token stream: x is
@@ -1271,11 +1106,8 @@ class PagedGPTDecoder:
         and attention runs through the packed ragged primitive
         (`ops.ragged_paged_attention_packed`), which gathers each
         ROW's pages once and lays the row's tokens out as one window
-        of at most `window` queries over them. Per-token math is the
-        dense
-        `_windowed_layer`'s exactly (row-local matmuls, the same
-        per-page attention walk), so a real position's bytes are
-        bit-identical packed vs dense — the A/B-twin guarantee."""
+        of at most `window` queries over them. Per-token math is
+        row-local: the stream's other tokens never move a byte."""
         cfg = self.cfg
         H, D = cfg.num_heads, cfg.head_dim
         T = rows.shape[0]
@@ -1324,9 +1156,7 @@ class PagedGPTDecoder:
         no tokens — masked by `live`), whose hidden state prices the
         row's logits; `sample_pos` [S] is the sampling position
         (true_len - 1, the standard (seed, kid, position) key walk).
-        Returns (next [S], k_pages, v_pages) — exactly what the dense
-        `_ragged_forward` returns, from exactly the same per-position
-        bytes."""
+        Returns (next [S], k_pages, v_pages)."""
         cfg, ps = self.cfg, self.page_size
         MP = table.shape[1]
         x = (self.wte[ptok] +
@@ -1359,8 +1189,7 @@ class PagedGPTDecoder:
         tick's stream concatenates every live row's new tokens (decode
         rows exactly ONE token, prefilling rows their next min(pend_n,
         w) suffix tokens, frozen rows NOTHING), so nobody pays window
-        padding — the dense twin (`_ragged_multi_step`) dispatches
-        S*w positions per tick, this dispatches at most t, bucketed by
+        padding: this dispatches at most t, bucketed by
         total token count alone. `w` is a TRACED scalar (the per-row
         chunk cap); `window` (static, >= w; None = t) is the bound the
         attention lays one row's tokens out in — `packed_window(w, t)`,
@@ -1369,13 +1198,10 @@ class PagedGPTDecoder:
         layout (cumsum + searchsorted
         over per-row token counts) is built on device each tick from
         the carry, so the program stays one host-sync-free lax.scan
-        (SERVE-HOST-SYNC-DECODE gates it like the dense twin).
+        (SERVE-HOST-SYNC-DECODE gates it).
 
-        Every per-row rule is the dense tick's verbatim: same emit
-        condition, same (seed, kid, true_len-1) sampling keys, same
-        freeze/budget updates, same scratch routing — and per-position
-        math rides the shared packed primitive — so streams and pool
-        bytes are byte-identical to the dense twin and the per-tick
+        Every per-row rule is `packed_tick`'s: streams and pool
+        bytes are byte-identical to the per-tick
         engine (test-pinned). Returns the RaggedMultiOut tuple layout
         (tokens_block [k, S], emitted [k, S], real [k], finals...)."""
         def forward(lay, pools):
@@ -1431,14 +1257,13 @@ class PagedGPTDecoder:
         A thin wrapper over the chunked ragged body at start=0: the
         separate flash-attention length-bucketed prefill is GONE — ALL
         prefill runs through the same per-position program family as
-        decode and the verify window (`_ragged_forward`), so a prompt's
+        the mixed horizons (`_packed_forward`), so a prompt's
         KV bytes are identical across every admission path (flash vs
         chunked drift is structurally impossible)."""
         return self.prefill_suffix_batch(
             [(ids, 0, pages) for ids, pages in requests], kids=kids)
 
-    def prefill_suffix_batch(self, requests, kids=None, packed=None,
-                             aids=None):
+    def prefill_suffix_batch(self, requests, kids=None, aids=None):
         """Chunked prefill over page-table rows (the prefix-cache
         admission path). requests: [(suffix_ids, start, pages), ...] —
         `pages` is the sequence's page list in block order (cached
@@ -1446,78 +1271,18 @@ class PagedGPTDecoder:
         pages), `start` the cached prefix length (0 = nothing cached:
         the suffix IS the prompt).
 
-        PACKED (the default): each group of up to max_batch requests
+        PACKED: each group of up to max_batch requests
         dispatches ONE flat [total_tokens] stream
         (`_prefill_packed_step`) bucketed by total token count (pow2)
         — mixed suffix lengths share one compiled program instead of
         one per (suffix-width, batch) pair, and nobody pays
-        pad-to-longest window columns. `packed=False` keeps the dense
-        window twin (`_prefill_suffix_step`, per-(W, nb) pow2 buckets)
-        — byte-identical first tokens (per-position math is layout-
-        independent, test-pinned). Returns the first generated token
-        per request (in order)."""
-        if packed is None:
-            packed = self.packed
-        if packed:
-            return self._prefill_packed_batch(requests, kids=kids,
-                                              aids=aids)
-        results = [None] * len(requests)
-        if kids is None:
-            kids = list(range(len(requests)))
-        if aids is None:
-            aids = [0] * len(requests)
-        if self._suffix_prefill is None:
-            self._suffix_prefill = _named_jit(
-                self._prefill_suffix_step, "prefill_suffix",
-                donate_argnums=(1, 2))
-        MP = self.max_pages
-        groups = {}
-        for i, (ids, start, pages) in enumerate(requests):
-            ids = np.asarray(ids, np.int32)
-            W = 4
-            while W < len(ids):
-                W *= 2
-            groups.setdefault(W, []).append((i, ids, int(start), pages))
-        for W, group in groups.items():
-            while group:
-                nb = 1
-                while nb * 2 <= len(group) and nb * 2 <= self.max_batch:
-                    nb *= 2
-                chunk, group = group[:nb], group[nb:]
-                pad = np.zeros((nb, W), np.int32)
-                st = np.zeros(nb, np.int32)
-                tl = np.ones(nb, np.int32)
-                tbl = np.full((nb, MP), self.num_pages - 1, np.int32)
-                kd = np.zeros(nb, np.int32)
-                ad = np.zeros(nb, np.int32)
-                for r, (i, ids, start, pages) in enumerate(chunk):
-                    pad[r, :len(ids)] = ids
-                    st[r] = start
-                    tl[r] = start + len(ids)
-                    k = min(len(pages), MP)
-                    tbl[r, :k] = pages[:k]     # rest stays on scratch
-                    kd[r] = kids[i]
-                    ad[r] = aids[i]
-                self._draws += 1
-                call = (jnp.asarray(pad), jnp.asarray(st),
-                        jnp.asarray(tl), jnp.asarray(tbl),
-                        jnp.asarray(kd))
-                if self.lora is not None:
-                    call += (jnp.asarray(ad),)
-                nxt, self.k_pages, self.v_pages = self._suffix_prefill(
-                    self._w(), self.k_pages, self.v_pages, *call)
-                nxt = np.asarray(nxt)
-                for r, (i, _, _, _) in enumerate(chunk):
-                    results[i] = int(nxt[r])
-        return results
-
-    def _prefill_packed_batch(self, requests, kids=None, aids=None):
-        """PACKED prefill dispatch (see `prefill_suffix_batch`): the
+        pad-to-longest window columns: the
         layout — flat tokens, per-token row ids and positions — is
         built host-side (all lengths are known here), bucketed to a
         pow2 total-token count and a pow2 longest suffix (the
         attention's per-row window, `packed_window`), and jitted once
-        per pair (`_packed_prefills`)."""
+        per pair (`_packed_prefills`). Returns the first generated
+        token per request (in order)."""
         results = [None] * len(requests)
         if kids is None:
             kids = list(range(len(requests)))
@@ -1817,17 +1582,15 @@ class PagedGPTDecoder:
         device-resident ticks in one lax.scan) is traced instead of the
         single tick — the SERVE-HOST-SYNC-DECODE rule checks it for
         host transfers and kept cache donation. With `prefix_w` the
-        chunked-prefill program is traced — PACKED by default
+        chunked-prefill program is traced
         (`_prefill_packed_step`, one flat stream at total-token bucket
-        S*prefix_w; a `packed=False` decoder traces the dense
-        `_prefill_suffix_step` window twin) — the prefix-cache
+        S*prefix_w) — the prefix-cache
         admission path, gated by the same serving rules plus the
         MEM-PAGE-REFCOUNT ledger audit (`gpt_decode_prefix` PROGRAM
         config). With `ragged=(k, w)` the MIXED ragged horizon program
-        is traced — PACKED by default (`_packed_multi_step`: K ticks
+        is traced (`_packed_multi_step`: K ticks
         over the flat [t] token stream, t = the pow2 bucket of one
-        w-wide chunk row next to S-1 decode rows, w a traced input;
-        `packed=False` traces the dense `_ragged_multi_step` twin) —
+        w-wide chunk row next to S-1 decode rows, w a traced input) —
         the `gpt_decode_ragged` PROGRAM config gates it with
         SERVE-HOST-SYNC-DECODE and (via an engine schedule trace on
         the context) SERVE-PREFILL-STALL. `donate=False` traces the
@@ -1894,76 +1657,33 @@ class PagedGPTDecoder:
                       ("pend", pend), ("pend_n", pend_n)]
             if aid_in is not None:
                 inputs.append(("aids", aid_in))
-            if self.packed:
-                # the PACKED horizon program: t = the pow2 total-token
-                # bucket of one full-chunk prefill row riding next to
-                # S-1 decode rows (the canonical mixed tick); w is a
-                # TRACED input, its pow2 bucket the attention's static
-                # per-row window, as `ragged_multi` keys it
-                t = pow2_at_least(S - 1 + rw)
-                w_in = jnp.asarray(rw, jnp.int32)
-                inputs.append(("w", w_in))
-                fn = jax.jit(functools.partial(self._packed_multi_step,
-                                               k=rk, t=t,
-                                               window=packed_window(rw, t)),
-                             donate_argnums=(1, 2) if donate else ())
-                traced = fn.trace(W_ALL, self.k_pages,
-                                  self.v_pages, tokens, lens, table,
-                                  kids, done, remaining, eos, pend,
-                                  pend_n, w_in, *aid_tail)
-                name = f"ragged_packed_k{rk}_t{t}"
-            else:
-                fn = jax.jit(functools.partial(self._ragged_multi_step,
-                                               k=rk, w=rw),
-                             donate_argnums=(1, 2) if donate else ())
-                traced = fn.trace(W_ALL, self.k_pages,
-                                  self.v_pages, tokens, lens, table,
-                                  kids, done, remaining, eos, pend,
-                                  pend_n, *aid_tail)
-                name = f"ragged_multi_k{rk}_w{rw}"
+            t = pow2_at_least(S - 1 + rw)   # a chunk beside decode rows
+            w_in = jnp.asarray(rw, jnp.int32)
+            inputs.append(("w", w_in))
+            fn = jax.jit(functools.partial(self._packed_multi_step,
+                                           k=rk, t=t,
+                                           window=packed_window(rw, t)),
+                         donate_argnums=(1, 2) if donate else ())
+            traced = fn.trace(W_ALL, self.k_pages, self.v_pages, tokens,
+                              lens, table, kids, done, remaining, eos,
+                              pend, pend_n, w_in, *aid_tail)
+            name = f"ragged_packed_k{rk}_t{t}"
         elif prefix_w:
             W = int(prefix_w)
-            if self.packed:
-                # the PACKED prefill program: one flat stream covering
-                # a full admission batch at suffix bucket W — the
-                # total-token bucket S*W replaces the (W, nb) grid
-                t = pow2_at_least(S * W)
-                ptok = jnp.zeros((t,), jnp.int32)
-                pos = jnp.zeros((t,), jnp.int32)
-                rows = jnp.zeros((t,), jnp.int32)
-                ok = jnp.zeros((t,), bool)
-                last_idx = jnp.zeros((S,), jnp.int32)
-                spos = jnp.zeros((S,), jnp.int32)
-                live = jnp.ones((S,), bool)
-                inputs = [("ptok", ptok), ("pos", pos), ("rows", rows),
-                          ("write_ok", ok), ("table", table),
-                          ("last_idx", last_idx), ("sample_pos", spos),
-                          ("kids", kids), ("live", live)]
-                if aid_in is not None:
-                    inputs.append(("aids", aid_in))
-                fn = jax.jit(functools.partial(self._prefill_packed_step,
-                                               window=packed_window(W, t)),
-                             donate_argnums=(1, 2) if donate else ())
-                traced = fn.trace(W_ALL, self.k_pages,
-                                  self.v_pages, ptok, pos, rows, ok,
-                                  table, last_idx, spos, kids, live,
-                                  *aid_tail)
-                name = f"prefill_packed_t{t}"
-            else:
-                ids = jnp.zeros((S, W), jnp.int32)
-                start = jnp.zeros((S,), jnp.int32)
-                true_len = jnp.ones((S,), jnp.int32)
-                inputs = [("ids", ids), ("start", start),
-                          ("true_len", true_len), ("table", table),
-                          ("kids", kids)]
-                if aid_in is not None:
-                    inputs.append(("aids", aid_in))
-                fn = jax.jit(self._prefill_suffix_step,
-                             donate_argnums=(1, 2) if donate else ())
-                traced = fn.trace(W_ALL, self.k_pages,
-                                  self.v_pages, ids, start, true_len,
-                                  table, kids, *aid_tail)
-                name = f"prefill_suffix_w{W}"
+            t = pow2_at_least(S * W)    # a full admission batch
+            zt, zs = jnp.zeros((t,), jnp.int32), jnp.zeros((S,), jnp.int32)
+            inputs = [("ptok", zt), ("pos", zt), ("rows", zt),
+                      ("write_ok", jnp.zeros((t,), bool)), ("table", table),
+                      ("last_idx", zs), ("sample_pos", zs),
+                      ("kids", kids), ("live", jnp.ones((S,), bool))]
+            if aid_in is not None:
+                inputs.append(("aids", aid_in))
+            fn = jax.jit(functools.partial(self._prefill_packed_step,
+                                           window=packed_window(W, t)),
+                         donate_argnums=(1, 2) if donate else ())
+            traced = fn.trace(W_ALL, self.k_pages, self.v_pages,
+                              *(v for _, v in inputs))
+            name = f"prefill_packed_t{t}"
         elif k:
             tokens = jnp.zeros((S,), jnp.int32)
             lens = jnp.zeros((S,), jnp.int32)
@@ -2010,12 +1730,11 @@ class PagedGPTDecoder:
         slot's KV prefix at `avg_ctx` (default: half the model's max
         sequence). The numerator of the decode tick roofline —
         `cost_model.decode_horizon` prices the default multi-step K
-        from it; bench.decode_roofline_tok_s is the tok/s view of the
-        same bytes model. An int8 pool reports its TRUE byte stream
+        from it. An int8 pool reports its TRUE byte stream
         (int8 payload + the f32 per-token scale planes), so the horizon
-        K, the ragged chunk budget and the capacity bench all re-price
+        K and the ragged chunk budget re-price
         automatically when the pool quantizes. `batch` overrides the
-        slot count (bench.run_decode_capacity sweeps it to find the
+        slot count (capacity planning sweeps it to find the
         max slots under a fixed per-token p99). `kv_quant` overrides
         the pool's quant mode for WHAT-IF pricing — e.g.
         ``kv_quant="int4"`` prices the per-group-scale int4 pool
@@ -2140,14 +1859,13 @@ class PagedGPTDecoder:
 
     def ragged_multi(self, tokens, lens, table, k, w, pend, pend_n,
                      kids=None, done=None, remaining=None, eos=None,
-                     packed=None, t_tokens=None, aids=None):
+                     t_tokens=None, aids=None):
         """Run `k` MIXED ragged ticks device-resident: decode rows and
         prefill-chunk rows serve together, up to w suffix tokens per
         prefilling slot per tick, ONE dispatch, zero intermediate host
         syncs.
 
-        PACKED (the default, `packed=None` -> the decoder's `packed`
-        flag): each tick dispatches the flat [t_tokens] token stream
+        Each tick dispatches the flat [t_tokens] token stream
         (`_packed_multi_step`) — decode rows pay ONE token, not a
         w-wide window — jitted per (k, t_tokens, `packed_window(w,
         t_tokens)`, table width) with w riding as a traced scalar, so
@@ -2155,18 +1873,13 @@ class PagedGPTDecoder:
         `HorizonPlan.t_tokens` prices it) and by the pow2 step of w.
         `t_tokens` must cover the largest per-tick total (live rows +
         chunk shares; defaults to the dense-equivalent S*w bound when
-        the caller doesn't supply the tight bucket). `packed=False`
-        dispatches the dense [S, w] window twin (`_ragged_multi_step`,
-        jitted per (k, w)) — byte-identical streams, kept for A/B
-        pad-fraction evidence.
+        the caller doesn't supply the tight bucket).
 
         All inputs/outputs may stay on device; `pend` [S, P] /
         `pend_n` [S] are the carried prompt suffixes
         (P = `pend_capacity`). Returns a RaggedMultiOut."""
         k, w = int(k), int(w)
         S = self.max_batch
-        if packed is None:
-            packed = self.packed
         if done is None:
             done = np.zeros(S, bool)
         if remaining is None:
@@ -2181,46 +1894,27 @@ class PagedGPTDecoder:
                 jnp.asarray(-1 if eos is None else int(eos), jnp.int32),
                 jnp.asarray(pend, jnp.int32),
                 jnp.asarray(pend_n, jnp.int32))
-        if packed:
-            if t_tokens is None:
-                # safe default: the dense-equivalent total (callers
-                # that know the live mix pass the tight pow2 bucket)
-                t_tokens = pow2_at_least(S * max(w, 1))
-            t = max(int(t_tokens), 1)
-            if t < S:
-                # every live slot owns at least one stream share; a
-                # bucket below S could silently drop rows' tokens
-                raise ValueError(
-                    f"t_tokens {t} < max_batch {S}: the packed bucket "
-                    "must cover at least one token per slot")
-            width = args[2].shape[1]
-            window = packed_window(w, t)
-            key = (k, t, window, width)
-            fn = self._packeds.get(key)
-            if fn is None:
-                fn = _named_jit(
-                    functools.partial(self._packed_multi_step, k=k, t=t,
-                                      window=window),
-                    self.program_name("packed", k, t, width, window),
-                    donate_argnums=(1, 2))
-                self._packeds[key] = fn
-            call = args + (jnp.asarray(w, jnp.int32),)
-            if self.lora is not None:
-                call += (jnp.asarray(self._aids_or_default(aids)),)
-            out = fn(self._w(), self.k_pages, self.v_pages, *call)
-        else:
-            width = args[2].shape[1]
-            key = (k, w, width)
-            fn = self._raggeds.get(key)
-            if fn is None:
-                fn = _named_jit(
-                    functools.partial(self._ragged_multi_step, k=k, w=w),
-                    self.program_name("ragged", k, w, width),
-                    donate_argnums=(1, 2))
-                self._raggeds[key] = fn
-            call = args
-            if self.lora is not None:
-                call += (jnp.asarray(self._aids_or_default(aids)),)
-            out = fn(self._w(), self.k_pages, self.v_pages, *call)
+        if t_tokens is None:        # callers pass the tight pow2 bucket
+            t_tokens = pow2_at_least(S * max(w, 1))
+        t = max(int(t_tokens), 1)
+        if t < S:                   # a live slot's token would be dropped
+            raise ValueError(
+                f"t_tokens {t} < max_batch {S}: the packed bucket "
+                "must cover at least one token per slot")
+        width = args[2].shape[1]
+        window = packed_window(w, t)
+        key = (k, t, window, width)
+        fn = self._packeds.get(key)
+        if fn is None:
+            fn = _named_jit(
+                functools.partial(self._packed_multi_step, k=k, t=t,
+                                  window=window),
+                self.program_name("packed", k, t, width, window),
+                donate_argnums=(1, 2))
+            self._packeds[key] = fn
+        call = args + (jnp.asarray(w, jnp.int32),)
+        if self.lora is not None:
+            call += (jnp.asarray(self._aids_or_default(aids)),)
+        out = fn(self._w(), self.k_pages, self.v_pages, *call)
         self.k_pages, self.v_pages = out[9], out[10]
         return RaggedMultiOut(*out[:9])

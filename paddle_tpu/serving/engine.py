@@ -68,12 +68,13 @@ class ContinuousBatchingEngine:
     retirement: a slot finishing inside block N stays frozen on device
     through block N+1 — its writes route to the scratch page — and its
     pages are freed exactly once, when block N is processed).
-    `ragged=False` keeps the dispatch-separate baseline (`_run_multi`:
+    `ragged=False` keeps the dispatch-separate loop (`_run_multi`:
     blocking chunked prefill at admission + decode-only
-    `decode_multi` horizons — byte-identical streams, used as the
-    stall bench's before). `k_max` defaults to
-    `cost_model.decode_horizon`'s priced answer; `k_max=1` selects the
-    legacy per-tick loop (`step()` is the per-tick API either way).
+    `decode_multi` horizons — byte-identical streams; on the chip 2-3%
+    more tokens/s, tails 1.4-1.8 x as long: PERF.md, PR 33). `k_max`
+    defaults to `cost_model.decode_horizon`'s priced answer; `k_max=1`
+    selects the legacy per-tick loop (`step()` is the per-tick API
+    either way).
 
     With `prefix_cache` (a `PrefixCache`) admission becomes
     content-addressed: each prompt's full token blocks are hashed
@@ -94,7 +95,7 @@ class ContinuousBatchingEngine:
     def __init__(self, decoder: PagedGPTDecoder, eos_token_id=None,
                  max_new_tokens=64, k_max=None, host_sync_s=None,
                  prefix_cache=None, ragged=None, chunk_tokens=None,
-                 scheduler=None, trace=None, packed=None,
+                 scheduler=None, trace=None,
                  host_tier=None, tier_policy="auto"):
         if max_new_tokens < 1:
             raise ValueError(
@@ -242,18 +243,10 @@ class ContinuousBatchingEngine:
         # packs a whole admission wave into one dispatch
         self.ragged = bool((k_max is None or self.k_max > 1)
                            if ragged is None else ragged)
-        # PACKED token-stream dispatch for the ragged horizons (default:
-        # the decoder's layout flag): every tick pays its total token
-        # count, bucketed pow2 (`HorizonPlan.t_tokens`) — not the dense
-        # [S, w] window grid. packed=False selects the dense A/B twin
-        # on THIS engine regardless of the decoder default (the
-        # pad-fraction bench runs both off one decoder).
-        self.packed = bool(decoder.packed if packed is None else packed)
         _refuse_unserved(decoder, {
             "prefix_cache": self.cache is not None,
             "host_tier": self.tier is not None,
-            "ragged=False": not self.ragged,
-            "packed=False": not self.packed})
+            "ragged=False": not self.ragged})
         self._prompt_len = [0] * S           # admitted prompt length/slot
         # THE record of every dispatched horizon (one "horizon" dict
         # each, always on: `_begin_round`), beside the "prefill_sync"
@@ -289,7 +282,7 @@ class ContinuousBatchingEngine:
         if self.trace is not None:
             self.trace.meta.update(
                 engine=type(self).__name__, k_max=self.k_max,
-                ragged=self.ragged, packed=self.packed,
+                ragged=self.ragged,
                 page_size=decoder.page_size,
                 kv_quant=decoder.kv_quant or "none")
         _ENGINES.add(self)
@@ -808,13 +801,9 @@ class ContinuousBatchingEngine:
         work — and only positions start..L-1 compute). Freshly computed
         full blocks are published to the cache afterwards."""
         if self.cache is None:
-            # packed=self.packed: the engine-level layout choice covers
-            # the admission prefill too — a packed=False engine is the
-            # dense twin END TO END, whatever the decoder's default
             return self.d.prefill_suffix_batch(
                 [(ids, 0, pages) for _, _, ids, pages in admitted],
                 kids=[rid for _, rid, _, _ in admitted],
-                packed=self.packed,
                 aids=[self._rid_adapter.get(rid, 0)
                       for _, rid, _, _ in admitted])
         reqs = []
@@ -823,7 +812,6 @@ class ContinuousBatchingEngine:
             reqs.append((ids[start:], start, pages))
         firsts = self.d.prefill_suffix_batch(
             reqs, kids=[rid for _, rid, _, _ in admitted],
-            packed=self.packed,
             aids=[self._rid_adapter.get(rid, 0)
                   for _, rid, _, _ in admitted])
         for slot, rid, ids, pages in admitted:
@@ -1625,7 +1613,7 @@ class ContinuousBatchingEngine:
             real = real[:, 0]
         with _Phase("engine.bookkeep", rec, "book_s", **ids):
             # pad ledger: dispatched is the horizon's layout cost (k *
-            # the packed t_tokens bucket, or k*S*w dense); real is the
+            # the packed t_tokens bucket); real is the
             # device's per-tick consumed-position count — exact even
             # when EOS froze a slot mid-horizon
             disp_toks = rec["tokens_dispatched"]
@@ -1772,7 +1760,7 @@ class ContinuousBatchingEngine:
                                 self._slot_pages, self.d)
                         width = self._table_width(live, plan, inflight)
                         t_tokens = plan.t_tokens
-                        if self.packed and t_tokens is None:
+                        if t_tokens is None:
                             # a custom scheduler may build HorizonPlan
                             # without t_tokens: fall back to the
                             # dense-equivalent bucket here so the
@@ -1783,14 +1771,11 @@ class ContinuousBatchingEngine:
                 if plan is not None:
                     tokens_d, lens_d, done_d, rem_d, pend_d, pend_n_d = \
                         carry
-                    # the jit key is (k, t-or-w, table width): a fresh
+                    # the jit key is (k, t, window, table width): a fresh
                     # combination compiles inside this window
-                    shape = (("packed", plan.k, t_tokens) if self.packed
-                             else ("ragged", plan.k, plan.w))
+                    shape = ("packed", plan.k, t_tokens)
                     program = self.d.program_name(
-                        *shape, width,
-                        packed_window(plan.w, t_tokens) if self.packed
-                        else None)
+                        *shape, width, packed_window(plan.w, t_tokens))
                     with _Phase("engine.dispatch", rec, "dispatch_s",
                                 seq=seq, program=program):
                         out = self.d.ragged_multi(
@@ -1798,8 +1783,7 @@ class ContinuousBatchingEngine:
                             self._table_cache[:, :width], plan.k, plan.w,
                             pend_d, pend_n_d, kids=self._kids,
                             done=done_d, remaining=rem_d, eos=self.eos,
-                            packed=self.packed, t_tokens=t_tokens,
-                            aids=self._aids)
+                            t_tokens=t_tokens, aids=self._aids)
                     carry = (out.tokens, out.lens, out.done,
                              out.remaining, out.pend, out.pend_n)
                     self.steps += plan.k
@@ -1810,16 +1794,13 @@ class ContinuousBatchingEngine:
                     for s, e in plan.emit_ticks.items():
                         inflight[s] += e
                     # layout cost of this dispatch: the packed path
-                    # pays the total-token bucket per tick, the dense
-                    # twin the full [S, w] window grid
+                    # pays the total-token bucket per tick
                     self._horizon_dispatched(
                         rec, shape, program, k=plan.k, w=plan.w,
-                        t_tokens=t_tokens if self.packed else None,
+                        t_tokens=t_tokens,
                         decode_rows=len(live) - plan.prefill_rows,
                         prefill_rows=plan.prefill_rows,
-                        disp_toks=plan.k * (t_tokens if self.packed
-                                            else S * plan.w),
-                        width=width)
+                        disp_toks=plan.k * t_tokens, width=width)
                     meta = (out.tokens_block, out.emitted, out.real,
                             plan.k,
                             {s: (rid, self._slot_gen[s])

@@ -23,16 +23,16 @@ What it keeps: the engine reaches a decoder only through `ragged_multi`,
 `prefill_suffix_batch`, `copy_page`, `program_name`/`first_use`,
 `pend_capacity`, `step_hbm_bytes`, `kv_page_bytes`, `cache_fingerprint`
 and the plain attributes (`num_pages`, `page_size`, `max_batch`,
-`max_pages`, `packed`, `kv_quant`, `lora`, `n_adapters`, `sampling`,
+`max_pages`, `kv_quant`, `lora`, `n_adapters`, `sampling`,
 `cfg.max_seq_len`, `cfg.num_params()`), and the packed token-stream
 layout and tick themselves: `decoder.packed_tick` and
 `decoder.packed_prefill_layout` are the one definition both decoders run.
 
 Only the engine's default path exists here: packed ragged horizons and
 packed chunked prefill, greedy. Every other option RAISES at construction
-(`quant`, `kv_quant`, `use_kernel`, sampling, `mesh`/tp, `packed=False`)
+(`quant`, `kv_quant`, `use_kernel`, sampling, `mesh`/tp)
 or when an engine is built over it (`engine_refusals`: prefix cache, host
-tier, speculation, the dense and the dispatch-separate loops); adapters
+tier, speculation, the dispatch-separate and per-tick loops); adapters
 have no attach method. Nothing falls back silently.
 """
 import functools
@@ -98,7 +98,6 @@ class PagedMLADecoder:
                         "k_pages/v_pages; the latent pool has neither",
         "host_tier": "the host tier moves k_pages/v_pages payloads",
         "speculation": "there is no verify program over the latent pool",
-        "packed=False": "only the packed token-stream layout is built",
         "ragged=False": "only the mixed ragged horizon is built (no "
                         "decode_multi, no per-tick decode)",
     }
@@ -106,14 +105,13 @@ class PagedMLADecoder:
     def __init__(self, model, num_pages=128, page_size=16, max_batch=8,
                  max_pages_per_seq=None, quant=None, kv_quant=None,
                  use_kernel=False, dtype=None, temperature=0.0, top_k=0,
-                 top_p=1.0, mesh=None, packed=True, release_model=False):
+                 top_p=1.0, mesh=None, release_model=False):
         cfg = model.cfg
         refused = {
             "quant": quant, "kv_quant": kv_quant, "mesh": mesh,
             "use_kernel": use_kernel or None,
             "temperature": temperature or None, "top_k": top_k or None,
             "top_p": None if top_p == 1.0 else top_p,
-            "packed=False": None if packed else True,
             "dtype": None if dtype is None
             or jnp.dtype(dtype) == jnp.dtype(cfg.dtype) else dtype}
         asked = sorted(k for k, v in refused.items() if v is not None)
@@ -132,7 +130,7 @@ class PagedMLADecoder:
         self.max_batch = int(max_batch)
         self.max_pages = max_pages_per_seq or \
             (cfg.max_seq_len + page_size - 1) // page_size
-        self.packed, self.sampling = True, None      # greedy: no seed used
+        self.sampling = None                         # greedy: no seed used
         self.kv_quant = self.lora = None
         self.n_adapters = 0
         self.compute_dtype = dt = jnp.dtype(cfg.dtype)
@@ -362,7 +360,7 @@ class PagedMLADecoder:
 
     def ragged_multi(self, tokens, lens, table, k, w, pend, pend_n,
                      kids=None, done=None, remaining=None, eos=None,
-                     packed=None, t_tokens=None, aids=None):
+                     t_tokens=None, aids=None):
         """`PagedGPTDecoder.ragged_multi` for this decoder: `k` mixed
         ticks in one dispatch, jitted per (k, t_tokens, window, table
         width) with w a traced scalar. Greedy: `kids` is not read.
@@ -370,8 +368,6 @@ class PagedMLADecoder:
         len(horizon_counters)]."""
         k, w = int(k), int(w)
         S = self.max_batch
-        if packed is False:
-            raise NotImplementedError(self.engine_refusals["packed=False"])
         if aids is not None and np.any(np.asarray(aids)):
             raise NotImplementedError("PagedMLADecoder has no adapters")
         if done is None:
@@ -407,15 +403,12 @@ class PagedMLADecoder:
         self.latent_pages = out[9]
         return RaggedMultiOut(*out[:9])
 
-    def prefill_suffix_batch(self, requests, kids=None, packed=None,
-                             aids=None):
-        """Packed chunked prefill (`PagedGPTDecoder.prefill_suffix_batch`,
-        its packed half): requests [(suffix_ids, start, pages), ...], up
+    def prefill_suffix_batch(self, requests, kids=None, aids=None):
+        """Packed chunked prefill (`PagedGPTDecoder.prefill_suffix_batch`
+        for this decoder): requests [(suffix_ids, start, pages), ...], up
         to max_batch of them a dispatch as ONE flat stream bucketed by
         total tokens; every row attends materialised. Returns each
         request's first generated token."""
-        if packed is False:
-            raise NotImplementedError(self.engine_refusals["packed=False"])
         results = [None] * len(requests)
         S, MP, ps = self.max_batch, self.max_pages, self.page_size
         todo = list(enumerate(requests))

@@ -77,7 +77,7 @@ class RaggedScheduler:
         self.d = decoder
         hbm = decoder.step_hbm_bytes()
         # matmul FLOPs one prompt token costs (the 2*params GPT rule —
-        # same constant bench.py and prefill_ttft_s use)
+        # the constant prefill_ttft_s uses)
         self.flops_per_token = 2.0 * decoder.cfg.num_params()
         if chunk_tokens is None:
             chunk_tokens = ragged_chunk_tokens(

@@ -76,8 +76,7 @@ class ServeStats:
     # pad ledger (lifetime counters, every engine's HORIZON/TICK
     # dispatch paths — per-tick, fused, ragged, speculative): how many
     # token POSITIONS the dispatched layouts computed vs how many of
-    # them were padding (window columns of decode rows on the dense
-    # [S, w] layout, frozen/empty rows' filler, packed-bucket slack).
+    # them were padding (frozen/empty rows' filler, packed-bucket slack).
     # Blocking-path prefill dispatches (ragged=False admission) are
     # NOT in the ledger — the ragged default has none. pad_fraction =
     # padded/dispatched is the packed-ragged-layout headline: pay for

@@ -257,7 +257,7 @@ class TenantEngine(ContinuousBatchingEngine):
     def __init__(self, decoder, eos_token_id=None, max_new_tokens=64,
                  k_max=None, host_sync_s=None, prefix_cache=None,
                  chunk_tokens=None, scheduler=None, trace=None,
-                 packed=None, host_tier=None, tier_policy="auto",
+                 host_tier=None, tier_policy="auto",
                  preemption=True):
         if scheduler is None:
             scheduler = TenantScheduler(decoder,
@@ -268,7 +268,7 @@ class TenantEngine(ContinuousBatchingEngine):
                          k_max=k_max, host_sync_s=host_sync_s,
                          prefix_cache=prefix_cache, ragged=True,
                          chunk_tokens=chunk_tokens, scheduler=scheduler,
-                         trace=trace, packed=packed,
+                         trace=trace,
                          host_tier=host_tier, tier_policy=tier_policy)
         self.preemption = bool(preemption)
         self._rid_tenant = {}        # rid -> (tenant, slo)

@@ -369,7 +369,7 @@ def test_counters_count_only_held_experts():
     dict(quant="a8w8"), dict(quant="w4a16"), dict(kv_quant="int8"),
     dict(kv_quant="int4"), dict(use_kernel=True), dict(temperature=0.8),
     dict(top_k=5), dict(top_p=0.9), dict(mesh=object()),
-    dict(packed=False), dict(dtype="bfloat16")])
+    dict(dtype="bfloat16")])
 def test_decoder_refuses_at_construction(option):
     with pytest.raises(NotImplementedError, match=next(iter(option))):
         _decoder(**option)
@@ -383,7 +383,7 @@ def plain_decoder():
 @pytest.mark.parametrize("option", [
     dict(prefix_cache=True), dict(prefix_cache=PrefixCache(8)),
     dict(prefix_cache=True, host_tier=True), dict(ragged=False),
-    dict(packed=False), dict(k_max=1)])
+    dict(k_max=1)])
 def test_engine_refuses_what_the_decoder_cannot_serve(plain_decoder, option):
     with pytest.raises(NotImplementedError, match="does not serve"):
         ContinuousBatchingEngine(plain_decoder, max_new_tokens=4, **option)

@@ -261,8 +261,6 @@ def test_a_program_name_is_made_once(tiny_model):
     name = PagedGPTDecoder.program_name("packed", 2, 256, 64, 128)
     assert name == "packed_multi_k2_t256_w128_p64"
     assert PagedGPTDecoder.program_name("packed", 2, 256, 64, 128) is name
-    assert PagedGPTDecoder.program_name("ragged", 4, 8, 2) == \
-        "ragged_multi_k4_w8_p2"
     assert PagedGPTDecoder.program_name("decode", 4, 1, 8) == \
         "decode_multi_k4"
     assert PagedGPTDecoder.program_name("tick", 1, 1, 8) == "decode_step"

@@ -168,8 +168,7 @@ def test_tick_records_price_and_measure(tiny_model):
     for ev in ticks:
         assert ev["track"] == "serve"
         # the default engine dispatches the PACKED token-stream layout
-        # (shape keyed by the total-token bucket); ragged=False /
-        # packed=False twins key by the dense (k, w) grid
+        # (shape keyed by the total-token bucket)
         assert ev["shape"][0] == "packed"
         assert ev["tokens_dispatched"] >= ev["tokens_padded"] >= 0
         assert ev["measured_s"] > 0
@@ -190,18 +189,36 @@ def test_tick_records_price_and_measure(tiny_model):
     assert s["meta"]["engine"] == "ContinuousBatchingEngine"
 
 
-def test_drift_ledger_excludes_prefill_polluted_blocks(tiny_model):
+@pytest.mark.parametrize("loop", ["multi", "per_tick"])
+def test_drift_ledger_excludes_prefill_polluted_blocks(tiny_model, loop):
     """Blocking-path discipline: a horizon whose measured window
     contained a blocking prefill stays OUT of the drift ledger (same
     exclusion as the token percentiles), so drift compares decode
-    ticks against the decode roofline only."""
+    ticks against the decode roofline only. On the per-tick loop such a
+    tick is recorded unpriced, and the ledger counts exactly the others
+    (three requests on two slots: the third's prefill blocks a tick
+    while a slot still decodes)."""
     rec = FlightRecorder()
-    outs, eng = _stream(tiny_model, [[3, 141, 59], [7, 8, 9, 10]],
-                        12, k_max=4, ragged=False, trace=rec)
+    if loop == "multi":
+        outs, eng = _stream(tiny_model, [[3, 141, 59], [7, 8, 9, 10]],
+                            12, k_max=4, ragged=False, trace=rec)
+    else:
+        outs, eng = _stream(tiny_model,
+                            [[3, 141, 59], [7, 8, 9, 10], [5, 6]], 6,
+                            k_max=1, trace=rec)
     ticks = [ev for ev in rec.events if ev["kind"] == "horizon"]
-    assert ticks and all(ev["shape"][0] == "decode" for ev in ticks)
+    kind = {"multi": "decode", "per_tick": "tick"}[loop]
+    assert ticks and all(ev["shape"][0] == kind for ev in ticks)
     ledger_n = sum(d["n"] for d in rec.drift_report())
     assert ledger_n < len(ticks) or eng.stats.prefill_syncs == 0
+    if loop == "per_tick":
+        assert eng.stats.prefill_syncs >= 2
+        polluted = [ev for ev in ticks if ev["predicted_s"] is None]
+        assert len(polluted) == eng.stats.prefill_syncs
+        assert all(ev["measured_s"] > 0 for ev in polluted)
+        # the decode program's first use is the first tick, itself
+        # polluted
+        assert ledger_n == len(ticks) - len(polluted)
 
 
 def test_serving_report_front_door(tiny_model):

@@ -15,13 +15,8 @@ catalog:
 * op counts match the architecture (a fusion-blocking duplicate
   forward, double-remat, or accidental f32 upcast shows up here as a
   count change) — GraphShapeAnalyzer + the models' own graph
-  contracts;
-* the analytical bytes-moved/FLOPs model per BASELINE config is stable
-  and committed (perf_evidence.json) so on-chip step times convert to
-  achieved-fraction numbers the moment a chip run is made.
+  contracts.
 """
-import json
-import os
 import re
 
 import jax.numpy as jnp
@@ -31,8 +26,6 @@ import paddle_tpu as paddle
 from paddle_tpu.analysis import (AnalysisContext, LoweredProgram,
                                  PassManager, lower_layer)
 from paddle_tpu.distributed import build_mesh
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from paddle_tpu.models.gpt import ATTENTION_TRANSPOSES as ATTN  # noqa: E402
 
@@ -153,60 +146,6 @@ def test_gpt_train_step_remat_policy_graph():
         f"train-step dot_general count changed: {n_dots} != {expected} — "
         "remat/backward structure shifted; re-derive and update if "
         "intentional")
-
-
-BASELINE_MODELS = {
-    "gpt_1p3b_bs4_seq1024": dict(kind="gpt", params=1.314e9, batch=4,
-                                 seq=1024, remat="dots"),
-    "resnet50_bs128": dict(kind="resnet", flops_fwd=8.2e9, batch=128),
-    "bert_base_bs32_seq512": dict(kind="bert", params=110e6, batch=32,
-                                  seq=512),
-}
-
-
-def _analytic_entry(name, spec):
-    """FLOPs + minimum HBM bytes per training step (the offline half of
-    the roofline; divide by measured step time on-chip)."""
-    if spec["kind"] == "gpt":
-        tokens = spec["batch"] * spec["seq"]
-        flops = 6 * spec["params"] * tokens
-        # optimizer-state traffic only: bf16 params + grads + bf16 adam
-        # m/v, read+write = 12 bytes/param. Activations are excluded by
-        # design — remat turns them into recompute, not HBM residency
-        param_bytes = spec["params"] * 2 * (1 + 1 + 2 + 2)
-        return {"flops_per_step": flops, "min_param_bytes": param_bytes}
-    if spec["kind"] == "resnet":
-        flops = 3 * spec["flops_fwd"] * spec["batch"]
-        return {"flops_per_step": flops,
-                "min_param_bytes": 25.6e6 * 2 * 6}
-    tokens = spec["batch"] * spec["seq"]
-    return {"flops_per_step": 6 * spec["params"] * tokens,
-            "min_param_bytes": spec["params"] * 2 * 6}
-
-
-def test_bytes_moved_model_matches_committed_artifact():
-    """perf_evidence.json is the committed analytical model; this test
-    regenerates it and fails on drift, so the artifact the judge (and
-    the on-chip campaign) reads is provably current."""
-    got = {name: _analytic_entry(name, spec)
-           for name, spec in BASELINE_MODELS.items()}
-    path = os.path.join(REPO, "perf_evidence.json")
-    with open(path) as f:
-        committed = json.load(f)
-    assert committed["model"] == got, (
-        "analytical perf model drifted from perf_evidence.json — "
-        "regenerate it (python tests/test_hlo_regression.py) and commit")
-
-
-if __name__ == "__main__":
-    out = {"model": {name: _analytic_entry(name, spec)
-                     for name, spec in BASELINE_MODELS.items()},
-           "note": "analytical FLOPs/bytes per BASELINE config; divide "
-                   "by on-chip step time for achieved fractions "
-                   "(tests/test_hlo_regression.py regenerates)"}
-    with open(os.path.join(REPO, "perf_evidence.json"), "w") as f:
-        json.dump(out, f, indent=1, sort_keys=True)
-    print("wrote perf_evidence.json")
 
 
 def test_gpt_gradient_merge_graph_scans_microbatches():

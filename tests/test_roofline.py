@@ -25,11 +25,6 @@ class TestChipSpec:
         assert chip_spec().name == "v5e"
         assert chip_spec("cpu").name == "v5e"
 
-    def test_bench_delegates_to_the_same_table(self):
-        import bench
-        assert bench.chip_peak_flops() == chip_spec().peak_flops
-        assert bench.chip_hbm_bw() == chip_spec().hbm_bw
-
 
 class TestJaxprFlops:
     def test_matmul_exact(self):

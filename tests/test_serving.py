@@ -337,25 +337,6 @@ def test_predictor_clear_error_without_program(tmp_path):
         create_predictor(Config(prog_file=path + ".pdmodel"))
 
 
-def test_decode_roofline_math():
-    """bench.decode_roofline_tok_s: explicit bytes-per-step model."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    from paddle_tpu.models import gpt_tiny
-    cfg = gpt_tiny()
-    bw = bench.chip_hbm_bw()
-    batch, ctx = 4, 100
-    got = bench.decode_roofline_tok_s(cfg, batch, ctx)
-    w = cfg.num_params() * 2
-    kv = batch * cfg.num_layers * 2 * ctx * cfg.hidden_size * 2
-    assert abs(got - bw * batch / (w + kv)) < 1e-6
-    # int8 weights halve the weight traffic -> higher ceiling
-    assert bench.decode_roofline_tok_s(cfg, batch, ctx, quant="a8w8") > got
-
-
 def test_inference_config_toggles_map_to_real_choices():
     """switch_ir_optim(False) -> eager op-by-op execution (no XLA
     program); enable_memory_optim -> input-buffer donation. Same
@@ -765,6 +746,71 @@ def test_explicit_ragged_honored_at_k_max_one(tiny_model):
     assert outs == tick
 
 
+@pytest.mark.parametrize("eng_kw, loop", [
+    (dict(k_max=1), "per_tick"),
+    (dict(k_max=4, ragged=False), "multi"),
+    (dict(host_sync_s=1e-12), "ragged"),       # K PRICED to 1
+    (dict(k_max=2), "ragged"),
+    (dict(), "ragged")])
+def test_run_takes_the_loop_the_two_arguments_name(tiny_model, eng_kw,
+                                                  loop):
+    """`run()` has three loops and two things choose: an explicit
+    `k_max=1` runs `_run_per_tick` (blocking prefill, `decode_step`
+    ticks), `ragged=False` runs `_run_multi` (blocking prefill,
+    `decode_multi` horizons), everything else `_run_ragged`, a horizon
+    PRICED to one tick too (big models legitimately price K=1: chunked
+    no-stall admission stays). Read off the horizons' records."""
+    prompts = [list(range(1, 41)), [5, 6, 7]]
+    outs, eng = _stream(tiny_model, prompts, 5, chunk_tokens=8, **eng_kw)
+    hz = eng.serve_schedule()
+    assert hz and {ev["kind"] for ev in hz} <= {"horizon", "prefill_sync"}
+    programs = {ev["program"] for ev in hz if ev["kind"] == "horizon"}
+    if loop != "ragged":
+        assert not eng.ragged and eng.scheduler is None
+        assert programs == {"decode_step"} if loop == "per_tick" else \
+            all(p.startswith("decode_multi_k") for p in programs)
+        assert eng.stats.prefill_syncs >= 1
+        assert any(ev["kind"] == "prefill_sync" for ev in hz)
+    else:
+        assert eng.ragged and eng.scheduler is not None
+        assert all(p.startswith("packed_multi_k") for p in programs)
+        assert eng.stats.prefill_syncs == 0      # no blocking prefill
+        assert eng.stats.prefill_chunks >= 5
+        assert all(ev["kind"] == "horizon" for ev in hz)
+    if "host_sync_s" in eng_kw:
+        assert eng.k_max == 1
+        assert all(ev["k"] == 1 for ev in hz)
+    # same streams whichever loop
+    tick, _ = _stream(tiny_model, prompts, 5, k_max=1)
+    assert outs == tick
+
+
+@pytest.mark.parametrize("build", ["engine", "tenant_engine", "gpt_decoder",
+                                   "mla_decoder"])
+def test_the_layout_switch_is_gone(tiny_model, build):
+    """`packed=` chose between two layouts of the mixed horizon; the
+    packed token stream is the one left, and neither engine nor either
+    decoder takes the flag any more (a `TypeError`, not a silent
+    acceptance)."""
+    from paddle_tpu.serving import TenantEngine
+    from paddle_tpu.serving.mla_decoder import PagedMLADecoder
+
+    def decoder(**kw):
+        return PagedGPTDecoder(tiny_model, num_pages=16, page_size=16,
+                               max_batch=2, **kw)
+
+    make = {
+        "engine": lambda **kw: ContinuousBatchingEngine(decoder(), **kw),
+        "tenant_engine": lambda **kw: TenantEngine(decoder(), **kw),
+        "gpt_decoder": decoder,
+        # the flags fail before the model is looked at
+        "mla_decoder": lambda **kw: PagedMLADecoder(tiny_model, **kw),
+    }[build]
+    for value in (False, True, None):
+        with pytest.raises(TypeError, match="packed"):
+            make(packed=value)
+
+
 def test_scheduler_chunk_budget_never_exceeded(tiny_model):
     """Review regression: a non-power-of-two chunk_tokens must bound
     the dispatched width from BELOW (normalized down to pow2) — plan()
@@ -790,7 +836,7 @@ def test_no_live_references_to_deleted_prefill_buckets():
     # built by concatenation so this test file doesn't match itself
     dead = ["_prefill" + "_fn", "_prefill" + "s"]
     offenders = []
-    files = [root / "bench.py"]
+    files = []
     for sub in ("paddle_tpu", "examples", "tests", "docs"):
         files.extend((root / sub).rglob("*"))
     for p in files:
@@ -850,10 +896,10 @@ def test_packed_streams_byte_identical_under_churn(tiny_model, seed):
     """THE packed acceptance bar: under randomized admission churn
     (sampled config + EOS + chunked prompts + more requests than
     slots), the PACKED token-stream engine's per-request streams are
-    byte-identical to the dense-window A/B twin's (packed=False) AND
-    to the per-tick engine's — with the prefix cache on and off, and
-    (seed-rotated) over an int8 KV pool. The packed layout changes
-    WHAT is dispatched, never what any position computes."""
+    byte-identical to the per-tick engine's — with the prefix cache
+    on and off, and (seed-rotated) over an int8 KV pool. The packed
+    layout changes WHAT is dispatched, never what any position
+    computes."""
     rng = np.random.RandomState(700 + seed)
     V = tiny_model.cfg.vocab_size
     prompts = [list(rng.randint(0, V, rng.randint(1, 40)).astype(int))
@@ -866,79 +912,78 @@ def test_packed_streams_byte_identical_under_churn(tiny_model, seed):
     base, _ = _stream_kw(tiny_model, prompts, max_new, eos, dec_kw,
                          k_max=1)
     for cache in (None, True):
-        dense, ed = _stream_kw(tiny_model, prompts, max_new, eos,
-                               dec_kw, k_max=4, chunk_tokens=8,
-                               packed=False, prefix_cache=cache)
         packed, ep = _stream_kw(tiny_model, prompts, max_new, eos,
                                 dec_kw, k_max=4, chunk_tokens=8,
-                                packed=True, prefix_cache=cache)
-        assert dense == base, (seed, cache, "dense twin")
+                                prefix_cache=cache)
         assert packed == base, (seed, cache, "packed")
-        assert not ed.packed and ep.packed
         assert ep.stats.prefill_syncs == 0
         # the layout claim, weak form at this 2-slot toy scale (the
-        # pow2 bucket can tie the tiny dense grid exactly; the strict
-        # win needs decode rows outnumbering chunk rows — pinned in
-        # test_packed_pad_ledger_counts_tokens_not_windows)
-        assert ep.stats.tokens_dispatched <= ed.stats.tokens_dispatched
-        assert ep.stats.pad_fraction <= ed.stats.pad_fraction
+        # pow2 bucket can tie the [S, w] window grid exactly; the
+        # strict win needs decode rows outnumbering chunk rows — pinned
+        # in test_packed_pad_ledger_counts_tokens_not_windows)
+        hz = ep.serve_schedule()
+        assert ep.stats.tokens_dispatched == \
+            sum(ev["k"] * ev["t_tokens"] for ev in hz) <= \
+            sum(ev["k"] * ev["slots"] * ev["w"] for ev in hz)
 
 
 def test_packed_pad_ledger_counts_tokens_not_windows(tiny_model):
-    """ServeStats pad ledger, pinned on a deterministic mixed
-    workload: the dense twin dispatches k*S*w positions per mixed
-    horizon while the packed engine dispatches its pow2 total-token
-    bucket; both reconcile exactly against the device's real-token
-    counts (dispatched - padded == the same real work on both)."""
+    """ServeStats pad ledger, pinned on a deterministic mixed workload
+    to counts made by hand: a horizon dispatches k x its pow2
+    total-token bucket, not the k x S x w window grid, and dispatched
+    minus padded is exactly the positions the requests consumed.
+
+    Four slots, chunks of 8, K=4, 8 tokens an answer; prompts of 40, 3,
+    2 and 3 tokens sent together. Horizon 1 (k=4): tick 0 streams
+    8 + 3 + 2 + 3 = 16 tokens (bucket 16), ticks 1-3 the long prompt's 8
+    beside three decode rows (11 of 16): 49 real of 64. Horizon 2 (k=1):
+    the prompt's last 8 and three decode rows, 11 of 16. Then four
+    decode rows in a bucket of 4: k=2 (8 of 8) and k=1 (4 of 4), after
+    which the three short requests have their 8 tokens; the last
+    horizon (k=4) still holds their frozen rows (retirement is one
+    horizon late) and emits the long request's last 4: 4 of 16."""
     long_p = list(range(1, 41))
     shorts = [[3, 141, 59], [7, 8], [9, 10, 11]]
-    outs_d, ed = _stream_kw(tiny_model, [long_p] + shorts, 8, k_max=4,
-                            chunk_tokens=8, packed=False, max_batch=4)
-    outs_p, ep = _stream_kw(tiny_model, [long_p] + shorts, 8, k_max=4,
-                            chunk_tokens=8, packed=True, max_batch=4)
-    assert outs_d == outs_p
-    for eng in (ed, ep):
-        s = eng.stats
-        assert s.tokens_dispatched > 0
-        assert 0 <= s.tokens_padded < s.tokens_dispatched
-        assert s.summary()["pad_fraction"] == round(s.pad_fraction, 4)
-    # identical schedules -> identical REAL work; the layouts differ
-    # only in padding
-    real_d = ed.stats.tokens_dispatched - ed.stats.tokens_padded
-    real_p = ep.stats.tokens_dispatched - ep.stats.tokens_padded
-    assert real_d == real_p
-    assert ep.stats.pad_fraction < ed.stats.pad_fraction
-    # packed dispatches bucket by total tokens: every horizon event
-    # carries its pow2 t_tokens
-    hz = [ev for ev in ep.serve_schedule() if ev["kind"] == "horizon"]
-    assert hz and all(ev["t_tokens"] & (ev["t_tokens"] - 1) == 0
-                      for ev in hz)
-    assert all(ev["t_tokens"] >= ep.d.max_batch for ev in hz)
+    outs, eng = _stream_kw(tiny_model, [long_p] + shorts, 8, k_max=4,
+                           chunk_tokens=8, max_batch=4)
+    assert outs == [_golden_greedy(tiny_model, p, 8)
+                    for p in [long_p] + shorts]
+    hz = eng.serve_schedule()
+    assert [(ev["k"], ev["w"], ev["t_tokens"], ev["tokens_dispatched"],
+             ev["tokens_padded"]) for ev in hz] == [
+        (4, 8, 16, 64, 15), (1, 8, 16, 16, 5), (2, 1, 4, 8, 0),
+        (1, 1, 4, 4, 0), (4, 1, 4, 16, 12)]
+    s = eng.stats
+    assert (s.tokens_dispatched, s.tokens_padded) == (108, 32)
+    assert s.summary()["pad_fraction"] == round(32 / 108, 4)
+    # real work: every prompt token and every generated token but each
+    # request's last (emitted, never consumed)
+    assert s.tokens_dispatched - s.tokens_padded == \
+        sum(map(len, [long_p] + shorts)) + 4 * 8 - 4
+    # the [S, w] window grid of the same schedule: 128 + 32 + 8 + 4 + 16
+    assert sum(ev["k"] * ev["slots"] * ev["w"] for ev in hz) == 188
+    # every horizon carries its pow2 bucket, at least a token a slot
+    assert all(ev["t_tokens"] & (ev["t_tokens"] - 1) == 0
+               and ev["t_tokens"] >= eng.d.max_batch for ev in hz)
 
 
 def test_packed_prefill_batches_mixed_lengths_in_one_bucket(tiny_model):
     """PACKED chunked prefill: mixed suffix lengths dispatch as ONE
     flat stream per total-token bucket (one jit entry) instead of one
-    program per (suffix-width, batch) pair — first tokens byte-equal
-    to the dense window path's."""
-    dec_p = PagedGPTDecoder(tiny_model, num_pages=32, page_size=16,
-                            max_batch=4)
-    dec_d = PagedGPTDecoder(tiny_model, num_pages=32, page_size=16,
-                            max_batch=4, packed=False)
+    program per (suffix-width, batch) pair — each first token the dense
+    model's greedy choice."""
+    dec = PagedGPTDecoder(tiny_model, num_pages=32, page_size=16,
+                          max_batch=4)
     reqs = [(list(range(1, 6)), 0, [0]),          # 5 tokens
             (list(range(1, 18)), 0, [1, 2]),      # 17 tokens
             (list(range(1, 3)), 0, [3])]          # 2 tokens
-    first_p = dec_p.prefill_suffix_batch([tuple(r) for r in reqs],
-                                         kids=[0, 1, 2])
-    first_d = dec_d.prefill_suffix_batch([tuple(r) for r in reqs],
-                                         kids=[0, 1, 2])
-    assert first_p == first_d
+    first = dec.prefill_suffix_batch(reqs, kids=[0, 1, 2])
+    assert first == [_golden_greedy(tiny_model, ids, 1)[0]
+                     for ids, _, _ in reqs]
     # 5+17+2 = 24 tokens -> ONE t=32 packed program (rows laid out in
-    # windows of 32, the longest suffix's bucket); the dense twin
-    # buckets per (W, nb): W=8 x1, W=32 x1, W=4 x1 = three programs
-    assert list(dec_p._packed_prefills) == [(32, 32)]
-    assert dec_p._suffix_prefill is None
-    assert dec_d._suffix_prefill is not None
+    # windows of 32, the longest suffix's bucket); a (W, nb) window
+    # grid would take W=8, W=32 and W=4: three programs
+    assert list(dec._packed_prefills) == [(32, 32)]
 
 
 def test_scheduler_plans_pow2_token_buckets(tiny_model):
@@ -981,11 +1026,11 @@ def _pool_leaves(dec):
 
 @pytest.mark.parametrize("pool", ["bf16", "int8", "int4"])
 def test_pools_byte_identical_across_engines(tiny_model, pool):
-    """After the same requests the per-tick engine, the dense ragged
-    engine and the packed engine leave THE SAME BYTES in the same pages
-    — payload and scales, every layer — and emit the same streams: one
-    write (`_kv_set` at the layer's index of the whole pool) behind all
-    three blocks of the decoder."""
+    """After the same requests the per-tick engine and the ragged
+    packed engine leave THE SAME BYTES in the same pages — payload and
+    scales, every layer — and emit the same streams: one write
+    (`_kv_set` at the layer's index of the whole pool) behind both
+    blocks of the decoder they run."""
     dec_kw = _pool_kw(pool)
     rng = np.random.RandomState(900)
     V = tiny_model.cfg.vocab_size
@@ -994,19 +1039,76 @@ def test_pools_byte_identical_across_engines(tiny_model, pool):
     prompts = [list(rng.randint(0, V, n).astype(int)) for n in (23, 5)]
     runs = {}
     for name, eng_kw in (("tick", dict(k_max=1)),
-                         ("dense", dict(k_max=4, chunk_tokens=8,
-                                        packed=False)),
-                         ("packed", dict(k_max=4, chunk_tokens=8,
-                                         packed=True))):
+                         ("packed", dict(k_max=4, chunk_tokens=8))):
         streams, eng = _stream_kw(tiny_model, prompts, 9, None, dec_kw,
                                   **eng_kw)
         runs[name] = (streams, _pool_leaves(eng.d))
     assert len(runs["tick"][1]) == (2 if pool == "bf16" else 4)
     assert any(leaf.any() for leaf in runs["tick"][1])
-    for name in ("dense", "packed"):
-        assert runs[name][0] == runs["tick"][0], (pool, name)
-        for a, b in zip(runs[name][1], runs["tick"][1]):
-            assert a.shape == b.shape and np.array_equal(a, b), (pool, name)
+    assert runs["packed"][0] == runs["tick"][0], pool
+    for a, b in zip(runs["packed"][1], runs["tick"][1]):
+        assert a.shape == b.shape and np.array_equal(a, b), pool
+
+
+@pytest.mark.parametrize("option", [
+    "prefix_cache", "lora", "prefix_cache+lora", "prefix_cache+int8",
+    "prefix_cache+int4", "lora+int8", "lora+int4"])
+def test_per_tick_reference_and_ragged_engine_agree_under(tiny_model,
+                                                          option):
+    """The per-tick loop (`k_max=1`: blocking packed prefill, one tick a
+    sync) is the plain reference of the ragged loop: with the SAME
+    option on both — a prefix cache over prompts that share a block
+    (one of them a full hit, which copies on write), a LoRA bank with a
+    different adapter a request, a quantised pool — the sampled streams
+    are byte-identical under churn (more requests than slots, EOS), and
+    both leave every page owned exactly once."""
+    from paddle_tpu.serving import make_lora_bank
+    parts = option.split("+")
+    rng = np.random.RandomState(1200)
+    V = tiny_model.cfg.vocab_size
+    shared = list(rng.randint(0, V, 16).astype(int))    # one full block
+    prompts = [shared + list(rng.randint(0, V, n).astype(int))
+               for n in (7, 21, 2)] + [list(shared)]
+    eos = int(rng.randint(0, V))
+    dec_kw = dict(temperature=0.8, top_k=40, seed=11)
+    for pool in ("int8", "int4"):
+        if pool in parts:
+            dec_kw["kv_quant"] = pool
+    adapters = [1, 2, 0, 2] if "lora" in parts else [None] * 4
+
+    def run(**eng_kw):
+        dec = PagedGPTDecoder(tiny_model, num_pages=48, page_size=16,
+                              max_batch=2, **dec_kw)
+        if "lora" in parts:
+            dec.attach_adapters(make_lora_bank(tiny_model.cfg, 2, rank=4,
+                                               seed=3))
+        eng = ContinuousBatchingEngine(
+            dec, eos_token_id=eos, max_new_tokens=7,
+            prefix_cache=True if "prefix_cache" in parts else None,
+            **eng_kw)
+        rids = [eng.submit(np.asarray(p, np.int32), adapter=a)
+                for p, a in zip(prompts, adapters)]
+        out = eng.run()
+        assert eng.audit_pages() == []
+        return [out[r] for r in rids], eng
+
+    tick, et = run(k_max=1)
+    ragged, er = run(k_max=4, chunk_tokens=8)
+    assert ragged == tick, option
+    assert not et.ragged and er.ragged
+    assert et.stats.prefill_syncs >= 2 and er.stats.prefill_syncs == 0
+    if "prefix_cache" in parts:
+        for eng in (et, er):
+            assert eng.stats.prefix_hits >= 1, option
+        # the full hit re-consumes its last token on a private copy
+        # unless an adapter's salt keeps its pages apart
+        if "lora" not in parts:
+            assert et.stats.prefix_cow >= 1 and er.stats.prefix_cow >= 1
+    if "lora" in parts:
+        # adapters 1 and 2 really changed what was served
+        base, _ = _stream(tiny_model, prompts[:1], 7, eos,
+                          dict(dec_kw), k_max=4, chunk_tokens=8)
+        assert len(tick[0]) >= 1 and tick[0] != base[0]
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int8", "int4"])
