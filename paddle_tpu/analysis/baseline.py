@@ -23,11 +23,10 @@ _TUNING_CACHE = {}   # name -> AutotuneReport (autotune.autotune_layer)
 
 # the ragged paged attention's by-design reorders (one body behind
 # decode ticks, chunked prefill and the mixed horizon — see
-# ops/ragged_paged_attention.py): the page-gather layout move
-# [n,MP,ps,H,D] -> per-page [MP][n,H,ps,D] and the q/out head-major
-# flip. Shared by every serving PROGRAM config.
-RAGGED_ATTENTION_TRANSPOSES = (r"dims = \[1, 0, 3, 2, 4\]",
-                               r"dims = \[0, 2, 1, 3\]")
+# ops/ragged_paged_attention.py): the head-major flip of q, of a
+# block's values and of the output. Shared by every serving PROGRAM
+# config.
+RAGGED_ATTENTION_TRANSPOSES = (r"dims = \[0, 2, 1, 3\]",)
 
 
 def _fresh():
@@ -324,7 +323,10 @@ def _gpt_decode_kv8():
     cfg = gpt_tiny(max_seq_len=64, dtype="float32", remat=False)
     model = GPT(cfg)
     model.eval()
-    dec = PagedGPTDecoder(model, num_pages=16, page_size=16, max_batch=2,
+    # a pool larger than ONE step's working set (2 rows x a block of 8
+    # pages), as every real pool is: the step's own convert must stay
+    # under the DTYPE-KV-DEQUANT-HBM threshold below
+    dec = PagedGPTDecoder(model, num_pages=64, page_size=16, max_batch=2,
                           kv_quant="int8")
     eng = ContinuousBatchingEngine(
         dec, max_new_tokens=4, k_max=2,
@@ -336,10 +338,10 @@ def _gpt_decode_kv8():
     program = dec.analysis_program(k=4)
     ctx = AnalysisContext(
         name="gpt_decode_kv8",
-        # the shared ragged-attention reorders, plus the int8 pool's
-        # per-page scale-plane gather layout move [n,MP,ps]->[MP,n,ps]
+        # the shared ragged-attention reorders (the scale planes ride
+        # the block copy as they are: no layout move of their own)
         allowed_activation_transposes=gpt_mod.ATTENTION_TRANSPOSES
-        + RAGGED_ATTENTION_TRANSPOSES + (r"dims = \[1, 0, 2\]",),
+        + RAGGED_ATTENTION_TRANSPOSES,
         expect_collectives=False,
         extra={"serving_decode": True,
                "kv_quant": "int8",
@@ -361,7 +363,7 @@ def _gpt_decode_kv4():
     layout: SERVE-HOST-SYNC-DECODE (zero host transfers, four donated
     cache leaves), DTYPE-KV-SCALE-WIDTH (group-scale planes exactly
     f32), DTYPE-KV-DEQUANT-HBM (the nibble unpack's int8->f32 convert
-    stays per-page inside the shared attention update — a full-pool
+    stays per-block inside the attention's walk — a full-pool
     dequant materialized in HBM is the defect), and MEM-PAGE-REFCOUNT
     over a page ledger committed from a real shared-prefix int4
     workload including a full-hit copy-on-write (CoW moves nibble
@@ -375,7 +377,8 @@ def _gpt_decode_kv4():
     cfg = gpt_tiny(max_seq_len=64, dtype="float32", remat=False)
     model = GPT(cfg)
     model.eval()
-    dec = PagedGPTDecoder(model, num_pages=16, page_size=16, max_batch=2,
+    # a pool larger than one step's working set: see gpt_decode_kv8
+    dec = PagedGPTDecoder(model, num_pages=64, page_size=16, max_batch=2,
                           kv_quant="int4")
     eng = ContinuousBatchingEngine(
         dec, max_new_tokens=4, k_max=2,
@@ -387,11 +390,10 @@ def _gpt_decode_kv4():
     program = dec.analysis_program(k=4)
     ctx = AnalysisContext(
         name="gpt_decode_kv4",
-        # the shared ragged-attention reorders, plus the int4 pool's
-        # page gathers: packed nibbles and group scales are rank-4
-        # [n,MP,ps,X] -> [MP,n,ps,X] layout moves (X = PB or G)
+        # the shared ragged-attention reorders (packed nibbles and
+        # group scales ride the block copy as they are)
         allowed_activation_transposes=gpt_mod.ATTENTION_TRANSPOSES
-        + RAGGED_ATTENTION_TRANSPOSES + (r"dims = \[1, 0, 2, 3\]",),
+        + RAGGED_ATTENTION_TRANSPOSES,
         expect_collectives=False,
         extra={"serving_decode": True,
                "kv_quant": "int4",
@@ -399,7 +401,7 @@ def _gpt_decode_kv4():
                # the packed payload holds 2*PB >= H*D nibbles per
                # token, so a convert of this many unpacked elements to
                # a wide float IS the dequantized pool landing in HBM
-               # (legit per-page converts stay n*ps*2*PB — far under)
+               # (a step's own convert is n x a block of 8 pages)
                "kv_pool_block_elems": (dec.num_pages * dec.page_size *
                                        cfg.num_heads * cfg.head_dim),
                "page_ledger": eng.page_ledger()})

@@ -26,22 +26,28 @@ Shapes:
                     (P, page_size, PB), per-GROUP scales f32
                     (P, page_size, G)) — the kv_quant="int4" layout
                     (`serving.decoder._quantize_kv_int4`). Dequant
-                    happens per page next to the shared per-page
-                    update, so the dequantized pool never materializes
-                    in HBM
+                    happens per step next to the shared update (a
+                    block of pages in the reference, a page in the
+                    kernel), so the dequantized pool never
+                    materializes in HBM
   page_table      : (n, max_pages) int32 page ids per row
   start           : (n,)           already-cached length per row
 
-Math: an online-softmax (flash) accumulation over the row's pages, in
-f32. The jnp reference (`use_kernel=False`, the CPU/production-default
-path) runs EXACTLY the same per-page update as the Pallas kernel via a
-`lax.scan` over pages — same operation order, same masking, same
-epsilon — so interpret-mode Pallas is bit-identical to the reference
-(test-pinned, the w4_matmul discipline). The kernel keeps the page pool
-in HBM and streams ONE page of K/V per grid step through VMEM via the
-scalar-prefetched page table (the `paged_attention` scalar-prefetch
-pattern), with online-softmax state in VMEM scratch across the page
-steps.
+Math: an online-softmax (flash) accumulation over the row's keys, in
+f32, by ONE update function (`_page_update`) that the jnp reference, the
+Pallas kernel and the materialised latent walk share. The jnp reference
+(`use_kernel=False`, the path the chip runs) walks BLOCKS of
+`KEY_BLOCK_PAGES` pages, as many as the deepest row of the batch holds:
+a `fori_loop` whose trip count is read from the queries' positions, each
+step copying its own block of every row out of the pool (`_ragged_ref`).
+The Pallas kernel keeps the page pool in HBM and streams ONE page of K/V
+per grid step through VMEM via the scalar-prefetched page table (the
+`paged_attention` scalar-prefetch pattern), with online-softmax state in
+VMEM scratch across the page steps: the same keys in the same order,
+grouped by page and not by block, so it agrees with the reference to
+float32 rounding (the tests hold it to the oracle's tolerance) and not
+to the bit. The TPU lowering refuses its per-page grid (ROADMAP D3); it
+runs in interpret mode on the CPU alone.
 """
 import functools
 
@@ -63,23 +69,25 @@ _MASK = -1e30
 
 def _page_update(m, s, acc, logits, v, kpos, qpos, k_scale=None,
                  v_scale=None):
-    """ONE page's online-softmax update — the shared math of the jnp
-    reference and the Pallas kernel (they call this same function, so
-    the two paths cannot drift; bit-identity rides on it).
+    """ONE step's online-softmax update over `ps` keys — the shared
+    math of the jnp reference (a block of `KEY_BLOCK_PAGES` pages a
+    step), the Pallas kernel (a page a step) and the materialised
+    latent walk: they call this same function, so the paths cannot
+    drift in anything but the grouping of the keys.
 
     m/s/acc: running max [..., W, 1], denominator [..., W, 1], value
-    accumulator [..., W, D]. logits [..., W, ps] this page's scores
-    (q*scale @ k^T), v [..., ps, D] this page's values, kpos [ps] the
-    page's absolute key positions, qpos [..., W] the queries' absolute
+    accumulator [..., W, D]. logits [..., W, ps] this step's scores
+    (q*scale @ k^T), v [..., ps, D] its values, kpos [ps] its keys'
+    absolute positions, qpos [..., W] the queries' absolute
     positions. Causal: a query attends to kpos <= qpos only.
 
-    k_scale/v_scale (optional): this page's per-token dequant scales,
+    k_scale/v_scale (optional): the keys' per-token dequant scales,
     broadcastable to [..., ps] — the int8 KV pool's write-time scales.
     Applied HERE, so the reference and the kernel share one dequant
     exactly like they share the softmax math: logits computed from raw
     int8 keys pick up the key scale (q·(k_q·s) == (q·k_q)·s), values
     dequantize before the accumulator dot, and the dequantized pool
-    never exists outside this page-sized working set."""
+    never exists outside this step's working set."""
     if k_scale is not None:
         logits = logits * k_scale[..., None, :]
     if v_scale is not None:
@@ -98,10 +106,10 @@ def _page_update(m, s, acc, logits, v, kpos, qpos, k_scale=None,
 
 
 def _dequant_page_int4(packed, gscale, heads):
-    """ONE page's int4 dequant — shared by the jnp reference and the
-    Pallas kernel exactly like `_page_update` (both call this same
-    function immediately before it, so the two paths cannot drift and
-    bit-identity extends to the nibble-packed pool).
+    """int4 dequant of one step's keys (a page, or a block of pages) —
+    shared by the jnp reference and the Pallas kernel exactly like
+    `_page_update` (both call this same function immediately before
+    it, so the two paths cannot drift on the nibble-packed pool).
 
     packed [..., ps, PB] uint8 nibble pairs (low nibble = element 2i —
     `serving.decoder._pack_int4`'s layout), gscale [..., ps, G] f32
@@ -114,8 +122,7 @@ def _dequant_page_int4(packed, gscale, heads):
     (groups tile the flattened H*D axis), so K must dequantize before
     the logits dot and V before the accumulator dot. Everything here is
     elementwise and exact in f32 (integer unpack, one cast, one
-    multiply), so ref == kernel bit-identity needs only this function
-    to be shared."""
+    multiply): both paths see the same dequantized values."""
     H, D = int(heads[0]), int(heads[1])
     PB = packed.shape[-1]
     G = gscale.shape[-1]
@@ -135,113 +142,117 @@ def _dequant_page_int4(packed, gscale, heads):
     return flat.reshape(packed.shape[:-1] + (H, D))
 
 
-# page counts up to this unroll the reference's page loop into straight
-# line code (XLA fuses across pages; a lax.scan pays while-loop overhead
-# per page — measurable on CPU where the decode tick is host-bound).
-# Unrolled and scanned variants run the IDENTICAL op sequence, so both
-# stay bit-identical to the kernel's grid walk.
-_UNROLL_PAGES = 32
+# pages of one block of keys (128 keys at pages of 16): the step of the
+# reference's walk (`_ragged_ref`) and of the materialised latent walk
+# (`mla_paged_attention_packed`). A module constant, not an option: a
+# step's working set is a block of every row, and 128 keys fill the
+# contraction of probabilities x values that one page of 16 left idle.
+KEY_BLOCK_PAGES = 8
 
 
 def _ragged_ref(q, k_pages, v_pages, page_table, qpos, scale,
                 k_scale=None, v_scale=None, int4=False, layer=None):
-    """jnp reference: the kernel's page loop as an unrolled loop (small
-    tables) or a lax.scan — the same per-page update in the same order
-    either way (see _page_update). `qpos` [n, W] is every query's
-    absolute position: `start + arange(W)` for the dense entry point,
-    the stream's own `pos` laid out by row for the packed one — the ONE
-    program both layouts run. With an int8 pool, `k_scale`/
-    `v_scale` [P, ps] carry the per-token write-time scales; the gather
-    stays int8 and only one page dequantizes per step. With an int4
-    pool (`int4=True`) the payload is nibble-packed [P, ps, PB] and
-    `k_scale`/`v_scale` [P, ps, G] carry per-GROUP scales; each page
-    dequantizes through the shared `_dequant_page_int4` before its
-    update — the gather stays packed, one page unpacks per step.
+    """jnp reference: an online-softmax walk over BLOCKS of
+    `KEY_BLOCK_PAGES` pages, as many as the deepest row of the batch
+    holds. `qpos` [n, W] is every query's absolute position: `start +
+    arange(W)` for the dense entry point, the stream's own `pos` laid
+    out by row for the packed one — the ONE program both layouts run.
+
+    The trip count is read from `qpos` (a traced scalar): block j holds
+    keys `kb*j .. kb*j + kb - 1` (kb = KEY_BLOCK_PAGES * page_size), so
+    the walk ends after block `max(qpos) // kb`. A garbage window slot
+    holds another row's real position or the padded tail's, so the
+    maximum is the deepest live row's. Step j copies its OWN block of
+    every row out of the pool (`pool[layer, table[:, 8j:8j+8]]`: one
+    index, so a layer of a whole pool is never sliced out) and makes
+    ONE `_page_update` over its kb keys; blocks past the bound are
+    neither copied nor read, so the bytes a tick moves follow the
+    context and not the table's width.
+
+    Every step has one shape: a table of any width is padded to a
+    multiple of the block with columns no query can see (their keys
+    are given a position past every query's). A block wholly past a
+    query's position is an exact no-op for it (`m` unmoved, `p` = 0.0,
+    `corr` = 1.0), so a token's bits depend on its row's keys alone —
+    not on the table's width, the batch's other rows or the window.
+
+    With an int8 pool, `k_scale`/`v_scale` [P, ps] carry the per-token
+    write-time scales; the copy stays int8 and only the step's block
+    dequantizes. With an int4 pool (`int4=True`) the payload is
+    nibble-packed [P, ps, PB] and `k_scale`/`v_scale` [P, ps, G] carry
+    per-GROUP scales; the block dequantizes through the shared
+    `_dequant_page_int4` before its update — the copy stays packed.
     `layer` (a traced scalar; None = the pools are one layer's) names
-    the layer of a whole [L, P, ...] pool to read: it is one more
-    index of the SAME gather, so the layer is never sliced out."""
+    the layer of a whole [L, P, ...] pool to read."""
     n, W, H, D = q.shape
     ps = k_pages.shape[1 if layer is None else 2]
+    kb = KEY_BLOCK_PAGES * ps
     MP = page_table.shape[1]
-    safe = jnp.maximum(page_table, 0)
+    blocks = -(-MP // KEY_BLOCK_PAGES)
+    safe = jnp.pad(jnp.maximum(page_table, 0),
+                   ((0, 0), (0, blocks * KEY_BLOCK_PAGES - MP)))
     quantized = k_scale is not None and not int4
-
-    def rows_of(pool):
-        return pool[safe] if layer is None else pool[layer, safe]
-
-    # named for the trace: ONE copy of each row's pages, whatever the
-    # number of queries in the row's window
-    with jax.named_scope("paged_gather"):
-        if int4:
-            # packed payload [n, MP, ps, PB] -> per-page [MP][n, ps, PB];
-            # group scales [n, MP, ps, G] -> per-page [MP][n, ps, G]
-            kg = jnp.moveaxis(rows_of(k_pages), 1, 0)
-            vg = jnp.moveaxis(rows_of(v_pages), 1, 0)
-            ksg = jnp.moveaxis(rows_of(k_scale), 1, 0)
-            vsg = jnp.moveaxis(rows_of(v_scale), 1, 0)
-        else:
-            # [n, MP, ps, H, D] -> per-page [MP][n, H, ps, D]
-            kg = jnp.moveaxis(rows_of(k_pages), (1, 3), (0, 2))
-            vg = jnp.moveaxis(rows_of(v_pages), (1, 3), (0, 2))
-            if quantized:
-                # [n, MP, ps] -> per-page [MP][n, ps]
-                ksg = jnp.moveaxis(rows_of(k_scale), 1, 0)
-                vsg = jnp.moveaxis(rows_of(v_scale), 1, 0)
     qf = (q.astype(jnp.float32) * scale).transpose(0, 2, 1, 3)  # [n,H,W,D]
+    trips = jnp.minimum(blocks, jnp.max(qpos) // kb + 1)
     qpos = qpos[:, None, :]                                     # [n,1,W]
 
-    def page_step(carry, inputs):
-        m, s, acc = carry
+    def block_step(j, carry):
+        # named for the trace: ONE copy of each row's block of pages,
+        # whatever the number of queries in the row's window
+        with jax.named_scope("paged_gather"):
+            cols = jax.lax.dynamic_slice_in_dim(
+                safe, j * KEY_BLOCK_PAGES, KEY_BLOCK_PAGES, axis=1)
+
+            def block_of(pool):
+                # [n, 8, ps, ...] -> the block's keys in order [n, kb, ...]
+                rows = pool[cols] if layer is None else pool[layer, cols]
+                return rows.reshape((n, kb) + rows.shape[3:])
+
+            kj, vj = block_of(k_pages), block_of(v_pages)
+            if k_scale is not None:
+                ksj, vsj = block_of(k_scale), block_of(v_scale)
         if int4:
-            j, kj, vj, ksj, vsj = inputs       # [n, ps, PB], [n, ps, G]
-            # barrier: the dequantized page must MATERIALIZE before the
-            # dot. Without it XLA fuses the group-scale multiply into
-            # the contraction and the fused gemm's rounding shifts with
-            # the window shape (observed: last-ulp drift at G > 1) —
-            # breaking both ref==kernel bit-identity and the
-            # W-independence the schedule-equivalence tests pin. The
-            # interpret-mode kernel runs op-by-op (dequant, then dot),
-            # so the barrier makes the compiled ref match it exactly.
+            # barrier: the dequantized block must MATERIALIZE before
+            # the dot. Without it XLA fuses the group-scale multiply
+            # into the contraction and the fused gemm's rounding shifts
+            # with the window shape (observed: last-ulp drift at G > 1)
+            # — breaking the W-independence the schedule-equivalence
+            # tests pin.
             kj = jax.lax.optimization_barrier(
-                _dequant_page_int4(kj, ksj, (H, D))).transpose(0, 2, 1, 3)
+                _dequant_page_int4(kj, ksj, (H, D)))
             vj = jax.lax.optimization_barrier(
-                _dequant_page_int4(vj, vsj, (H, D))).transpose(0, 2, 1, 3)
-        elif quantized:
-            j, kj, vj, ksj, vsj = inputs
-        else:
-            j, kj, vj = inputs                 # [n, H, ps, D]
+                _dequant_page_int4(vj, vsj, (H, D)))
         logits = jax.lax.dot_general(
             qf, kj.astype(jnp.float32),
-            (((3,), (3,)), ((0, 1), (0, 1))),
-            preferred_element_type=jnp.float32)          # [n, H, W, ps]
-        kpos = j * ps + jnp.arange(ps)
+            (((3,), (3,)), ((0, 1), (0, 2))),
+            preferred_element_type=jnp.float32)              # [n, H, W, kb]
+        kpos = j * kb + jnp.arange(kb)
+        if MP % KEY_BLOCK_PAGES:
+            # the padded columns' keys: masked for every query, also a
+            # padded one that sits past the table's last position
+            kpos = jnp.where(kpos < MP * ps, kpos, jnp.iinfo(jnp.int32).max)
         return _page_update(
-            m, s, acc, logits, vj.astype(jnp.float32), kpos, qpos,
-            # [n, ps] -> [n, 1, ps]: broadcast over the head axis
+            *carry, logits,
+            vj.astype(jnp.float32).transpose(0, 2, 1, 3),    # [n, H, kb, D]
+            kpos, qpos,
+            # [n, kb] -> [n, 1, kb]: broadcast over the head axis
             k_scale=ksj[:, None] if quantized else None,
-            v_scale=vsj[:, None] if quantized else None), None
+            v_scale=vsj[:, None] if quantized else None)
 
-    pages = (kg, vg) + ((ksg, vsg) if (quantized or int4) else ())
     with jax.named_scope("paged_attention"):
-        carry = (jnp.full((n, H, W, 1), _MASK, jnp.float32),
-                 jnp.zeros((n, H, W, 1), jnp.float32),
-                 jnp.zeros((n, H, W, D), jnp.float32))
-        if MP <= _UNROLL_PAGES:
-            for j in range(MP):
-                carry, _ = page_step(
-                    carry, (j,) + tuple(x[j] for x in pages))
-        else:
-            carry, _ = jax.lax.scan(page_step, carry,
-                                    (jnp.arange(MP),) + pages)
-        m, s, acc = carry
+        m, s, acc = jax.lax.fori_loop(
+            0, trips, block_step,
+            (jnp.full((n, H, W, 1), _MASK, jnp.float32),
+             jnp.zeros((n, H, W, 1), jnp.float32),
+             jnp.zeros((n, H, W, D), jnp.float32)))
         out = acc / jnp.maximum(s, _DENOM_EPS)           # [n, H, W, D]
         return out.transpose(0, 2, 1, 3).astype(q.dtype)  # [n, W, H, D]
 
 
 # the reference always executes COMPILED, even when the caller is
 # eager: op-by-op dispatch rounds a hair differently from XLA's fused
-# lowering, and the bit-identity contract with the interpret-mode
-# kernel (which runs compiled) is pinned at the compiled semantics.
+# lowering, and the bit-identity of a position across windows, tables
+# and layouts is pinned at the compiled semantics.
 # Inside a jitted caller (the decoder's programs) this inlines away.
 @functools.partial(jax.jit, static_argnames=("scale", "int4"))
 def _dense_ref(q, k_pages, v_pages, page_table, start, scale,
@@ -261,8 +272,8 @@ def _packed_ref(q, k_pages, v_pages, page_table, row_ids, pos, scale,
     run the dense reference once. A row's tokens are contiguous in the
     stream, so row r's window is the `window` stream slots from its
     first token on: two gathers of q-sized arrays (in, out) and ONE
-    gather of the row's pages, shared by every token of the row — never
-    a per-token copy of the page table or of the pages. Window slots
+    copy of each block of the row's pages, shared by every token of the
+    row — never a per-token copy of the page table or of the pages. Window slots
     past a row's last token hold the stream's next tokens (another
     row's, or the padded tail): row-local garbage, like the dense
     path's padded queries, that no token gathers back."""
@@ -514,10 +525,10 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
     further than that from its row's first gets garbage, which is what
     the decoder's padded tail is for.
 
-    Per-token math is EXACTLY the dense path's: the reference gathers
-    each ROW's pages once, lays the row's tokens out as that row's
-    window and runs the dense reference's per-page `_page_update` walk
-    (`_packed_ref`; a 1-wide window is padded to the same 2-wide one
+    Per-token math is EXACTLY the dense path's: the reference lays a
+    row's tokens out as that row's window and runs the dense
+    reference's walk over blocks of the ROW's pages, each copied once
+    whatever the row's tokens (`_packed_ref`; a 1-wide window is padded to the same 2-wide one
     the dense W=1 path uses), so a token's output is bit-identical to
     the dense `ragged_paged_attention` computing the same position
     inside any window width — the packed/dense byte-identity the
@@ -582,7 +593,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, start,
     (pages int8, scales f32 [P, ps]) — the serving decoder's
     kv_quant="int8" layout — or int4 as (nibble-packed uint8
     [P, ps, PB], per-group scales f32 [P, ps, G]) — kv_quant="int4".
-    Both paths dequantize per page next to the shared `_page_update`
+    Both paths dequantize per step next to the shared `_page_update`
     (int8 inside it, int4 through `_dequant_page_int4` right before
     it — group scales cannot be folded post-dot).
 
@@ -635,14 +646,12 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, start,
 
 
 # ------------------------------------------------- latent (MLA) attention
-# keys of one block of the materialised walk: a multiple of the page size
-# that divides the gathered context (see `_mla_key_block`)
-_MLA_KEY_PAGES = 8
-
-
 def _mla_key_block(max_pages, page_size):
+    """Keys of one block of the materialised walk: `KEY_BLOCK_PAGES`
+    pages, or the largest part of them that divides the gathered
+    context."""
     import math
-    return math.gcd(int(max_pages), _MLA_KEY_PAGES) * int(page_size)
+    return math.gcd(int(max_pages), KEY_BLOCK_PAGES) * int(page_size)
 
 
 def mla_paged_attention_packed(q_nope, q_rope, latent_pages, layer, w_kvb,

@@ -10,6 +10,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.ragged_paged_attention import KEY_BLOCK_PAGES
+
 __all__ = ["PagedGPTDecoder", "MultiDecodeOut", "RaggedMultiOut",
            "_spec_accept", "_sample_tokens", "_ln", "_mm", "_mm_heads",
            "_quantize_w", "_quantize_kv", "_kv_set", "INT4_GROUP",
@@ -489,6 +491,11 @@ class PagedGPTDecoder:
     # engine options this decoder cannot serve (none)
     horizon_counters = ()
     engine_refusals = {}
+    # columns of the page table one step of the attention's walk copies:
+    # the walk ends at the deepest row's block, so the engine's record
+    # counts whole blocks (`pages_gathered`). None: the attention copies
+    # the whole table it is handed (the latent decoder's absorbed form)
+    walk_block_pages = KEY_BLOCK_PAGES
 
     def __init__(self, model, num_pages=128, page_size=16, max_batch=8,
                  max_pages_per_seq=None, quant=None, kv_quant=None,

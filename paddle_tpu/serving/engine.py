@@ -379,9 +379,16 @@ class ContinuousBatchingEngine:
                   fetch_wait_s, book_s, on_sync_s
         work      tokens emitted, tokens_dispatched, tokens_padded
         pages     pages_gathered, the page copies ONE tick of the
-                  program makes for K in one layer (slots x the table
-                  width it was handed: a row's pages once, whatever
-                  its tokens); pages_live, the pages the rows' contexts
+                  program makes for K in one layer: slots x the
+                  columns its attention walks, a row's pages once
+                  whatever its tokens. A decoder whose walk ends at
+                  the deepest row (`walk_block_pages`: whole blocks of
+                  that many columns) counts the blocks that hold the
+                  host's bound on the deepest position, at most the
+                  table's width — an upper bound on what the device
+                  walks, trailing it as pages_live does; a decoder
+                  that copies the whole table (None) counts the width
+                  it was handed. pages_live, the pages the rows' contexts
                   fill, sum of ceil(_lens / page_size) over rows
                   holding a request — the host's view at dispatch, not
                   fetched: it trails the device by the horizon in
@@ -413,13 +420,15 @@ class ContinuousBatchingEngine:
 
     def _horizon_dispatched(self, rec, shape, program, k, w, t_tokens,
                             decode_rows, prefill_rows, disp_toks,
-                            width, priced=True):
+                            width, need, priced=True):
         """The open round has dispatched its horizon: stamp what it is
         and append the record to the schedule. `shape` is the dispatch
         shape the drift ledger keys on, `program` the name of the
         compiled program it ran (the shape with the table's width:
         `PagedGPTDecoder.program_name`), `width` the columns of the
-        page table handed to it. With a recorder attached the
+        page table handed to it, `need` the columns that hold every
+        position the horizon may reach (the host's bound, before
+        `_table_width` rounds it up). With a recorder attached the
         SAME dict becomes its tick, the price added (`priced=False`:
         a window polluted by a blocking prefill is recorded unpriced
         and stays out of the ledger). The pending tiered-KV restore
@@ -428,8 +437,11 @@ class ContinuousBatchingEngine:
         admission lands inside THIS horizon's window."""
         restore_s = self._take_restore_s()
         ps = self.d.page_size
+        block = self.d.walk_block_pages
+        walked = width if block is None else \
+            min(width, block * -(-need // block))
         rec.update(
-            pages_gathered=self.d.max_batch * width,
+            pages_gathered=self.d.max_batch * walked,
             # a free slot's length is 0 (`_release_slot`)
             pages_live=int(((self._lens + ps - 1) // ps).sum()),
             k=k, w=w, t_tokens=t_tokens, decode_rows=decode_rows,
@@ -1182,7 +1194,8 @@ class ContinuousBatchingEngine:
             self._horizon_dispatched(
                 rec, ("tick", 1, 1), program, k=1, w=1, t_tokens=None,
                 decode_rows=len(active), prefill_rows=0, disp_toks=S,
-                width=self.d.max_pages, priced=not prefilled)
+                width=self.d.max_pages, need=self._need(1),
+                priced=not prefilled)
         with _Phase("engine.fetch", rec, "fetch_wait_s", seq=seq,
                     horizon=seq):
             nxt = np.asarray(nxt)
@@ -1480,7 +1493,8 @@ class ContinuousBatchingEngine:
                     self._horizon_dispatched(
                         rec, shape, program, k=k, w=1, t_tokens=None,
                         decode_rows=len(disp), prefill_rows=0,
-                        disp_toks=k * S, width=self.d.max_pages)
+                        disp_toks=k * S, width=self.d.max_pages,
+                        need=self._need(0, inflight))
                     meta = (out.tokens_block, out.done_before, k,
                             {s: self._slot_req[s] for s in disp},
                             prefilled, rec)
@@ -1684,7 +1698,8 @@ class ContinuousBatchingEngine:
         chunk ticks of a long prompt pay a SHORT gather instead of
         the pool-capacity one (on TPU the kernel streams one page per
         grid step anyway; on CPU the reference's gather width is the
-        mixed tick's dominant cost)."""
+        mixed tick's dominant cost). Returns (width, need): the
+        bucketed width and the page bound it was rounded up from."""
         ps = self.d.page_size
         bound = 1
         for s, rid in live.items():
@@ -1711,7 +1726,17 @@ class ContinuousBatchingEngine:
         width = 1
         while width < need:
             width *= 2
-        return min(width, self.d.max_pages)
+        return min(width, self.d.max_pages), need
+
+    def _need(self, ticks, inflight=None):
+        """Table columns that hold every position a horizon of the
+        loops with a blocking prefill may reach: their `_lens` is the
+        device's, less the ticks in flight (`inflight`, this horizon's
+        among them) or those this dispatch makes (`ticks`); one more
+        for the zero query a 1-wide window is padded with."""
+        deepest = max(int(n) + (inflight[s] if inflight else 0)
+                      for s, n in enumerate(self._lens)) + ticks + 1
+        return min(self.d.max_pages, deepest // self.d.page_size + 1)
 
     def _run_ragged(self, step_times=None, on_sync=None):
         """Mixed-horizon drain: every scheduling round admits queued
@@ -1758,7 +1783,8 @@ class ContinuousBatchingEngine:
                         if self._table_cache is None:
                             self._table_cache = self._table(
                                 self._slot_pages, self.d)
-                        width = self._table_width(live, plan, inflight)
+                        width, need = self._table_width(
+                            live, plan, inflight)
                         t_tokens = plan.t_tokens
                         if t_tokens is None:
                             # a custom scheduler may build HorizonPlan
@@ -1800,7 +1826,8 @@ class ContinuousBatchingEngine:
                         t_tokens=t_tokens,
                         decode_rows=len(live) - plan.prefill_rows,
                         prefill_rows=plan.prefill_rows,
-                        disp_toks=plan.k * t_tokens, width=width)
+                        disp_toks=plan.k * t_tokens, width=width,
+                        need=need)
                     meta = (out.tokens_block, out.emitted, out.real,
                             plan.k,
                             {s: (rid, self._slot_gen[s])
@@ -1995,7 +2022,7 @@ class SpeculativeEngine(ContinuousBatchingEngine):
                 rec, ("tick", 1, 1), program, k=1, w=1, t_tokens=None,
                 decode_rows=len(active), prefill_rows=0,
                 disp_toks=S_all * (2 * k + 1), width=self.d.max_pages,
-                priced=not prefilled)
+                need=self._need(k), priced=not prefilled)
         # the draft fetch and the verify forward both block: the host
         # waits for the device through all of this phase
         with _Phase("engine.fetch", rec, "fetch_wait_s", seq=seq,

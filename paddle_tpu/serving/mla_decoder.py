@@ -91,6 +91,9 @@ class PagedMLADecoder:
     # ticks into its record under these names
     horizon_counters = ("expert_assignments", "experts_hit",
                         "absorbed_rows", "materialised_tokens")
+    # the absorbed form copies every column of the table it is handed
+    # (see `PagedGPTDecoder.walk_block_pages`)
+    walk_block_pages = None
     # engine options this decoder cannot serve: {option: why}. The engine
     # raises at construction when one of them is asked for.
     engine_refusals = {

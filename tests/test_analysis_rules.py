@@ -918,7 +918,7 @@ def test_page_refcount_rule_catches_planted_defects(mutate, expect):
 
 # ------------------------------------------------------- kv-quant rules
 
-def _kv8_decoder(num_pages=8):
+def _kv8_decoder(num_pages=64):
     from paddle_tpu.models import GPT, gpt_tiny
     from paddle_tpu.serving import PagedGPTDecoder
     paddle.seed(0)
@@ -943,7 +943,8 @@ def test_kv_quant_rule_catches_dequantized_pool_in_hbm():
     dequantizes the WHOLE int8 pool up front (convert + scale multiply
     at full pool shape) re-materializes the bf16-width byte stream the
     int8 pool exists to delete. The real capture — dequant inside the
-    shared per-page attention update — stays clean."""
+    shared attention update, a block of 8 pages of each row a step, in
+    a pool larger than that — stays clean."""
     dec = _kv8_decoder()
     ctx = _kv8_ctx(dec)
     pm = PassManager(["kv-quant"])
@@ -1008,7 +1009,7 @@ def test_kv_quant_rule_catches_non_f32_scale_plane():
     assert report.metrics["kv-quant"]["n_bad_scale_planes"] == 1
 
 
-def _kv4_decoder(num_pages=8):
+def _kv4_decoder(num_pages=64):
     from paddle_tpu.models import GPT, gpt_tiny
     from paddle_tpu.serving import PagedGPTDecoder
     paddle.seed(0)
@@ -1034,8 +1035,8 @@ def test_kv_quant_dequant_rule_reproves_on_packed_int4_pool():
     convert at full pool shape (the nibble unpack lands in int8 BEFORE
     the float convert; the uint8 bit-twiddling itself is integer-only
     and can never match), so the same regex catches it. The real
-    capture — per-page unpack next to the shared attention update, a
-    page-sized convert — stays clean."""
+    capture — per-block unpack next to the shared attention update, a
+    convert of 8 pages a row — stays clean."""
     from paddle_tpu.serving.decoder import _dequantize_kv_int4
     dec = _kv4_decoder()
     ctx = _kv4_ctx(dec)

@@ -47,8 +47,10 @@ WHAT_THEY_WERE = {
 
 # (pages_gathered, pages_live) of the same horizons, counted by hand: two
 # slots, pages of 16, prompts of 3, 29 and 2 tokens, 6 tokens an answer.
-# Gathered is slots x the table's columns handed to the program (the whole
-# table of 8 off the ragged loop, its live power-of-two width on it).
+# Gathered is slots x the table's columns the attention walks: here the
+# columns handed to the program (the whole table of 8 off the ragged loop,
+# its live power-of-two width on it), since the walk's block of 8 columns
+# covers them (a wider table: `test_pages_gathered_counts_...` below).
 # Live is the host's `_lens` at dispatch: per tick the contexts are 3+i
 # and 29+i (1 + 2 pages; 7 and 33 at the fifth tick: 1 + 3), then the
 # third request alone (2-6 tokens: 1 page). The ragged loop dispatches
@@ -354,6 +356,53 @@ def test_the_latent_decoders_record_adds_its_four_counters(mla_decoder):
     assert sum(ev["materialised_tokens"] for ev in hz) == \
         sum(map(len, PROMPTS))
     assert sum(ev["absorbed_rows"] for ev in hz) == 3 * len(PROMPTS)
+
+
+# `pages_gathered` where the table is wider than the walk: two slots, pages
+# of 4, 32 columns a row, prompts of 70 and 3 tokens, 6 tokens an answer.
+# (table width, pages_gathered) of every horizon, counted by hand. On the
+# ragged loop the long prompt goes in chunks of 8: after the first horizon
+# (k=4) the bound on its position is 32 + 4 + 1 = 37 (10 pages and one:
+# 11 columns needed, a table of 16), after the second (k=2) 51 (14: 16),
+# after the third 67 (18: a table of 32), then 72 and, decoding, 77-78
+# (19-21: 32). `PagedGPTDecoder`'s walk goes in blocks of 8 columns as far
+# as the bound: 16, 16, then 24 of the 32; `PagedMLADecoder` copies every
+# column it is handed. The loops with a blocking prefill hand over the
+# whole table of 32 and walk 24 of it (contexts of 70-76 tokens).
+WIDE_PROMPTS = (list(range(1, 71)), [1, 2, 3])
+WHAT_IS_WALKED = {
+    ("gpt", "ragged"): [(16, 32), (16, 32)] + [(32, 48)] * 4,
+    ("mla", "ragged"): [(16, 32), (16, 32)] + [(32, 64)] * 4,
+    ("gpt", "multi"): [(32, 48)] * 2,
+    ("gpt", "per_tick"): [(32, 48)] * 5,
+}
+
+
+@pytest.mark.parametrize("which,loop", list(WHAT_IS_WALKED))
+def test_pages_gathered_counts_what_the_walk_copies(tiny_model, which, loop):
+    if which == "gpt":
+        dec = PagedGPTDecoder(tiny_model, num_pages=80, page_size=4,
+                              max_batch=2)
+    else:
+        from paddle_tpu.models.deepseek_v2 import (DeepSeekV2,
+                                                   deepseek_v2_tiny)
+        from paddle_tpu.serving.mla_decoder import PagedMLADecoder
+        dec = PagedMLADecoder(
+            DeepSeekV2(deepseek_v2_tiny(experts_held=4, expert_offset=4)),
+            num_pages=2 * 32 + 2, page_size=4, max_batch=2,
+            max_pages_per_seq=32)
+    assert dec.max_pages == 32
+    assert dec.walk_block_pages == (8 if which == "gpt" else None)
+    eng = ContinuousBatchingEngine(dec, max_new_tokens=6, **LOOPS[loop])
+    for p in WIDE_PROMPTS:
+        eng.submit(np.asarray(p, np.int32))
+    eng.run()
+    hz = [ev for ev in eng.serve_schedule() if ev["kind"] == "horizon"]
+    widths = [int(ev["program"].rsplit("_p", 1)[1]) if loop == "ragged"
+              else dec.max_pages for ev in hz]
+    assert list(zip(widths, (ev["pages_gathered"] for ev in hz))) == \
+        WHAT_IS_WALKED[which, loop]
+    assert all(0 <= ev["pages_live"] <= ev["pages_gathered"] for ev in hz)
 
 
 def test_the_latent_program_names_its_parts_and_its_kind(mla_decoder):
