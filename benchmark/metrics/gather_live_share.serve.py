@@ -2,9 +2,13 @@
 share that held context: over the ticks of every dispatched horizon, the
 pages the rows' contexts fill (`pages_live`, the engine's host-side lengths
 at dispatch) over the page copies a tick's program makes for K in one layer
-(`pages_gathered`: slots x the columns of the table it was handed). The rest
-is the table's power-of-two width beyond the live pages and the slots that
-hold no request (counts; a program that does not count them gives nothing)."""
+(`pages_gathered`: slots x the table columns one tick's attention walks;
+since PR 34, for a decoder whose walk ends at the deepest row, the whole
+blocks of 8 pages that hold the deepest position, at most the table's width;
+for one that copies the table it is handed, that width). The rest is the
+deepest row's depth against the others', the slots that hold no request and
+the last block's tail (counts; a program that does not count them gives
+nothing)."""
 from benchmark.records import horizons
 
 

@@ -5,7 +5,9 @@
 For each metric: each set's median and spread (the distance between the first
 and third quartile of `statistics.quantiles(values, n=4)` as a share of the
 median), the wider of the two, five times that, and how far the second set's
-median lies from the first's.
+median lies from the first's; and, first, the machine's pauses that `sets`
+saw inside each run's window, run by run, so that a far-off run can be held
+against them.
 """
 import json
 import statistics
@@ -34,8 +36,15 @@ def metric_values(path):
     return out
 
 
+def pauses(path):
+    with open(path) as f:
+        return [round(row.get("paused_s") or 0.0, 4)
+                for row in map(json.loads, f)]
+
+
 def main():
     a, b = (metric_values(p) for p in sys.argv[1:3])
+    print(json.dumps({"paused_s": [pauses(p) for p in sys.argv[1:3]]}))
     for name in a:
         va, vb = a[name], b.get(name, [])
         if len(va) < 3 or len(vb) < 3:

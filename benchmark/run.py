@@ -26,7 +26,11 @@ from .cells import BenchmarkError                   # noqa: E402
 
 SPANS = ("bench_window", "batch_made", "step_dispatched", "step_waited",
          "loss_fetched", "request_submitted", "engine_run", "on_sync",
-         "drain")
+         "drain",
+         # the engine's own, around a round and its phases (inside
+         # `engine_run`): an idle gap is named by the innermost
+         "engine.round", "engine.admit", "engine.plan", "engine.dispatch",
+         "engine.fetch", "engine.bookkeep", "engine.on_sync")
 _COMPILE_EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
                    "/jax/core/compile/backend_compile_duration")
 TRACED_WINDOW_S = 6.0     # a traced run's window is short: traces are large

@@ -100,7 +100,7 @@ def test_cell_runs_and_is_judged(root_here, fault, correct):
     result = bench_tiny.run(root_here, CELL, faults=fault)
     assert result["correct"] is correct, result["compared"]
     assert result["attempted"] % 5 == 0 and result["failed"] == 0
-    assert set(result["metrics"]) == {"serve_tokens_per_s", "ttft_ms_p50",
+    assert set(result["metrics"]) == {"serve_tokens_per_s", "ttft_ms_p75",
                                       "itl_ms_p99", "setup_s"}
     gap = result["compared"]["served_logit_gap"]
     assert (gap["value"] <= gap["limit"]) is correct
@@ -201,23 +201,24 @@ def test_real_cell_is_the_issues():
     assert e["max_new_tokens"] == traffic["answer_tokens"]
     assert e["positions"] >= 4096 + traffic["answer_tokens"]
     assert job["checked_requests"] >= 5
-    # Listed by the .serve metrics that move serve_tokens_per_s, but
-    # gather_live_share.serve (held to the GPT cell by its own test).
-    # Not by ttft_ms_p50 and itl_ms_p99 nor by the two tick metrics that
-    # move them: a window holds three waves here, and one stall of
-    # 100 ms (about two a window on the chip's host) flips the 99th
-    # percentile gap by 16% and the median first token by 2-4% (PERF.md
-    # sections 6 and 7). The two new ones are appended at the end: the
-    # driver reads an entry put anywhere else as a change to its
-    # neighbours.
-    serve = [m for m in bench["per_layer"] if m["name"].endswith(".serve")]
-    assert [m["name"] for m in bench["per_layer"][-3:]] == [
-        "gather_live_share.serve", "routed_here_share.serve",
-        "experts_hit_share.serve"]
-    assert [m["name"] for m in serve if name not in m["workloads"]] == [
-        "mixed_tick_ms_p50.serve", "prefill_tick_ms_p50.serve",
-        "gather_live_share.serve"]
-    assert all(m["workloads"] == [name] for m in serve[-2:])
+    # Listed by the .serve metrics that move serve_tokens_per_s, but two:
+    # gather_live_share.serve (held to the GPT cell by its own test) and
+    # stall_share.serve (a traced window of 6 s is ONE wave here, and the
+    # reader needs the same work done three times). Not by the two tails
+    # nor by the two tick metrics that move them: a window holds three
+    # waves here, and one pause of the machine (0.11 s, up to three in
+    # 20 s on some machines) moves the 99th percentile gap, the 61st
+    # largest of 6,096, across a step from 108 to 126 ms (PERF.md sections
+    # 6 and 7). Entries are found by name: the driver makes every PR put
+    # its new ones last, so none keeps a place.
+    serve = {m["name"]: m for m in bench["per_layer"]
+             if m["name"].endswith(".serve")}
+    assert sorted(n for n, m in serve.items()
+                  if name not in m["workloads"]) == [
+        "gather_live_share.serve", "mixed_tick_ms_p50.serve",
+        "prefill_tick_ms_p50.serve", "stall_share.serve"]
+    for own in ("routed_here_share.serve", "experts_hit_share.serve"):
+        assert serve[own]["workloads"] == [name]
     cell = cells.Cell(name)
     e2e = [m["name"] for m in cell.end_to_end()]
     assert e2e == ["serve_tokens_per_s", "setup_s"]

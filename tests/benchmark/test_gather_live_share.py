@@ -37,7 +37,10 @@ def served(tmp_path_factory):
 
 
 def test_the_real_benchmark_lists_it_last_for_the_serve_cell_alone():
-    entry = cells.load_benchmark()["per_layer"][-1]
+    """Found by name (the test keeps its own: the driver counts tests by
+    them): later PRs add their entries after it, as the driver requires."""
+    entry = next(m for m in cells.load_benchmark()["per_layer"]
+                 if m["name"] == NAME)
     assert entry == {
         "name": NAME, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "kernels",
