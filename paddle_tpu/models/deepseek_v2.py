@@ -41,13 +41,15 @@ from ..nn.layer_base import Layer
 
 __all__ = ["DeepSeekV2Config", "DeepSeekV2", "DeepSeekV2MoE",
            "deepseek_v2_tiny", "yarn_inv_freq", "group_limited_route",
-           "group_limited_topk", "moe_ffn"]
+           "group_limited_topk", "moe_ffn", "held_expert_walk"]
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass
 class DeepSeekV2Config:
+    family = "deepseek_v2"               # its entry in mla_decoder.FAMILIES
+    q_lora_scale = kv_lora_scale = 1.0   # this family's MLA has no LoRA scales
     vocab_size: int = 102400
     hidden_size: int = 5120
     num_layers: int = 60
@@ -209,11 +211,16 @@ def rope(x, pos, inv_freq, gain=1.0):
 
 
 # ---------------------------------------------------------------- layers
-def rms_norm(x, w, eps):
+def rms_norm(x, w, eps, gain=1.0):
+    """RMSNorm in float32, x's type out; `gain` (a Python number) scales
+    the result before it is rounded to that type."""
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
                             + eps)
-    return (y * w.astype(jnp.float32)).astype(x.dtype)
+    y = y * w.astype(jnp.float32)
+    if gain != 1.0:
+        y = y * gain
+    return y.astype(x.dtype)
 
 
 def _mm(x, w):
@@ -230,17 +237,29 @@ def mla_project(w, y, pos, cfg, inv_freq):
     """What both forms of MLA share, for normed tokens y [T, h] at
     positions pos [T]: (q_nope [T, H, dn], q_rope [T, H, dr] rotated,
     latent [T, rank + dr] = the normed latent beside the rotated shared
-    key — the row the cache holds). `w`: q_a, q_a_ln, q_b, kv_a, kv_a_ln."""
+    key — the row the cache holds). `w`: q_a, q_a_ln, q_b, kv_a, kv_a_ln.
+    A config with LoRA scales (`q_lora_scale`, `kv_lora_scale`: 1 where
+    the family has none) gets both parts of every query times the first and the
+    normed latent times the second, so the keys' nope part and the values
+    carry it, whichever form reads the row; the shared rotary key does
+    not. Both are applied in float32, before the rounding the unscaled
+    value would get anyway."""
     T, H = y.shape[0], cfg.num_heads
     dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     gain = rope_gain(cfg)
     with jax.named_scope("mla_q"):
         cq = rms_norm(_mm(y, w["q_a"]), w["q_a_ln"], cfg.rms_norm_eps)
-        q = _mm(cq, w["q_b"]).reshape(T, H, dn + dr)
+        if cfg.q_lora_scale == 1.0:
+            q = _mm(cq, w["q_b"])
+        else:
+            q = (jnp.dot(cq, w["q_b"], preferred_element_type=jnp.float32)
+                 * cfg.q_lora_scale).astype(cq.dtype)
+        q = q.reshape(T, H, dn + dr)
         q_nope, q_rope = q[..., :dn], rope(q[..., dn:], pos, inv_freq, gain)
     kva = _mm(y, w["kv_a"])
     latent = jnp.concatenate(
-        [rms_norm(kva[:, :r], w["kv_a_ln"], cfg.rms_norm_eps),
+        [rms_norm(kva[:, :r], w["kv_a_ln"], cfg.rms_norm_eps,
+                  cfg.kv_lora_scale),
          rope(kva[:, r:], pos, inv_freq, gain)], axis=-1)
     return q_nope, q_rope, latent
 
@@ -295,27 +314,26 @@ def group_limited_route(scores, cfg):
 _EXPERT_BLOCK = 256
 
 
-def moe_ffn(w, x, cfg, valid=None, layer=None):
-    """One chip's part of the expert layer for tokens x [T, h]. `w`:
-    router [h, n_routed_experts], gate/up [held, h, f], down [held, f, h]
-    (with `layer`: stacks [layers, held, ...] read at that layer),
-    s_gate/s_up/s_down (the shared experts as one MLP). Routed over the
-    router's whole width in float32. Of the selected (token, expert) pairs
-    only those of held experts are computed, and no pair is dropped: the
-    pairs are sorted by expert, and each held expert walks ITS OWN pairs
-    in blocks of rows, as many blocks as it has pairs for — an expert no
-    token selected runs no block and its weights are not read; there is
-    no capacity. Returns (y, assignments, experts_hit): the pairs held
-    experts took and the held experts with at least one, both over
-    `valid` tokens [T] (None: all)."""
-    E, off, k = cfg.experts_held, cfg.expert_offset, cfg.num_experts_per_tok
+def held_expert_walk(w, x, cw, ei, held, offset, valid=None, layer=None):
+    """The held experts' part of a routed sum, whatever router made the
+    selection: tokens x [T, h], each with k selected columns `ei` [T, k]
+    of combine weight `cw` [T, k]; this chip holds the experts `offset` ..
+    `offset + held` (`w`: gate/up [held, h, f], down [held, f, h]; with
+    `layer`: stacks [layers, held, ...] read at that layer). Of the
+    (token, column) pairs only those of held experts are computed, and no
+    pair is dropped: the pairs are sorted by expert, and each held expert
+    walks ITS OWN pairs in blocks of rows, as many blocks as it has pairs
+    for — an expert no token selected runs no block and its weights are
+    not read; there is no capacity. A pair of any other column (another
+    chip's expert, a column that is no expert at all) sorts behind the
+    held ones and no block reaches it. Returns (routed [T, h] float32,
+    counts [held]: the pairs each held expert took), over `valid` tokens
+    [T] (None: all)."""
+    E, off, k = held, offset, ei.shape[1]
     T, h = x.shape
     A = T * k
     B = min(_EXPERT_BLOCK, A)
     with jax.named_scope("moe_router"):
-        logits = jnp.dot(x.astype(jnp.float32),
-                         w["router"].astype(jnp.float32), precision=HIGHEST)
-        cw, ei = group_limited_topk(jax.nn.softmax(logits, -1), cfg)
         here = (ei >= off) & (ei < off + E) & (cw > 0)
         if valid is not None:
             here = here & valid[:, None]
@@ -357,6 +375,25 @@ def moe_ffn(w, x, cfg, valid=None, layer=None):
         out = jax.lax.fori_loop(0, E, one_expert,
                                 jnp.zeros((A + B, h), jnp.float32))
         routed = jnp.zeros((T, h), jnp.float32).at[token].add(out[:A])
+    return routed, counts
+
+
+def moe_ffn(w, x, cfg, valid=None, layer=None):
+    """One chip's part of the expert layer for tokens x [T, h]. `w`:
+    router [h, n_routed_experts], gate/up [held, h, f], down [held, f, h]
+    (with `layer`: stacks [layers, held, ...] read at that layer),
+    s_gate/s_up/s_down (the shared experts as one MLP). Routed over the
+    router's whole width in float32 (`group_limited_topk`); the held
+    experts' pairs go through `held_expert_walk`, the shared experts are
+    added whole. Returns (y, assignments, experts_hit): the pairs held
+    experts took and the held experts with at least one, both over
+    `valid` tokens [T] (None: all)."""
+    with jax.named_scope("moe_router"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         w["router"].astype(jnp.float32), precision=HIGHEST)
+        cw, ei = group_limited_topk(jax.nn.softmax(logits, -1), cfg)
+    routed, counts = held_expert_walk(w, x, cw, ei, cfg.experts_held,
+                                      cfg.expert_offset, valid, layer)
     with jax.named_scope("moe_shared"):
         y = swiglu(x, w["s_gate"], w["s_up"], w["s_down"])
     return (routed.astype(x.dtype) + y, jnp.sum(counts),
@@ -458,11 +495,6 @@ class _Attention(Layer):
                 self.o_proj.weight)
 
 
-def _attn_weights(w):
-    return dict(zip(("q_a", "q_a_ln", "q_b", "kv_a", "kv_a_ln", "kv_b",
-                     "o"), w))
-
-
 class _Block(Layer):
     def __init__(self, make, i, cfg):
         super().__init__()
@@ -560,9 +592,7 @@ class DeepSeekV2(Layer):
 
 
 def _block_full(p, pre, x, pos, cfg, inv, dense):
-    a = _attn_weights([p[pre + "self_attn." + k + ".weight"] for k in (
-        "q_a_proj", "q_a_layernorm", "q_b_proj", "kv_a_proj_with_mqa",
-        "kv_a_layernorm", "kv_b_proj", "o_proj")])
+    a = {k: p[pre + "self_attn." + leaf] for k, leaf in ATTN_LEAVES.items()}
     y = rms_norm(x, p[pre + "input_layernorm.weight"], cfg.rms_norm_eps)
     x = x + mla_materialised_full(a, y, pos, cfg, inv)
     y = rms_norm(x, p[pre + "post_attention_layernorm.weight"],
@@ -577,3 +607,79 @@ def _block_full(p, pre, x, pos, cfg, inv, dense):
                       p[m + "shared_experts.up_proj.weight"],
                       p[m + "shared_experts.down_proj.weight"]])
     return x + moe_ffn(w, y, cfg)[0]
+
+
+# ------------------------------------------- what the paged decoder walks
+# an attention's weights by the keys `mla_project` and the paged forms
+# read, as leaves under "<layer>.self_attn."
+ATTN_LEAVES = {"q_a": "q_a_proj.weight", "q_a_ln": "q_a_layernorm.weight",
+               "q_b": "q_b_proj.weight", "kv_a": "kv_a_proj_with_mqa.weight",
+               "kv_a_ln": "kv_a_layernorm.weight",
+               "kv_b": "kv_b_proj.weight", "o": "o_proj.weight"}
+
+
+class Serving:
+    """This family's entry in `serving.mla_decoder.FAMILIES`: the block's
+    topology, its leaves, its cache entries and its counters, which
+    `PagedMLADecoder` walks (that module's docstring has the contract).
+    A layer is attention and then one MLP, dense or experts; one cache
+    entry a layer; an expert layer counts the pairs its held experts took
+    and the held experts with at least one."""
+
+    cache_entries = 1
+    counters = ("expert_assignments", "experts_hit")
+    _KINDS = {
+        "dense": {"gate": "mlp.gate_proj.weight",
+                  "up": "mlp.up_proj.weight",
+                  "down": "mlp.down_proj.weight"},
+        "moe": {"router": "mlp.gate.weight",
+                "gate": "mlp.experts.gate_proj",
+                "up": "mlp.experts.up_proj",
+                "down": "mlp.experts.down_proj",
+                "s_gate": "mlp.shared_experts.gate_proj.weight",
+                "s_up": "mlp.shared_experts.up_proj.weight",
+                "s_down": "mlp.shared_experts.down_proj.weight"}}
+
+    @staticmethod
+    def runs(cfg):
+        """[(kind, first layer, layers)]: each run of equal layers."""
+        runs = []
+        for i in range(cfg.num_layers):
+            kind = "dense" if cfg.is_dense(i) else "moe"
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, i, 1])
+        return [tuple(r) for r in runs]
+
+    @classmethod
+    def leaves(cls, kind):
+        """{key: leaf under "layers.<i>."} of a layer of `kind`."""
+        attn = {k: "self_attn." + v for k, v in ATTN_LEAVES.items()}
+        return dict({"ln1": "input_layernorm.weight", **attn,
+                     "ln2": "post_attention_layernorm.weight"},
+                    **cls._KINDS[kind])
+
+    @staticmethod
+    def whole(kind):
+        """The keys read by (layer, expert) inside the walk, so that an
+        expert no token selected is not read at all; every other weight
+        is the layer scan's per-layer slice."""
+        return ("gate", "up", "down") if kind == "moe" else ()
+
+    @staticmethod
+    def block(cfg, kind, x, wl, seg, ri, attend, valid):
+        """One layer over the stream x [T, h]. `wl`: the layer's sliced
+        weights, `seg`: its run's stacks (for `whole` keys, read at `ri`);
+        `attend(j, y, w)`: attention j of the layer over normed y through
+        its own cache entry; `valid` [T]: the real tokens. Returns (x, the
+        layer's counts in `counters`' order, or () if it counts none)."""
+        eps = cfg.rms_norm_eps
+        x = x + attend(0, rms_norm(x, wl["ln1"], eps), wl)
+        y = rms_norm(x, wl["ln2"], eps)
+        if kind == "dense":
+            with jax.named_scope("mlp"):
+                return x + swiglu(y, wl["gate"], wl["up"], wl["down"]), ()
+        w = dict(wl, **{k: seg[k] for k in Serving.whole(kind)})
+        out, assigned, hit = moe_ffn(w, y, cfg, valid=valid, layer=ri)
+        return x + out, (assigned, hit)

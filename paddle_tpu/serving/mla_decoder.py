@@ -3,21 +3,35 @@ second decoder behind `ContinuousBatchingEngine`, beside `PagedGPTDecoder`.
 
 What differs from the GPT decoder, by mechanism:
 
-* ONE latent pool `[layers, pages, page_size, kv_lora_rank + rope_dim]`
+* ONE latent pool `[entries, pages, page_size, kv_lora_rank + rope_dim]`
   in place of `k_pages`/`v_pages` `[L, P, ps, H, D]`: a token costs
-  `latent_dim x itemsize` bytes a layer whatever the number of heads
+  `latent_dim x itemsize` bytes an attention whatever the number of heads
   (`kv_token_bytes`; 1,152 B at the published widths in bfloat16, where
-  full keys and values would be 81,920 B).
+  full keys and values would be 81,920 B). An entry is one ATTENTION's
+  cache: a layer has as many as its family says (`cache_entries`; entry
+  `layer x cache_entries + j` is attention j's).
 * Attention in two forms over that one pool, chosen BY ROW KIND and never
   by a knob: a row that takes prompt chunks attends through MATERIALISED
   heads, a row that decodes through ABSORBED projections, side by side in
   one mixed horizon (`ops.mla_paged_attention_packed`).
-* Layers of two kinds (dense MLP, expert layer): each run of equal layers
-  is stacked and scanned; the pool rides the scans' carry and is written
-  in place at `[layer, page, offset]`.
-* RMSNorm, YaRN rotary positions in place of a position table, gated SiLU
-  MLPs without biases, an untied head, and the dropless group-limited
-  expert layer of `models/deepseek_v2.py`, told which experts it holds.
+* The BLOCK IS THE MODEL FAMILY'S (`FAMILIES`, by `cfg.family`): this
+  decoder stacks each run of equal layers, scans it with the pool in the
+  carry, and gives the family's `block` an `attend(j, y, w)` that
+  projects, writes entry j of the layer in place at `[entry, page,
+  offset]`, attends in both forms and projects out. What a family's
+  entry states: `cache_entries` (a layer), `counters` (names of the
+  int32 counts its blocks return, summed here over layers), `runs(cfg)`
+  ([(kind, first layer, layers)]), `leaves(kind)` ({key: parameter name
+  under "layers.<i>."}; keys that start with "kv_b" are laid out [rank,
+  heads, nope + v]), `whole(kind)` (keys read by (layer, expert), not
+  sliced by the scan) and `block(cfg, kind, x, wl, seg, ri, attend,
+  valid)` -> (x, counts). DeepSeek-V2 (attention then one MLP, dense or
+  group-limited experts with shared ones) is the first entry, LongCat-
+  Flash (two attentions, two dense MLPs and a shortcut-connected expert
+  branch with identity experts a layer) the second.
+* RMSNorm, rotary positions in place of a position table, gated SiLU
+  MLPs without biases, an untied head, and dropless expert layers told
+  which experts they hold (`models.deepseek_v2.held_expert_walk`).
 
 What it keeps: the engine reaches a decoder only through `ragged_multi`,
 `prefill_suffix_batch`, `copy_page`, `program_name`/`first_use`,
@@ -42,42 +56,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.deepseek_v2 import (mla_project, moe_ffn, rms_norm,
-                                  softmax_scale, swiglu, yarn_inv_freq)
+from ..models import deepseek_v2, longcat_flash
+from ..models.deepseek_v2 import (mla_project, rms_norm, softmax_scale,
+                                  yarn_inv_freq)
 from ..ops.ragged_paged_attention import mla_paged_attention_packed
 from .decoder import (RaggedMultiOut, _named_jit, packed_prefill_layout,
                       packed_tick, packed_window, pow2_at_least)
 
-__all__ = ["PagedMLADecoder", "latent_token_bytes"]
+__all__ = ["PagedMLADecoder", "FAMILIES", "latent_token_bytes"]
 
-_ATTN = {"ln1": "input_layernorm.weight",
-         "q_a": "self_attn.q_a_proj.weight",
-         "q_a_ln": "self_attn.q_a_layernorm.weight",
-         "q_b": "self_attn.q_b_proj.weight",
-         "kv_a": "self_attn.kv_a_proj_with_mqa.weight",
-         "kv_a_ln": "self_attn.kv_a_layernorm.weight",
-         "kv_b": "self_attn.kv_b_proj.weight",
-         "o": "self_attn.o_proj.weight",
-         "ln2": "post_attention_layernorm.weight"}
-_DENSE = {"gate": "mlp.gate_proj.weight", "up": "mlp.up_proj.weight",
-          "down": "mlp.down_proj.weight"}
-_MOE = {"router": "mlp.gate.weight",
-        "gate": "mlp.experts.gate_proj", "up": "mlp.experts.up_proj",
-        "down": "mlp.experts.down_proj",
-        "s_gate": "mlp.shared_experts.gate_proj.weight",
-        "s_up": "mlp.shared_experts.up_proj.weight",
-        "s_down": "mlp.shared_experts.down_proj.weight"}
-# the held experts' matrices are read by (layer, expert) inside the expert
-# loop, so that an expert no token selected is not read at all; every
-# other weight is a scan's per-layer slice
-_BY_EXPERT = ("gate", "up", "down")
+# a model family's block, leaves, cache entries and counters, by the
+# `family` its config names (the module's docstring has the contract)
+FAMILIES = {"deepseek_v2": deepseek_v2.Serving,
+            "longcat_flash": longcat_flash.Serving}
 
 
 _stack = jax.jit(lambda *arrays: jnp.stack(arrays))
 
 
 def latent_token_bytes(cfg, itemsize=2):
-    """Cache bytes ONE token costs in one layer: the latent row."""
+    """Cache bytes ONE token costs in one attention: the latent row."""
     return int(cfg.latent_dim * itemsize)
 
 
@@ -86,11 +84,6 @@ class PagedMLADecoder:
     pool (see the module's docstring)."""
 
     kind = "mla"
-    # what `ragged_multi`'s `real` block carries beside each tick's real
-    # token count, a column each: the engine sums them over a horizon's
-    # ticks into its record under these names
-    horizon_counters = ("expert_assignments", "experts_hit",
-                        "absorbed_rows", "materialised_tokens")
     # the absorbed form copies every column of the table it is handed
     # (see `PagedGPTDecoder.walk_block_pages`)
     walk_block_pages = None
@@ -129,6 +122,12 @@ class PagedMLADecoder:
             raise NotImplementedError(
                 "PagedMLADecoder does not shard: a tp mesh is active")
         self.cfg = cfg
+        self.family = fam = FAMILIES[cfg.family]
+        # what `ragged_multi`'s `real` block carries beside each tick's
+        # real token count, a column each: the engine sums them over a
+        # horizon's ticks into its record under these names
+        self.horizon_counters = tuple(fam.counters) + (
+            "absorbed_rows", "materialised_tokens")
         self.page_size, self.num_pages = int(page_size), int(num_pages)
         self.max_batch = int(max_batch)
         self.max_pages = max_pages_per_seq or \
@@ -140,7 +139,8 @@ class PagedMLADecoder:
         self.inv_freq = jnp.asarray(yarn_inv_freq(cfg), jnp.float32)
         self.weights = self._stack_weights(model, release_model)
         self.latent_pages = jnp.zeros(
-            (cfg.num_layers, num_pages, page_size, cfg.latent_dim), dt)
+            (cfg.num_layers * fam.cache_entries, num_pages, page_size,
+             cfg.latent_dim), dt)
         self._packeds = {}          # (k, t, window, width) -> program
         self._packed_prefills = {}  # (t, window) -> program
         self._copy = None
@@ -172,23 +172,18 @@ class PagedMLADecoder:
                     named[n]._value = None
             return out
 
-        runs = []                   # [dense, first layer, n]
-        for i in range(cfg.num_layers):
-            d = cfg.is_dense(i)
-            if runs and runs[-1][0] == d:
-                runs[-1][2] += 1
-            else:
-                runs.append([d, i, 1])
+        fam = self.family
         H, r = cfg.num_heads, cfg.kv_lora_rank
+        self._runs = fam.runs(cfg)
         segments = []
-        for dense, first, n in runs:
-            leaves = dict(_ATTN, **(_DENSE if dense else _MOE))
+        for kind, first, n in self._runs:
             w = {k: take([f"layers.{i}.{leaf}"
                           for i in range(first, first + n)])
-                 for k, leaf in leaves.items()}
-            w["kv_b"] = w["kv_b"].reshape(n, r, H, -1)
+                 for k, leaf in fam.leaves(kind).items()}
+            for k in w:
+                if k.startswith("kv_b"):
+                    w[k] = w[k].reshape(n, r, H, -1)
             segments.append(w)
-        self._runs = [tuple(x) for x in runs]
         return {"embed": take(["embed_tokens.weight"], stack=False),
                 "norm": take(["norm.weight"], stack=False),
                 "head": take(["lm_head.weight"], stack=False),
@@ -220,11 +215,13 @@ class PagedMLADecoder:
 
     @property
     def kv_token_bytes(self):
-        """Cache bytes one token costs in one layer (the latent row)."""
+        """Cache bytes one token costs in one attention (the latent row;
+        a layer has `family.cache_entries` of them)."""
         return latent_token_bytes(self.cfg, self.compute_dtype.itemsize)
 
     def kv_token_bytes_by_layer(self):
-        return [self.kv_token_bytes] * self.cfg.num_layers
+        return [self.kv_token_bytes * self.family.cache_entries] \
+            * self.cfg.num_layers
 
     @property
     def kv_page_bytes(self):
@@ -248,46 +245,49 @@ class PagedMLADecoder:
         cfg = self.cfg
         probes = tuple(float(jnp.sum(v.astype(jnp.float32)))
                        for v in jax.tree_util.tree_leaves(self.weights))
-        return repr((self.kind, cfg.num_layers, cfg.hidden_size,
+        return repr((self.kind, cfg.family, cfg.num_layers, cfg.hidden_size,
                      cfg.num_heads, cfg.latent_dim, cfg.vocab_size,
                      cfg.expert_offset, cfg.experts_held, self.page_size,
                      str(self.compute_dtype), probes)).encode()
 
     # ------------------------------------------------------ the programs
 
-    def _layer(self, dense, seg_w, pids, offs, table, rows, pos, row_new,
+    def _layer(self, kind, seg_w, pids, offs, table, rows, pos, row_new,
                mat_rows, valid, window):
         """One layer over the packed stream as a scan body: carry
-        (x [T, h], the whole latent pool, the two expert counters), xs
-        (the layer's weights, its index in the pool, its index in its
-        run)."""
-        cfg = self.cfg
+        (x [T, h], the whole latent pool, the family's counters), xs
+        (the layer's weights, its first entry in the pool, its index in
+        its run). The family's block says where the layer's attentions
+        sit; `attend` is what each of them is here."""
+        cfg, fam = self.cfg, self.family
         T = rows.shape[0]
         scale = softmax_scale(cfg)
 
         def layer(carry, xs):
-            x, pool, assigned, hit = carry
-            wl, li, ri = xs
-            y = rms_norm(x, wl["ln1"], cfg.rms_norm_eps)
-            q_nope, q_rope, latent = mla_project(wl, y, pos, cfg,
-                                                 self.inv_freq)
-            with jax.named_scope("latent_write"):
-                pool = pool.at[li, pids, offs].set(latent)
-            attn = mla_paged_attention_packed(
-                q_nope, q_rope, pool, li, wl["kv_b"], table, rows, pos,
-                row_new, mat_rows, scale, window=window)
-            x = x + jnp.dot(attn.reshape(T, -1), wl["o"],
-                            preferred_element_type=jnp.float32
-                            ).astype(x.dtype)
-            y = rms_norm(x, wl["ln2"], cfg.rms_norm_eps)
-            if dense:
-                with jax.named_scope("mlp"):
-                    x = x + swiglu(y, wl["gate"], wl["up"], wl["down"])
-            else:
-                w = dict(wl, **{k: seg_w[k] for k in _BY_EXPERT})
-                out, a, h = moe_ffn(w, y, cfg, valid=valid, layer=ri)
-                x, assigned, hit = x + out, assigned + a, hit + h
-            return (x, pool, assigned, hit), None
+            x, pool, *counts = carry
+            wl, entry0, ri = xs
+            pools = [pool]
+
+            def attend(j, y, w):
+                """Attention j of this layer over normed tokens y: its
+                rows written into ITS entry of the pool, read back through
+                both forms, projected out. [T, h]."""
+                entry = entry0 + j if j else entry0
+                q_nope, q_rope, latent = mla_project(w, y, pos, cfg,
+                                                     self.inv_freq)
+                with jax.named_scope("latent_write"):
+                    pools[0] = pools[0].at[entry, pids, offs].set(latent)
+                attn = mla_paged_attention_packed(
+                    q_nope, q_rope, pools[0], entry, w["kv_b"], table, rows,
+                    pos, row_new, mat_rows, scale, window=window)
+                return jnp.dot(attn.reshape(T, -1), w["o"],
+                               preferred_element_type=jnp.float32
+                               ).astype(y.dtype)
+
+            x, added = fam.block(cfg, kind, x, wl, seg_w, ri, attend, valid)
+            if added:
+                counts = [c + a for c, a in zip(counts, added)]
+            return (x, pools[0], *counts), None
 
         return layer
 
@@ -296,31 +296,34 @@ class PagedMLADecoder:
         """The shared PACKED forward (the layout and the arguments of
         `PagedGPTDecoder._packed_forward`; `row_new` [S] the stream
         tokens of each row, `mat_rows` [S] the rows that take prompt
-        chunks). Returns (next [S], pool, expert_assignments,
-        experts_hit)."""
+        chunks). Returns (next [S], pool, the family's counters)."""
+        fam = self.family
         ps, MP = self.page_size, table.shape[1]
         x = weights["embed"][ptok].astype(self.compute_dtype)
         pids = table[rows, jnp.minimum(pos // ps, MP - 1)]
         pids = jnp.where(write_ok, pids, self.num_pages - 1)
         offs = pos % ps
-        carry = (x, pool, jnp.int32(0), jnp.int32(0))
+        carry = (x, pool) + (jnp.int32(0),) * len(fam.counters)
         with jax.named_scope("layers"):
-            for (dense, first, n), seg in zip(self._runs,
-                                              weights["segments"]):
-                xs = {k: v for k, v in seg.items()
-                      if dense or k not in _BY_EXPERT}
+            for (kind, first, n), seg in zip(self._runs,
+                                             weights["segments"]):
+                whole = fam.whole(kind)
+                xs = {k: v for k, v in seg.items() if k not in whole}
+                entry0 = first + jnp.arange(n)
+                if fam.cache_entries != 1:
+                    entry0 = entry0 * fam.cache_entries
                 carry, _ = jax.lax.scan(
-                    self._layer(dense, seg, pids, offs, table, rows, pos,
+                    self._layer(kind, seg, pids, offs, table, rows, pos,
                                 row_new, mat_rows, write_ok, window),
-                    carry, (xs, first + jnp.arange(n), jnp.arange(n)))
-        x, pool, assigned, hit = carry
+                    carry, (xs, entry0, jnp.arange(n)))
+        x, pool, *counts = carry
         x = rms_norm(x, weights["norm"], self.cfg.rms_norm_eps)
         last = x[jnp.clip(last_idx, 0, x.shape[0] - 1)]
         last = jnp.where(live[:, None], last, 0.0)
         with jax.named_scope("lm_head"):
             logits = jnp.dot(last, weights["head"],
                              preferred_element_type=jnp.float32)
-        return jnp.argmax(logits, -1).astype(jnp.int32), pool, assigned, hit
+        return jnp.argmax(logits, -1).astype(jnp.int32), pool, counts
 
     def _packed_multi_step(self, weights, pool, tokens, lens, table, done,
                            remaining, eos, pend, pend_n, w, *, k, t,
@@ -336,12 +339,12 @@ class PagedMLADecoder:
 
             def forward(lay, pools):
                 mat_rows = lay.is_pf & ~done
-                nxt, pool, assigned, hit = self._packed_forward(
+                nxt, pool, counts = self._packed_forward(
                     weights, pools[0], lay.ptok, lay.pos, lay.rows,
                     lay.write_ok, table, lay.last_idx, lay.live, lay.nl,
                     mat_rows, window)
                 return nxt, (pool,), (
-                    assigned, hit, jnp.sum(lay.live & ~lay.is_pf),
+                    *counts, jnp.sum(lay.live & ~lay.is_pf),
                     jnp.sum(jnp.where(mat_rows, lay.nl, 0)))
 
             return packed_tick(carry, w, eos, t=t,
@@ -354,7 +357,7 @@ class PagedMLADecoder:
 
     def _prefill_packed_step(self, weights, pool, ptok, pos, rows, write_ok,
                              table, last_idx, live, row_new, *, window):
-        nxt, pool, _, _ = self._packed_forward(
+        nxt, pool, _ = self._packed_forward(
             weights, pool, ptok, pos, rows, write_ok, table, last_idx, live,
             row_new, live, window)
         return nxt, pool

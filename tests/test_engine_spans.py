@@ -427,6 +427,67 @@ def test_the_latent_program_names_its_parts_and_its_kind(mla_decoder):
     assert fn is None or fn.__name__.startswith("mla_packed_multi_")
 
 
+@pytest.fixture(scope="module")
+def double_layer_decoder():
+    from paddle_tpu.models.longcat_flash import (LongCatFlash,
+                                                 longcat_flash_tiny)
+    from paddle_tpu.serving.mla_decoder import PagedMLADecoder
+    model = LongCatFlash(longcat_flash_tiny(experts_held=4, expert_offset=2))
+    return PagedMLADecoder(model, num_pages=2 * 8 + 2, page_size=8,
+                           max_batch=2, max_pages_per_seq=8)
+
+
+def test_the_double_layer_familys_record_adds_its_five_counters(
+        double_layer_decoder):
+    """The same decoder over the LongCat-Flash family: the record's
+    fields, the latent decoder's four counters and `zero_assignments`
+    (the identity pairs selected), and nothing else. Every real token
+    selects 3 columns in each of 2 layers: the held experts' pairs and
+    the identity pairs together are at most that."""
+    dec = double_layer_decoder
+    eng = ContinuousBatchingEngine(dec, max_new_tokens=4, chunk_tokens=8)
+    for p in PROMPTS:
+        eng.submit(p)
+    eng.run()
+    hz = eng.serve_schedule()
+    assert dec.horizon_counters == (
+        "expert_assignments", "experts_hit", "zero_assignments",
+        "absorbed_rows", "materialised_tokens")
+    for ev in hz:
+        assert set(ev) == FIELDS | set(dec.horizon_counters)
+        assert ev["program"].startswith("mla_packed_multi_k")
+        assert all(isinstance(ev[c], int) and ev[c] >= 0
+                   for c in dec.horizon_counters)
+        real = ev["tokens_dispatched"] - ev["tokens_padded"]
+        assert ev["expert_assignments"] + ev["zero_assignments"] \
+            <= real * 3 * 2
+        assert ev["experts_hit"] <= 4 * 2 * ev["k"]
+    assert sum(ev["zero_assignments"] for ev in hz) > 0
+    assert sum(ev["materialised_tokens"] for ev in hz) == \
+        sum(map(len, PROMPTS))
+    assert sum(ev["absorbed_rows"] for ev in hz) == 3 * len(PROMPTS)
+
+
+def test_the_double_layer_program_names_its_parts(double_layer_decoder):
+    import jax.numpy as jnp
+    dec, S = double_layer_decoder, 2
+    text = jax.jit(lambda *a: dec._packed_multi_step(
+        *a, k=2, t=16, window=8)).lower(
+        dec.weights, dec.latent_pages, jnp.zeros(S, jnp.int32),
+        jnp.zeros(S, jnp.int32), jnp.zeros((S, 8), jnp.int32),
+        jnp.zeros(S, bool), jnp.full(S, 2, jnp.int32),
+        jnp.asarray(-1, jnp.int32),
+        jnp.zeros((S, dec.pend_capacity), jnp.int32),
+        jnp.zeros(S, jnp.int32), jnp.asarray(8, jnp.int32)).as_text(
+        debug_info=True)
+    for scope in ("layers", "attn0", "attn1", "mla_q", "latent_write",
+                  "paged_gather", "mla_absorbed", "mla_materialised",
+                  "mlp0", "mlp1", "moe_router", "moe_experts", "moe_zero",
+                  "shortcut_join", "lm_head"):
+        assert scope in text, scope
+    assert "moe_shared" not in text
+
+
 def _lowered_step(model, loss_fn, batch):
     from paddle_tpu.distributed import Trainer, build_mesh
     build_mesh(dp=1)
