@@ -33,6 +33,7 @@ import dataclasses
 import math
 
 import jax
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 import jax.numpy as jnp
 import numpy as np
 
@@ -310,8 +311,24 @@ def group_limited_route(scores, cfg):
         jnp.arange(scores.shape[0])[:, None], ei].set(w)
 
 
-# rows of one block of the held experts' grouped products
-_EXPERT_BLOCK = 256
+def _expert_tiles(rows, h, f, dtype):
+    """(row tile, (k, n) tile of gate and up [h, f], (k, n) tile of down
+    [f, h]) of the held experts' grouped products, from what the walk
+    sees: `rows` pairs of `dtype`. A weight tile spans the whole
+    contracted width, so consecutive row tiles of one expert find it in
+    place, and as many columns (a multiple of 128 that divides the width,
+    else the width) as keep it under 3 MiB. A few rows get the smallest
+    tile the type's layout allows, since a hit expert then has a pair or
+    two; many rows get 128."""
+    item = jnp.dtype(dtype).itemsize
+
+    def weight_tile(k, n):
+        fits = [t for t in range(128, n + 1, 128)
+                if n % t == 0 and k * t * item <= 3 << 20]
+        return k, (fits[-1] if fits else n)
+
+    tm = 128 if rows > 512 else 8 * (4 // item)
+    return tm, weight_tile(h, f), weight_tile(f, h)
 
 
 def held_expert_walk(w, x, cw, ei, held, offset, valid=None, layer=None):
@@ -321,18 +338,22 @@ def held_expert_walk(w, x, cw, ei, held, offset, valid=None, layer=None):
     `offset + held` (`w`: gate/up [held, h, f], down [held, f, h]; with
     `layer`: stacks [layers, held, ...] read at that layer). Of the
     (token, column) pairs only those of held experts are computed, and no
-    pair is dropped: the pairs are sorted by expert, and each held expert
-    walks ITS OWN pairs in blocks of rows, as many blocks as it has pairs
-    for — an expert no token selected runs no block and its weights are
-    not read; there is no capacity. A pair of any other column (another
-    chip's expert, a column that is no expert at all) sorts behind the
-    held ones and no block reaches it. Returns (routed [T, h] float32,
-    counts [held]: the pairs each held expert took), over `valid` tokens
-    [T] (None: all)."""
+    pair is dropped: the pairs are sorted by expert, and each projection
+    is ONE grouped product over the sorted rows (megablox `gmm`) whose
+    kernel is handed the WHOLE stack as [layers x held, ...] and picks a
+    row tile's expert, and the layer, in its block index map — a hit
+    expert's matrix is read once, from where it lies, an expert no token
+    selected is not read, and nothing is copied out of the stack; there
+    is no capacity. A pair of any other column (another chip's expert, a
+    column that is no expert at all) sorts behind the held ones and no
+    tile reaches it. Returns (routed [T, h] float32, counts [held]: the
+    pairs each held expert took), over `valid` tokens [T] (None: all)."""
     E, off, k = held, offset, ei.shape[1]
     T, h = x.shape
     A = T * k
-    B = min(_EXPERT_BLOCK, A)
+    tm, up_tile, down_tile = _expert_tiles(A, h, w["gate"].shape[-1],
+                                           x.dtype)
+    M = -(-A // tm) * tm
     with jax.named_scope("moe_router"):
         here = (ei >= off) & (ei < off + E) & (cw > 0)
         if valid is not None:
@@ -342,38 +363,30 @@ def held_expert_walk(w, x, cw, ei, held, offset, valid=None, layer=None):
         order = jnp.argsort(key)
         token = (order // k).astype(jnp.int32)
         counts = jnp.zeros(E + 1, jnp.int32).at[key].add(1)[:E]
-        starts = jnp.cumsum(counts) - counts
     with jax.named_scope("moe_experts"):
-        # padded by a block, so that an expert's last block never clamps
-        xs = jnp.pad(x[token], ((0, B), (0, 0)))
-        ws = jnp.pad(cw.reshape(A)[order], (0, B))
+        # padded to whole row tiles
+        xs = jnp.pad(x[token], ((0, M - A), (0, 0)))
+        ws = jnp.pad(cw.reshape(A)[order], (0, M - A))
+        # rows behind the last held pair are written by no tile
+        live = (jnp.arange(M) < jnp.sum(counts))[:, None]
+        sizes = counts
+        if layer is not None:
+            # this layer's experts among the groups of the whole stack
+            sizes = jax.lax.dynamic_update_slice_in_dim(
+                jnp.zeros(w["gate"].shape[0] * E, jnp.int32), counts,
+                layer * E, 0)
 
-        def of(name, e):
-            return w[name][e] if layer is None else w[name][layer, e]
+        def product(a, name, tile):
+            stack = w[name].reshape((-1,) + w[name].shape[-2:])
+            return gmm(a, stack, sizes, preferred_element_type=jnp.float32,
+                       tiling=(tm,) + tile,
+                       interpret=jax.default_backend() == "cpu")
 
-        def one_expert(e, out):
-            def one_block(b, out):
-                lo = starts[e] + b * B
-                xb = jax.lax.dynamic_slice_in_dim(xs, lo, B, 0)
-                # rows past this expert's pairs are the next expert's: 0
-                wb = jnp.where(b * B + jnp.arange(B) < counts[e],
-                               jax.lax.dynamic_slice_in_dim(ws, lo, B, 0),
-                               0.0)
-                g = jnp.dot(xb, of("gate", e),
-                            preferred_element_type=jnp.float32)
-                u = jnp.dot(xb, of("up", e),
-                            preferred_element_type=jnp.float32)
-                a = (jax.nn.silu(g) * u * wb[:, None]).astype(x.dtype)
-                yb = jnp.dot(a, of("down", e),
-                             preferred_element_type=jnp.float32)
-                return jax.lax.dynamic_update_slice_in_dim(
-                    out, jax.lax.dynamic_slice_in_dim(out, lo, B, 0) + yb,
-                    lo, 0)
-            return jax.lax.fori_loop(0, (counts[e] + B - 1) // B,
-                                     one_block, out)
-
-        out = jax.lax.fori_loop(0, E, one_expert,
-                                jnp.zeros((A + B, h), jnp.float32))
+        g = product(xs, "gate", up_tile)
+        u = product(xs, "up", up_tile)
+        a = jnp.where(live, jax.nn.silu(g) * u * ws[:, None],
+                      0.0).astype(x.dtype)
+        out = jnp.where(live, product(a, "down", down_tile), 0.0)
         routed = jnp.zeros((T, h), jnp.float32).at[token].add(out[:A])
     return routed, counts
 
@@ -384,10 +397,11 @@ def moe_ffn(w, x, cfg, valid=None, layer=None):
     (with `layer`: stacks [layers, held, ...] read at that layer),
     s_gate/s_up/s_down (the shared experts as one MLP). Routed over the
     router's whole width in float32 (`group_limited_topk`); the held
-    experts' pairs go through `held_expert_walk`, the shared experts are
-    added whole. Returns (y, assignments, experts_hit): the pairs held
-    experts took and the held experts with at least one, both over
-    `valid` tokens [T] (None: all)."""
+    experts' pairs go through `held_expert_walk` (grouped products that
+    read the stacks in place), the shared experts are added whole.
+    Returns (y, assignments, experts_hit): the pairs held experts took
+    and the held experts with at least one, both over `valid` tokens [T]
+    (None: all)."""
     with jax.named_scope("moe_router"):
         logits = jnp.dot(x.astype(jnp.float32),
                          w["router"].astype(jnp.float32), precision=HIGHEST)

@@ -175,7 +175,8 @@ def longcat_moe(w, u, cfg, valid=None, layer=None):
     """One chip's part of the expert branch for tokens u [T, h]. `w`:
     router [h, router_width], bias [router_width], gate/up [held, h, f],
     down [held, f, h] (with `layer`: stacks [layers, held, ...] read at
-    that layer). Returns (s [T, h], (assignments, experts_hit,
+    that layer, in place, by `held_expert_walk`'s grouped products).
+    Returns (s [T, h], (assignments, experts_hit,
     zero_assignments)): the pairs held experts took, the held experts
     with at least one, and the identity pairs selected, all over `valid`
     tokens [T] (None: all)."""

@@ -31,7 +31,10 @@ What differs from the GPT decoder, by mechanism:
   branch with identity experts a layer) the second.
 * RMSNorm, rotary positions in place of a position table, gated SiLU
   MLPs without biases, an untied head, and dropless expert layers told
-  which experts they hold (`models.deepseek_v2.held_expert_walk`).
+  which experts they hold (`models.deepseek_v2.held_expert_walk`: one
+  grouped product a projection over the pairs sorted by held expert,
+  which reads a hit expert's matrices in place in the stack the scan
+  does not slice).
 
 What it keeps: the engine reaches a decoder only through `ragged_multi`,
 `prefill_suffix_batch`, `copy_page`, `program_name`/`first_use`,

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
+from chip_expert_path import SHAPES as EXPERT_WALKS
+
 V5E_HBM = 16 << 30
 # gpt_1p3b at bs8 x seq1024
 B, S, H, D, HIDDEN, VOCAB = 8, 1024, 16, 128, 2048, 50304
@@ -125,6 +127,36 @@ def test_cross_entropy_backward(one_chip):
         spec(one_chip, (ROWS, 1), jnp.float32),
         spec(one_chip, (ROWS,), jnp.float32))
     assert pallas_calls(compiled) == 1
+
+
+# the held experts' walk at the two MLA cells' real widths (the table of
+# `chip_expert_path.py`, which measures the same shapes on the chip), at the
+# rows of a decode tick and of the largest chunk bucket
+@pytest.mark.parametrize("cell,rows", [
+    (c, r) for c, sh in sorted(EXPERT_WALKS.items())
+    for r in (sh["rows"][0], sh["rows"][-1])])
+def test_held_expert_walk(one_chip, as_tpu, cell, rows):
+    """Three grouped products over the whole [layers, held, ...] stacks:
+    the chip's compiler takes the tiles `_expert_tiles` chooses, and no
+    expert's matrix (nor a layer's experts) is sliced out of a stack."""
+    from paddle_tpu.models.deepseek_v2 import held_expert_walk
+    sh = EXPERT_WALKS[cell]
+    h, f, held, k = sh["h"], sh["f"], sh["held"], sh["k"]
+    up = spec(one_chip, (sh["layers"], held, h, f), jnp.bfloat16)
+    w = {"gate": up, "up": up,
+         "down": spec(one_chip, (sh["layers"], held, f, h), jnp.bfloat16)}
+    compiled = compile_for(
+        lambda w, x, cw, ei, valid, layer: held_expert_walk(
+            w, x, cw, ei, held, 0, valid, layer),
+        w, spec(one_chip, (rows, h), jnp.bfloat16),
+        spec(one_chip, (rows, k), jnp.float32),
+        spec(one_chip, (rows, k), jnp.int32),
+        spec(one_chip, (rows,), jnp.bool_), spec(one_chip, (), jnp.int32))
+    assert pallas_calls(compiled) == 3
+    text = compiled.as_text()
+    for shape in (f"bf16[1,1,{h},{f}]", f"bf16[1,1,{f},{h}]",
+                  f"bf16[1,{held},{h},{f}]", f"bf16[{held},{h},{f}]"):
+        assert shape not in text, shape
 
 
 # ---------------------------------------------------------------- whole step

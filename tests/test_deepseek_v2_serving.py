@@ -180,16 +180,25 @@ def test_the_shares_of_all_groups_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total, want, atol=2e-4)
 
 
-@pytest.mark.parametrize("block", [4, 16, 256])
-def test_no_pair_is_dropped_whatever_the_block(monkeypatch, block):
-    """The held experts' grouped products walk an expert's pairs in blocks
-    of rows: with blocks far smaller than an expert's load (4 rows, 60
-    tokens x 3 selections over 16 experts), with blocks a few experts fit
-    in, and with one block for all, the share equals the reference's
-    (which loops over experts with dense weights): nothing is dropped at
-    a block's edge and no row is counted for its neighbour's expert."""
+def _hold_row_tile(monkeypatch, tile):
+    """The walk chooses its tiles from its input alone (`_expert_tiles`):
+    a test holds the row tile by standing in front of that one place."""
     import paddle_tpu.models.deepseek_v2 as ds
-    monkeypatch.setattr(ds, "_EXPERT_BLOCK", block)
+    chosen = ds._expert_tiles
+    monkeypatch.setattr(ds, "_expert_tiles",
+                        lambda *a: (tile,) + chosen(*a)[1:])
+
+
+@pytest.mark.parametrize("tile", [4, 16, 256])
+def test_no_pair_is_dropped_whatever_the_row_tile(monkeypatch, tile):
+    """The held experts' grouped products go over the sorted pairs in
+    tiles of rows: with tiles far smaller than an expert's load (4 rows,
+    60 tokens x 3 selections over 16 experts), with tiles a few experts
+    fit in, and with one tile for all, the share equals the reference's
+    (which loops over experts with dense weights): nothing is dropped at
+    a tile's edge and no row is counted for its neighbour's expert."""
+    import paddle_tpu.models.deepseek_v2 as ds
+    _hold_row_tile(monkeypatch, tile)
     cfg = dict(TINY, n_routed_experts=8, expert_offset=4)
     params = ref.init_params(cfg, 3)
     layer = ref.f32({k: params[f"layers.2.{k}"]
@@ -238,6 +247,106 @@ def test_a_zeroed_held_expert_moves_the_rows_that_selected_it_and_no_other():
     off = np.abs(got - want).max(-1)
     assert 3 <= chose.sum() < 60
     assert (off[chose] > 1e-2).all() and (off[~chose] <= 2e-4).all(), off
+
+
+# ----------------------------- the grouped product at its edges, by hand
+# 4 experts held from column 2 of a router 10 wide; 3 columns a token
+_HELD, _OFF, _K = 4, 2, 3
+
+
+def _walk_against_a_dense_loop(ei, tile, monkeypatch, layers=None, layer=None):
+    """`held_expert_walk` over hand-made selections `ei` [T, 3] against a
+    loop over the held experts in numpy (float64); returns (routed, counts,
+    want, hand counts)."""
+    import paddle_tpu.models.deepseek_v2 as ds
+    _hold_row_tile(monkeypatch, tile)
+    ei = np.asarray(ei, np.int32)
+    T = len(ei)
+    rng = np.random.default_rng(7)
+    lead = (_HELD,) if layers is None else (layers, _HELD)
+    w = {"gate": rng.normal(size=lead + (32, 16)) * 0.3,
+         "up": rng.normal(size=lead + (32, 16)) * 0.3,
+         "down": rng.normal(size=lead + (16, 32)) * 0.3}
+    x = rng.normal(size=(T, 32))
+    cw = rng.uniform(0.1, 1.0, size=(T, _K))
+    routed, counts = ds.held_expert_walk(
+        {k: jnp.asarray(v, jnp.float32) for k, v in w.items()},
+        jnp.asarray(x, jnp.float32), jnp.asarray(cw, jnp.float32),
+        jnp.asarray(ei), _HELD, _OFF, None,
+        None if layer is None else jnp.int32(layer))
+    mine = w if layer is None else {k: v[layer] for k, v in w.items()}
+    want = np.zeros((T, 32))
+    for e in range(_HELD):
+        weight = np.where(ei == _OFF + e, cw, 0.0).sum(-1, keepdims=True)
+        g, u = x @ mine["gate"][e], x @ mine["up"][e]
+        want += (g / (1 + np.exp(-g)) * u * weight) @ mine["down"][e]
+    hand = [(ei == _OFF + e).sum() for e in range(_HELD)]
+    return np.asarray(routed), np.asarray(counts), want, hand
+
+
+def _selections(loads, others=0):
+    """[T, 3] selections in which held expert e has `loads[e]` pairs (one
+    a token, in a column that moves from token to token), `others` more
+    tokens select no held expert, and every other pair is of a column not
+    held (0, 1 or 6-9)."""
+    away = [0, 1, 6, 7, 8, 9]
+    rows = []
+    for e, n in enumerate(loads):
+        for _ in range(n):
+            i = len(rows)
+            row = [away[(i + j) % 6] for j in range(_K)]
+            row[i % _K] = _OFF + e
+            rows.append(row)
+    rows += [[away[(i + j) % 6] for j in range(_K)] for i in range(others)]
+    order = np.random.default_rng(1).permutation(len(rows))
+    return np.asarray(rows)[order]
+
+
+# name: (loads of the four held experts, tokens with no held pair, row tile)
+_EDGES = {
+    "an_expert_with_no_pair_between_two_that_have_some": ((5, 0, 6, 3), 4, 4),
+    "every_pair_on_one_expert": ((0, 0, 13, 0), 3, 4),
+    "no_pair_held_at_all": ((0, 0, 0, 0), 9, 4),
+    "a_group_boundary_inside_a_row_tile": ((3, 2, 5, 1), 5, 8),
+    "rows_no_multiple_of_the_tile": ((2, 3, 1, 1), 0, 16),
+    "the_first_and_the_last_expert_alone": ((7, 0, 0, 2), 1, 4),
+}
+
+
+@pytest.mark.parametrize("edge", sorted(_EDGES))
+def test_the_grouped_product_at_its_edges(monkeypatch, edge):
+    """The kernel's grid is built from the counts: an expert with no pair
+    is no group to visit (between two that have some, or every expert but
+    one), no held pair at all is no tile at all and a sum of exactly zero,
+    a group's boundary inside a row tile stores each row for its own
+    expert, and pairs that do not fill whole tiles are padded, not lost."""
+    loads, others, tile = _EDGES[edge]
+    ei = _selections(loads, others)
+    if edge == "rows_no_multiple_of_the_tile":
+        assert (len(ei) * _K) % tile
+    routed, counts, want, hand = _walk_against_a_dense_loop(
+        ei, tile, monkeypatch)
+    assert list(counts) == hand == list(loads)
+    np.testing.assert_allclose(routed, want, atol=2e-4)
+    if not sum(loads):
+        assert not routed.any()
+    else:
+        assert np.abs(want).max() > 0.05
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_the_layer_index_picks_its_slice_of_the_stack(monkeypatch, layer):
+    """Stacks [3, held, ...] reach the kernel whole; the layer's counts
+    stand at `layer x held` among the groups of all three layers, so the
+    first and the last layer read their own experts and no other's."""
+    ei = _selections((3, 2, 5, 1), 5)
+    routed, counts, want, hand = _walk_against_a_dense_loop(
+        ei, 8, monkeypatch, layers=3, layer=layer)
+    assert list(counts) == hand
+    np.testing.assert_allclose(routed, want, atol=2e-4)
+    other, *_ = _walk_against_a_dense_loop(ei, 8, monkeypatch, layers=3,
+                                           layer=1)
+    assert np.abs(other - want).max() > 0.05
 
 
 # --------------------------------- (d) selection and YaRN by hand

@@ -215,15 +215,17 @@ def _moe_weights(layer):
             "down": layer["mlp.experts.down_proj"]}
 
 
-@pytest.mark.parametrize("block", [4, 16, 256])
-def test_the_branch_is_the_references_whatever_the_walks_block(monkeypatch,
-                                                               block):
+@pytest.mark.parametrize("tile", [4, 16, 256])
+def test_the_branch_is_the_references_whatever_the_walks_row_tile(
+        monkeypatch, tile):
     """The expert branch alone (router, the held experts' walk, the
     identity sum) against the reference's dense loop over experts, with
-    blocks far smaller than an expert's load, with a few experts to a
-    block, and with one block for all; the counters against the
+    row tiles far smaller than an expert's load, with a few experts to a
+    tile, and with one tile for all; the counters against the
     reference's selection."""
-    monkeypatch.setattr(ds, "_EXPERT_BLOCK", block)
+    chosen = ds._expert_tiles
+    monkeypatch.setattr(ds, "_expert_tiles",
+                        lambda *a: (tile,) + chosen(*a)[1:])
     cfg = dict(TINY, n_routed_experts=5, expert_offset=3)
     layer = ref.f32(_one_layer(cfg, 3))
     u = jnp.asarray(np.random.default_rng(5).normal(size=(60, 32)),
