@@ -37,15 +37,16 @@ def served(tmp_path_factory):
 
 
 def test_the_real_benchmark_lists_it_last_for_the_serve_cell_alone():
-    """Found by name (the test keeps its own: the driver counts tests by
-    them): later PRs add their entries after it, as the driver requires."""
+    """Found by name and held field by field, its list from the GPT serve
+    cell on: later PRs append entries, and cells to its list, after it
+    (the name is older than what the test holds)."""
     entry = next(m for m in cells.load_benchmark()["per_layer"]
                  if m["name"] == NAME)
-    assert entry == {
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
         "name": NAME, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "kernels",
-        "moves": "serve_tokens_per_s",
-        "workloads": ["gpt3_1p3b.serve_wave5_late3"]}
+        "moves": "serve_tokens_per_s"}
+    assert entry["workloads"][0] == "gpt3_1p3b.serve_wave5_late3"
 
 
 def test_reader_is_the_live_pages_over_the_gathered_by_ticks(served):

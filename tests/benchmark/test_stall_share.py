@@ -88,10 +88,12 @@ def test_stall_share_is_the_seconds_over_the_same_works_median(
 def test_the_real_benchmark_lists_it_for_the_gpt_serve_cell():
     bench = cells.load_benchmark()
     entry = next(m for m in bench["per_layer"] if m["name"] == NAME)
-    assert entry == {
+    # field by field, its list from the GPT serve cell on: later PRs append
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
         "name": NAME, "unit": "%", "better": "lower",
         "source": "host_clock", "layer": "entry points",
-        "moves": "serve_tokens_per_s", "workloads": [REAL_CELL]}
+        "moves": "serve_tokens_per_s"}
+    assert entry["workloads"][0] == REAL_CELL
     assert os.path.exists(os.path.join(cells.HERE, "metrics", NAME + ".py"))
     # the DeepSeek cell's traced window is one wave: it could read nothing
     assert NAME in [m["name"] for m in cells.Cell(REAL_CELL).per_layer()]
