@@ -637,11 +637,29 @@ class Serving:
     topology, its leaves, its cache entries and its counters, which
     `PagedMLADecoder` walks (that module's docstring has the contract).
     A layer is attention and then one MLP, dense or experts; one cache
-    entry a layer; an expert layer counts the pairs its held experts took
-    and the held experts with at least one."""
+    entry a layer, the latent row; an expert layer counts the pairs its
+    held experts took and the held experts with at least one."""
 
+    attention = "mla"
     cache_entries = 1
     counters = ("expert_assignments", "experts_hit")
+
+    @staticmethod
+    def inv_freq(cfg):
+        return yarn_inv_freq(cfg)
+
+    @staticmethod
+    def entry_width(cfg):
+        return cfg.latent_dim
+
+    @classmethod
+    def layer_entries(cls, cfg):
+        return [cls.cache_entries] * cfg.num_layers
+
+    @staticmethod
+    def state_layers(cfg):
+        """No layer keeps a per-slot state."""
+        return [], None
     _KINDS = {
         "dense": {"gate": "mlp.gate_proj.weight",
                   "up": "mlp.up_proj.weight",

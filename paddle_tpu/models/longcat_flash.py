@@ -57,6 +57,7 @@ from .deepseek_v2 import (ATTN_LEAVES, HIGHEST, _Attention, _adopted, _drawn,
                           _Leaves, _linear, _MLP, _norm, held_expert_walk,
                           mla_materialised_full, rms_norm, swiglu,
                           yarn_inv_freq)
+from .deepseek_v2 import Serving as _LatentServing
 
 __all__ = ["LongCatFlashConfig", "LongCatFlash", "longcat_flash_tiny",
            "zero_expert_route", "longcat_moe", "longcat_block"]
@@ -253,10 +254,12 @@ def longcat_block(cfg, x, w, attend, experts):
         return b1 + s, counts
 
 
-class Serving:
+class Serving(_LatentServing):
     """This family's entry in `serving.mla_decoder.FAMILIES` (that
     module's docstring has the contract): every layer is one double
-    layer, with two cache entries (its two attentions'); a layer counts
+    layer, with two cache entries (its two attentions' latent rows); the
+    pool's width, the rotary frequencies and the absence of a state are
+    DeepSeek-V2's (the base class); a layer counts
     the pairs its held experts took, the held experts with at least one,
     and the identity pairs selected."""
 

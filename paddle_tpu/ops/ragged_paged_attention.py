@@ -183,7 +183,15 @@ def _ragged_ref(q, k_pages, v_pages, page_table, qpos, scale,
     per-GROUP scales; the block dequantizes through the shared
     `_dequant_page_int4` before its update — the copy stays packed.
     `layer` (a traced scalar; None = the pools are one layer's) names
-    the layer of a whole [L, P, ...] pool to read."""
+    the layer of a whole [L, P, ...] pool to read.
+
+    GROUPED (`v_pages` None): `k_pages` is ONE pool whose last axis holds
+    a token's keys and then its values, `Hk` heads of D each ([..., ps,
+    2 x Hk x D]), with Hk dividing H: query head h reads key/value head
+    h // (H / Hk) (`ops.attention._fold_gqa`'s order). The G = H / Hk
+    query heads of a key/value head are folded into its window (G x W
+    queries over the block's keys), so a block is gathered and
+    contracted once per key/value head and no key is repeated."""
     n, W, H, D = q.shape
     ps = k_pages.shape[1 if layer is None else 2]
     kb = KEY_BLOCK_PAGES * ps
@@ -192,9 +200,16 @@ def _ragged_ref(q, k_pages, v_pages, page_table, qpos, scale,
     safe = jnp.pad(jnp.maximum(page_table, 0),
                    ((0, 0), (0, blocks * KEY_BLOCK_PAGES - MP)))
     quantized = k_scale is not None and not int4
+    fused = v_pages is None
+    Hk = k_pages.shape[-1] // (2 * D) if fused else H
+    G = H // Hk
     qf = (q.astype(jnp.float32) * scale).transpose(0, 2, 1, 3)  # [n,H,W,D]
     trips = jnp.minimum(blocks, jnp.max(qpos) // kb + 1)
     qpos = qpos[:, None, :]                                     # [n,1,W]
+    if G > 1:
+        # [n, H, W, D] -> [n, Hk, G x W, D]: head h = kv head h // G
+        qf = qf.reshape(n, Hk, G * W, D)
+        qpos = jnp.tile(qpos, (1, 1, G))                        # [n,1,GW]
 
     def block_step(j, carry):
         # named for the trace: ONE copy of each row's block of pages,
@@ -208,7 +223,12 @@ def _ragged_ref(q, k_pages, v_pages, page_table, qpos, scale,
                 rows = pool[cols] if layer is None else pool[layer, cols]
                 return rows.reshape((n, kb) + rows.shape[3:])
 
-            kj, vj = block_of(k_pages), block_of(v_pages)
+            if fused:
+                kv = block_of(k_pages)                  # [n, kb, 2 Hk D]
+                kj = kv[..., :Hk * D].reshape(n, kb, Hk, D)
+                vj = kv[..., Hk * D:].reshape(n, kb, Hk, D)
+            else:
+                kj, vj = block_of(k_pages), block_of(v_pages)
             if k_scale is not None:
                 ksj, vsj = block_of(k_scale), block_of(v_scale)
         if int4:
@@ -242,10 +262,12 @@ def _ragged_ref(q, k_pages, v_pages, page_table, qpos, scale,
     with jax.named_scope("paged_attention"):
         m, s, acc = jax.lax.fori_loop(
             0, trips, block_step,
-            (jnp.full((n, H, W, 1), _MASK, jnp.float32),
-             jnp.zeros((n, H, W, 1), jnp.float32),
-             jnp.zeros((n, H, W, D), jnp.float32)))
+            (jnp.full((n, Hk, G * W, 1), _MASK, jnp.float32),
+             jnp.zeros((n, Hk, G * W, 1), jnp.float32),
+             jnp.zeros((n, Hk, G * W, D), jnp.float32)))
         out = acc / jnp.maximum(s, _DENOM_EPS)           # [n, H, W, D]
+        if G > 1:
+            out = out.reshape(n, H, W, D)
         return out.transpose(0, 2, 1, 3).astype(q.dtype)  # [n, W, H, D]
 
 
@@ -538,7 +560,11 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
     index maps (see `_packed_kernel_call`). int8/int4 pools pass as
     (pages, scales) tuples exactly like the dense entry point; with
     `layer` given the pools are whole ([L, P, ...]) and that layer is
-    read, as in the dense entry point. Returns [T, H, D]."""
+    read, as in the dense entry point. With `v_pages` None the pool is
+    GROUPED: `k_pages` holds each token's keys and then its values of
+    fewer key/value heads than q has heads (`_ragged_ref` says how the
+    query heads fold onto them); the reference alone walks it. Returns
+    [T, H, D]."""
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
     row_ids = jnp.asarray(row_ids, jnp.int32)
@@ -560,7 +586,7 @@ def ragged_paged_attention_packed(q, k_pages, v_pages, page_table,
                            pos, scale=float(scale), window=window,
                            k_scale=ks, v_scale=vs, int4=int4, layer=layer)
 
-    if not use_kernel:
+    if not use_kernel or v_pages is None:
         return reference()
     if interpret is None:
         interpret = jax.default_backend() == "cpu"

@@ -1,34 +1,54 @@
-"""Paged decode executor for latent-attention (MLA) expert models: the
-second decoder behind `ContinuousBatchingEngine`, beside `PagedGPTDecoder`.
+"""Paged decode executor for the expert model families: the second
+decoder behind `ContinuousBatchingEngine`, beside `PagedGPTDecoder`.
 
 What differs from the GPT decoder, by mechanism:
 
-* ONE latent pool `[entries, pages, page_size, kv_lora_rank + rope_dim]`
-  in place of `k_pages`/`v_pages` `[L, P, ps, H, D]`: a token costs
-  `latent_dim x itemsize` bytes an attention whatever the number of heads
-  (`kv_token_bytes`; 1,152 B at the published widths in bfloat16, where
-  full keys and values would be 81,920 B). An entry is one ATTENTION's
-  cache: a layer has as many as its family says (`cache_entries`; entry
-  `layer x cache_entries + j` is attention j's).
-* Attention in two forms over that one pool, chosen BY ROW KIND and never
-  by a knob: a row that takes prompt chunks attends through MATERIALISED
-  heads, a row that decodes through ABSORBED projections, side by side in
-  one mixed horizon (`ops.mla_paged_attention_packed`).
+* ONE pool `[entries, pages, page_size, width]` in place of
+  `k_pages`/`v_pages` `[L, P, ps, H, D]`, DESCRIBED BY THE FAMILY: how
+  many entries each layer has (`layer_entries`) and how many values an
+  entry holds a token (`entry_width`). A latent-attention (MLA) family
+  has one or two entries a layer of `kv_lora_rank + rope_dim` values
+  (1,152 B a token in bfloat16 where DeepSeek-V2's full keys and values
+  would be 81,920 B); LFM2 has one entry an attention layer, its
+  grouped keys and then values (2 x 8 x 64), and none a conv layer.
+  Entry `e` of layer `l` sits at the layer's first entry plus `e`.
+* The family's attention over its entries (`attention`): "mla" in two
+  forms over one pool, chosen BY ROW KIND and never by a knob (a row
+  that takes prompt chunks attends through MATERIALISED heads, a row
+  that decodes through ABSORBED projections, side by side in one mixed
+  horizon, `ops.mla_paged_attention_packed`), or "gqa", fewer
+  key/value heads than query heads through the grouped form of the
+  packed walk (`ops.ragged_paged_attention_packed` with one pool).
+* A PER-SLOT STATE where the family keeps one (`state_layers`: LFM2's
+  conv layers keep z of a row's two latest positions, [slots, 2, h]
+  each). It rides the horizon's carry beside the pool, is read and
+  written in the packed tick for chunk rows and decode rows alike
+  (`models.lfm2_moe.packed_conv_taps`: a tap at a position below 0 reads
+  zero, so a slot a new request takes needs no reset; a row with no
+  tokens, frozen or padded, writes nothing), and stays on the device
+  between horizons.
 * The BLOCK IS THE MODEL FAMILY'S (`FAMILIES`, by `cfg.family`): this
-  decoder stacks each run of equal layers, scans it with the pool in the
-  carry, and gives the family's `block` an `attend(j, y, w)` that
-  projects, writes entry j of the layer in place at `[entry, page,
-  offset]`, attends in both forms and projects out. What a family's
-  entry states: `cache_entries` (a layer), `counters` (names of the
+  decoder stacks each run of equal layers, scans it with the pool (and
+  the state) in the carry, and gives the family's `block` an `attend(j,
+  y, w)` that projects, writes entry j of the layer in place at
+  `[entry, page, offset]`, attends and projects out, and to a family
+  with a state `taps(z)` (the conv's two earlier z of every stream
+  token). What a family's entry states: `attention`, `entry_width(cfg)`,
+  `layer_entries(cfg)` ([entries a layer]), `state_layers(cfg)` ((the
+  layers that keep a state, its shape a slot), or ([], None)),
+  `inv_freq(cfg)` (its rotary frequencies), `counters` (names of the
   int32 counts its blocks return, summed here over layers), `runs(cfg)`
   ([(kind, first layer, layers)]), `leaves(kind)` ({key: parameter name
   under "layers.<i>."}; keys that start with "kv_b" are laid out [rank,
   heads, nope + v]), `whole(kind)` (keys read by (layer, expert), not
   sliced by the scan) and `block(cfg, kind, x, wl, seg, ri, attend,
-  valid)` -> (x, counts). DeepSeek-V2 (attention then one MLP, dense or
-  group-limited experts with shared ones) is the first entry, LongCat-
-  Flash (two attentions, two dense MLPs and a shortcut-connected expert
-  branch with identity experts a layer) the second.
+  valid[, taps])` -> (x, counts); a "gqa" family also `project(w, y,
+  pos, cfg, inv_freq)` -> (q [T, H, D], the row its entry holds).
+  DeepSeek-V2 (attention then one MLP, dense or group-limited experts
+  with shared ones) is the first entry, LongCat-Flash (two attentions,
+  two dense MLPs and a shortcut-connected expert branch with identity
+  experts a layer) the second, LFM2-MoE (gated short convs and
+  grouped-query attentions, sigmoid-routed experts) the third.
 * RMSNorm, rotary positions in place of a position table, gated SiLU
   MLPs without biases, an untied head, and dropless expert layers told
   which experts they hold (`models.deepseek_v2.held_expert_walk`: one
@@ -50,8 +70,11 @@ packed chunked prefill, greedy. Every other option RAISES at construction
 (`quant`, `kv_quant`, `use_kernel`, sampling, `mesh`/tp)
 or when an engine is built over it (`engine_refusals`: prefix cache, host
 tier, speculation, the dispatch-separate and per-tick loops); adapters
-have no attach method. Nothing falls back silently.
+have no attach method. A family with a per-slot state has no packed
+prefill outside the horizon either (`prefill_suffix_batch` raises: its
+rows are not slots). Nothing falls back silently.
 """
+import collections
 import functools
 import weakref
 
@@ -59,10 +82,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import deepseek_v2, longcat_flash
-from ..models.deepseek_v2 import (mla_project, rms_norm, softmax_scale,
-                                  yarn_inv_freq)
-from ..ops.ragged_paged_attention import mla_paged_attention_packed
+from ..models import deepseek_v2, lfm2_moe, longcat_flash
+from ..models.deepseek_v2 import mla_project, rms_norm, softmax_scale
+from ..ops.ragged_paged_attention import (KEY_BLOCK_PAGES,
+                                          mla_paged_attention_packed,
+                                          ragged_paged_attention_packed)
 from .decoder import (RaggedMultiOut, _named_jit, packed_prefill_layout,
                       packed_tick, packed_window, pow2_at_least)
 
@@ -71,7 +95,8 @@ __all__ = ["PagedMLADecoder", "FAMILIES", "latent_token_bytes"]
 # a model family's block, leaves, cache entries and counters, by the
 # `family` its config names (the module's docstring has the contract)
 FAMILIES = {"deepseek_v2": deepseek_v2.Serving,
-            "longcat_flash": longcat_flash.Serving}
+            "longcat_flash": longcat_flash.Serving,
+            "lfm2_moe": lfm2_moe.Serving}
 
 
 _stack = jax.jit(lambda *arrays: jnp.stack(arrays))
@@ -82,13 +107,55 @@ def latent_token_bytes(cfg, itemsize=2):
     return int(cfg.latent_dim * itemsize)
 
 
+def _mla_attend(dec, lay, pool, entry, y, w):
+    """MLA over normed tokens y [T, h]: its rows written into entry
+    `entry` of the pool, read back through both forms, projected out.
+    Returns ([T, h], pool)."""
+    q_nope, q_rope, latent = mla_project(w, y, lay.pos, dec.cfg,
+                                         dec.inv_freq)
+    with jax.named_scope("latent_write"):
+        pool = pool.at[entry, lay.pids, lay.offs].set(latent)
+    attn = mla_paged_attention_packed(
+        q_nope, q_rope, pool, entry, w["kv_b"], lay.table, lay.rows,
+        lay.pos, lay.row_new, lay.mat_rows, softmax_scale(dec.cfg),
+        window=lay.window)
+    return jnp.dot(attn.reshape(y.shape[0], -1), w["o"],
+                   preferred_element_type=jnp.float32).astype(y.dtype), pool
+
+
+def _gqa_attend(dec, lay, pool, entry, y, w):
+    """Grouped-query attention over normed tokens y [T, h]: the family's
+    projection, its keys and values written into entry `entry`, the
+    grouped packed walk over the rows' pages (query head h reads
+    key/value head h // (H / Hk)), projected out. Returns ([T, h],
+    pool)."""
+    q, row = dec.family.project(w, y, lay.pos, dec.cfg, dec.inv_freq)
+    with jax.named_scope("kv_write"):
+        pool = pool.at[entry, lay.pids, lay.offs].set(row)
+    attn = ragged_paged_attention_packed(
+        q, pool, None, lay.table, lay.rows, lay.pos, window=lay.window,
+        layer=entry)
+    return jnp.dot(attn.reshape(y.shape[0], -1), w["o"],
+                   preferred_element_type=jnp.float32).astype(y.dtype), pool
+
+
+# a family's attention over its pool entries, by its `attention`
+ATTENTIONS = {"mla": _mla_attend, "gqa": _gqa_attend}
+
+# what one layer of the packed forward reads of the stream's layout
+_Stream = collections.namedtuple(
+    "_Stream", "pids offs table rows pos row_new mat_rows window")
+
+
 class PagedMLADecoder:
-    """Stacked-weight MLA/expert decode executor over a paged latent
-    pool (see the module's docstring)."""
+    """Stacked-weight expert-model decode executor over a paged pool
+    (and a per-slot state) that the model's family describes (see the
+    module's docstring)."""
 
     kind = "mla"
     # the absorbed form copies every column of the table it is handed
-    # (see `PagedGPTDecoder.walk_block_pages`)
+    # (see `PagedGPTDecoder.walk_block_pages`); the grouped walk of a
+    # "gqa" family ends at the deepest row's block, as the GPT walk does
     walk_block_pages = None
     # engine options this decoder cannot serve: {option: why}. The engine
     # raises at construction when one of them is asked for.
@@ -126,6 +193,16 @@ class PagedMLADecoder:
                 "PagedMLADecoder does not shard: a tp mesh is active")
         self.cfg = cfg
         self.family = fam = FAMILIES[cfg.family]
+        if fam.attention == "gqa":
+            self.walk_block_pages = KEY_BLOCK_PAGES
+        self._state_layers, state_shape = fam.state_layers(cfg)
+        if self._state_layers:
+            self.engine_refusals = dict(self.engine_refusals, **{
+                k: why + "; and a layer's per-slot state is not a page "
+                "(a mounted prefix, a restored or a spilled page would "
+                "come without the state of the positions it holds)"
+                for k, why in self.engine_refusals.items()
+                if k in ("prefix_cache", "host_tier")})
         # what `ragged_multi`'s `real` block carries beside each tick's
         # real token count, a column each: the engine sums them over a
         # horizon's ticks into its record under these names
@@ -139,11 +216,23 @@ class PagedMLADecoder:
         self.kv_quant = self.lora = None
         self.n_adapters = 0
         self.compute_dtype = dt = jnp.dtype(cfg.dtype)
-        self.inv_freq = jnp.asarray(yarn_inv_freq(cfg), jnp.float32)
+        self.inv_freq = jnp.asarray(fam.inv_freq(cfg), jnp.float32)
         self.weights = self._stack_weights(model, release_model)
-        self.latent_pages = jnp.zeros(
-            (cfg.num_layers * fam.cache_entries, num_pages, page_size,
-             cfg.latent_dim), dt)
+        # the first pool entry of each layer (a layer with none: its
+        # place among the layers that keep a state)
+        entries = fam.layer_entries(cfg)
+        self._entries = entries
+        firsts = np.cumsum([0] + entries[:-1])
+        holder = {layer: i for i, layer in enumerate(self._state_layers)}
+        self._index = [int(firsts[i]) if entries[i] else holder.get(i, 0)
+                       for i in range(cfg.num_layers)]
+        pool = jnp.zeros((sum(entries), num_pages, page_size,
+                          fam.entry_width(cfg)), dt)
+        # what every program carries and returns: the pool, with the
+        # per-slot state beside it where the family keeps one
+        self.cache = pool if not self._state_layers else (pool, jnp.zeros(
+            (len(self._state_layers), self.max_batch) + tuple(state_shape),
+            dt))
         self._packeds = {}          # (k, t, window, width) -> program
         self._packed_prefills = {}  # (t, window) -> program
         self._copy = None
@@ -176,7 +265,6 @@ class PagedMLADecoder:
             return out
 
         fam = self.family
-        H, r = cfg.num_heads, cfg.kv_lora_rank
         self._runs = fam.runs(cfg)
         segments = []
         for kind, first, n in self._runs:
@@ -185,7 +273,8 @@ class PagedMLADecoder:
                  for k, leaf in fam.leaves(kind).items()}
             for k in w:
                 if k.startswith("kv_b"):
-                    w[k] = w[k].reshape(n, r, H, -1)
+                    w[k] = w[k].reshape(n, cfg.kv_lora_rank, cfg.num_heads,
+                                        -1)
             segments.append(w)
         return {"embed": take(["embed_tokens.weight"], stack=False),
                 "norm": take(["norm.weight"], stack=False),
@@ -218,13 +307,21 @@ class PagedMLADecoder:
 
     @property
     def kv_token_bytes(self):
-        """Cache bytes one token costs in one attention (the latent row;
-        a layer has `family.cache_entries` of them)."""
-        return latent_token_bytes(self.cfg, self.compute_dtype.itemsize)
+        """Cache bytes one token costs in one pool entry (a layer has
+        `family.layer_entries` of them)."""
+        return int(self.family.entry_width(self.cfg)
+                   * self.compute_dtype.itemsize)
 
     def kv_token_bytes_by_layer(self):
-        return [self.kv_token_bytes * self.family.cache_entries] \
-            * self.cfg.num_layers
+        return [self.kv_token_bytes * e for e in self._entries]
+
+    @property
+    def state_slot_bytes(self):
+        """Bytes of the per-slot state one slot holds, all layers."""
+        if not self._state_layers:
+            return 0
+        state = self.cache[1]
+        return int(state.size // state.shape[1] * state.dtype.itemsize)
 
     @property
     def kv_page_bytes(self):
@@ -233,14 +330,16 @@ class PagedMLADecoder:
     def step_hbm_bytes(self, avg_ctx=None, batch=None):
         """HBM bytes ONE decode tick moves at most: every weight byte
         held (the held experts too: a tick reads those its rows select,
-        `experts_hit` counts them) plus each slot's latent rows at
-        `avg_ctx` (default: half a sequence's pool capacity)."""
+        `experts_hit` counts them) plus each slot's pool rows at
+        `avg_ctx` (default: half a sequence's pool capacity) and its
+        per-slot state."""
         if avg_ctx is None:
             avg_ctx = max(self.pend_capacity // 2, 1)
         if batch is None:
             batch = self.max_batch
         return int(self.cfg.num_params() * self.compute_dtype.itemsize
-                   + batch * avg_ctx * sum(self.kv_token_bytes_by_layer()))
+                   + batch * avg_ctx * sum(self.kv_token_bytes_by_layer())
+                   + batch * self.state_slot_bytes)
 
     def cache_fingerprint(self):
         """Identity of this decoder's cache bytes (weights, shapes, page
@@ -249,104 +348,119 @@ class PagedMLADecoder:
         probes = tuple(float(jnp.sum(v.astype(jnp.float32)))
                        for v in jax.tree_util.tree_leaves(self.weights))
         return repr((self.kind, cfg.family, cfg.num_layers, cfg.hidden_size,
-                     cfg.num_heads, cfg.latent_dim, cfg.vocab_size,
+                     cfg.num_heads, tuple(self._entries),
+                     self.family.entry_width(cfg), cfg.vocab_size,
                      cfg.expert_offset, cfg.experts_held, self.page_size,
                      str(self.compute_dtype), probes)).encode()
 
     # ------------------------------------------------------ the programs
 
-    def _layer(self, kind, seg_w, pids, offs, table, rows, pos, row_new,
-               mat_rows, valid, window):
+    def _layer(self, kind, seg_w, lay, valid):
         """One layer over the packed stream as a scan body: carry
-        (x [T, h], the whole latent pool, the family's counters), xs
-        (the layer's weights, its first entry in the pool, its index in
-        its run). The family's block says where the layer's attentions
-        sit; `attend` is what each of them is here."""
+        (x [T, h], the cache: the whole pool, and the whole state where
+        the family keeps one, the family's counters), xs (the layer's
+        weights, its first pool entry or its place among the layers that
+        keep a state, its index in its run). The family's block says
+        where the layer's attentions sit; `attend` is what each of them
+        is here, `taps` what the state gives a conv."""
         cfg, fam = self.cfg, self.family
-        T = rows.shape[0]
-        scale = softmax_scale(cfg)
+        attention = ATTENTIONS[fam.attention]
+        stateful = bool(self._state_layers)
 
         def layer(carry, xs):
-            x, pool, *counts = carry
+            x, cache, *counts = carry
             wl, entry0, ri = xs
-            pools = [pool]
+            held = list(cache) if stateful else [cache]
 
             def attend(j, y, w):
-                """Attention j of this layer over normed tokens y: its
-                rows written into ITS entry of the pool, read back through
-                both forms, projected out. [T, h]."""
+                """Attention j of this layer over normed tokens y,
+                through ITS entry of the pool. [T, h]."""
                 entry = entry0 + j if j else entry0
-                q_nope, q_rope, latent = mla_project(w, y, pos, cfg,
-                                                     self.inv_freq)
-                with jax.named_scope("latent_write"):
-                    pools[0] = pools[0].at[entry, pids, offs].set(latent)
-                attn = mla_paged_attention_packed(
-                    q_nope, q_rope, pools[0], entry, w["kv_b"], table, rows,
-                    pos, row_new, mat_rows, scale, window=window)
-                return jnp.dot(attn.reshape(T, -1), w["o"],
-                               preferred_element_type=jnp.float32
-                               ).astype(y.dtype)
+                out, held[0] = attention(self, lay, held[0], entry, y, w)
+                return out
 
-            x, added = fam.block(cfg, kind, x, wl, seg_w, ri, attend, valid)
+            extra = {}
+            if stateful:
+                def taps(z):
+                    """This layer's (z2, z1) of every stream token; the
+                    rows' state moves on to their latest positions."""
+                    with jax.named_scope("conv_state"):
+                        st = held[1][entry0]
+                        out, st = lfm2_moe.packed_conv_taps(
+                            z, st, lay.rows, lay.pos, lay.row_new)
+                        held[1] = held[1].at[entry0].set(st)
+                    return out
+
+                extra["taps"] = taps
+            x, added = fam.block(cfg, kind, x, wl, seg_w, ri, attend, valid,
+                                 **extra)
             if added:
                 counts = [c + a for c, a in zip(counts, added)]
-            return (x, pools[0], *counts), None
+            return (x, tuple(held) if stateful else held[0], *counts), None
 
         return layer
 
-    def _packed_forward(self, weights, pool, ptok, pos, rows, write_ok,
+    def _entry0(self, first, n):
+        """xs' index of each layer of a run (see `_layer`)."""
+        entries = self._entries[first:first + n]
+        if len(set(self._entries)) == 1 and entries[0]:
+            entry0 = first + jnp.arange(n)
+            if entries[0] != 1:
+                entry0 = entry0 * entries[0]
+            return entry0
+        return jnp.asarray(self._index[first:first + n], jnp.int32)
+
+    def _packed_forward(self, weights, cache, ptok, pos, rows, write_ok,
                         table, last_idx, live, row_new, mat_rows, window):
         """The shared PACKED forward (the layout and the arguments of
         `PagedGPTDecoder._packed_forward`; `row_new` [S] the stream
         tokens of each row, `mat_rows` [S] the rows that take prompt
-        chunks). Returns (next [S], pool, the family's counters)."""
+        chunks). Returns (next [S], cache, the family's counters)."""
         fam = self.family
         ps, MP = self.page_size, table.shape[1]
         x = weights["embed"][ptok].astype(self.compute_dtype)
         pids = table[rows, jnp.minimum(pos // ps, MP - 1)]
         pids = jnp.where(write_ok, pids, self.num_pages - 1)
         offs = pos % ps
-        carry = (x, pool) + (jnp.int32(0),) * len(fam.counters)
+        lay = _Stream(pids, offs, table, rows, pos, row_new, mat_rows, window)
+        carry = (x, cache) + (jnp.int32(0),) * len(fam.counters)
         with jax.named_scope("layers"):
             for (kind, first, n), seg in zip(self._runs,
                                              weights["segments"]):
                 whole = fam.whole(kind)
                 xs = {k: v for k, v in seg.items() if k not in whole}
-                entry0 = first + jnp.arange(n)
-                if fam.cache_entries != 1:
-                    entry0 = entry0 * fam.cache_entries
                 carry, _ = jax.lax.scan(
-                    self._layer(kind, seg, pids, offs, table, rows, pos,
-                                row_new, mat_rows, write_ok, window),
-                    carry, (xs, entry0, jnp.arange(n)))
-        x, pool, *counts = carry
+                    self._layer(kind, seg, lay, write_ok),
+                    carry, (xs, self._entry0(first, n), jnp.arange(n)))
+        x, cache, *counts = carry
         x = rms_norm(x, weights["norm"], self.cfg.rms_norm_eps)
         last = x[jnp.clip(last_idx, 0, x.shape[0] - 1)]
         last = jnp.where(live[:, None], last, 0.0)
         with jax.named_scope("lm_head"):
             logits = jnp.dot(last, weights["head"],
                              preferred_element_type=jnp.float32)
-        return jnp.argmax(logits, -1).astype(jnp.int32), pool, counts
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache, counts
 
-    def _packed_multi_step(self, weights, pool, tokens, lens, table, done,
+    def _packed_multi_step(self, weights, cache, tokens, lens, table, done,
                            remaining, eos, pend, pend_n, w, *, k, t,
                            window):
         """K mixed ticks over the packed [t] stream: `decoder.packed_tick`
         (the layout and every per-row rule, shared with
         `PagedGPTDecoder`) over this decoder's forward. A row that holds
         prompt tokens (`pend_n > 0`) attends materialised, a row that
-        decodes absorbed. Beside each tick's real token count the `real`
-        block carries `horizon_counters`, a column each."""
+        decodes absorbed (an MLA family's forms). Beside each tick's real
+        token count the `real` block carries `horizon_counters`, a column
+        each."""
         def tick(carry, _):
             done = carry[2]
 
-            def forward(lay, pools):
+            def forward(lay, caches):
                 mat_rows = lay.is_pf & ~done
-                nxt, pool, counts = self._packed_forward(
-                    weights, pools[0], lay.ptok, lay.pos, lay.rows,
+                nxt, cache, counts = self._packed_forward(
+                    weights, caches[0], lay.ptok, lay.pos, lay.rows,
                     lay.write_ok, table, lay.last_idx, lay.live, lay.nl,
                     mat_rows, window)
-                return nxt, (pool,), (
+                return nxt, (cache,), (
                     *counts, jnp.sum(lay.live & ~lay.is_pf),
                     jnp.sum(jnp.where(mat_rows, lay.nl, 0)))
 
@@ -354,16 +468,16 @@ class PagedMLADecoder:
                                capacity=table.shape[1] * self.page_size,
                                forward=forward)
 
-        carry = (tokens, lens, done, remaining, pend, pend_n, pool)
+        carry = (tokens, lens, done, remaining, pend, pend_n, cache)
         carry, outs = jax.lax.scan(tick, carry, jnp.arange(k))
         return outs + carry
 
-    def _prefill_packed_step(self, weights, pool, ptok, pos, rows, write_ok,
+    def _prefill_packed_step(self, weights, cache, ptok, pos, rows, write_ok,
                              table, last_idx, live, row_new, *, window):
-        nxt, pool, _ = self._packed_forward(
-            weights, pool, ptok, pos, rows, write_ok, table, last_idx, live,
+        nxt, cache, _ = self._packed_forward(
+            weights, cache, ptok, pos, rows, write_ok, table, last_idx, live,
             row_new, live, window)
-        return nxt, pool
+        return nxt, cache
 
     # ---------------------------------------------------- host-side API
 
@@ -402,14 +516,14 @@ class PagedMLADecoder:
                 self.program_name("packed", k, t, width, window),
                 donate_argnums=(1,))
             self._packeds[key] = fn
-        out = fn(self.weights, self.latent_pages,
+        out = fn(self.weights, self.cache,
                  jnp.asarray(tokens, jnp.int32), jnp.asarray(lens, jnp.int32),
                  table, jnp.asarray(done, bool),
                  jnp.asarray(remaining, jnp.int32),
                  jnp.asarray(-1 if eos is None else int(eos), jnp.int32),
                  jnp.asarray(pend, jnp.int32),
                  jnp.asarray(pend_n, jnp.int32), jnp.asarray(w, jnp.int32))
-        self.latent_pages = out[9]
+        self.cache = out[9]
         return RaggedMultiOut(*out[:9])
 
     def prefill_suffix_batch(self, requests, kids=None, aids=None):
@@ -417,7 +531,14 @@ class PagedMLADecoder:
         for this decoder): requests [(suffix_ids, start, pages), ...], up
         to max_batch of them a dispatch as ONE flat stream bucketed by
         total tokens; every row attends materialised. Returns each
-        request's first generated token."""
+        request's first generated token. A family with a per-slot state
+        has none: a request's row here is its place in `requests`, not
+        its slot, and its state would land in another slot's."""
+        if self._state_layers:
+            raise NotImplementedError(
+                f"PagedMLADecoder over {self.cfg.family} has no packed "
+                "prefill outside the ragged horizon: its per-slot state "
+                "is kept by slot, and this layout's rows are not slots")
         results = [None] * len(requests)
         S, MP, ps = self.max_batch, self.max_pages, self.page_size
         todo = list(enumerate(requests))
@@ -434,8 +555,8 @@ class PagedMLADecoder:
                     f"mla_prefill_packed_t{t}_w{window}",
                     donate_argnums=(1,))
                 self._packed_prefills[t, window] = fn
-            nxt, self.latent_pages = fn(
-                self.weights, self.latent_pages, *map(jnp.asarray, (
+            nxt, self.cache = fn(
+                self.weights, self.cache, *map(jnp.asarray, (
                     lay.ptok, lay.pos, lay.rows, lay.ok, lay.table,
                     lay.last_idx, lay.live, lay.new)))
             nxt = np.asarray(nxt)
@@ -444,11 +565,16 @@ class PagedMLADecoder:
         return results
 
     def copy_page(self, src, dst):
-        """Device-side copy of one page's latent rows, every layer."""
+        """Device-side copy of one page's pool rows, every entry (a
+        per-slot state is no page's and stays)."""
         if self._copy is None:
-            self._copy = _named_jit(
-                lambda pool, s, d: pool.at[:, d].set(pool[:, s]),
-                "mla_copy_page", donate_argnums=(0,))
-        self.latent_pages = self._copy(
-            self.latent_pages, jnp.asarray(int(src), jnp.int32),
+            def copy(cache, s, d):
+                pool = cache[0] if self._state_layers else cache
+                pool = pool.at[:, d].set(pool[:, s])
+                return (pool,) + cache[1:] if self._state_layers else pool
+
+            self._copy = _named_jit(copy, "mla_copy_page",
+                                    donate_argnums=(0,))
+        self.cache = self._copy(
+            self.cache, jnp.asarray(int(src), jnp.int32),
             jnp.asarray(int(dst), jnp.int32))

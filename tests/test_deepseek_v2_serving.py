@@ -529,7 +529,7 @@ def test_latent_pool_layout_and_bytes(plain_decoder):
     token is latent_dim x itemsize a layer. At the published widths in
     bfloat16: (512 + 64) x 2 = 1,152 B a layer."""
     d, cfg = plain_decoder, plain_decoder.cfg
-    assert d.latent_pages.shape == (3, 34, 8, 16 + 4)
+    assert d.cache.shape == (3, 34, 8, 16 + 4)
     assert d.kv_token_bytes == 20 * 4
     assert d.kv_page_bytes == 3 * 8 * 80
     assert d.pend_capacity == 64
@@ -571,7 +571,7 @@ def test_the_decoder_leaves_the_layer_unless_told_to_release_it(release):
 
 def test_copy_page_moves_every_layers_rows(plain_decoder):
     d = plain_decoder
-    d.latent_pages = d.latent_pages.at[:, 3].set(7.0)
+    d.cache = d.cache.at[:, 3].set(7.0)
     d.copy_page(3, 5)
-    assert float(jnp.abs(d.latent_pages[:, 5] - 7.0).max()) == 0.0
+    assert float(jnp.abs(d.cache[:, 5] - 7.0).max()) == 0.0
     assert d.cache_fingerprint() == d.cache_fingerprint()

@@ -413,7 +413,7 @@ def test_the_latent_program_names_its_parts_and_its_kind(mla_decoder):
     assert name == "mla_packed_multi_k2_t16_w8_p8"
     text = jax.jit(lambda *a: dec._packed_multi_step(
         *a, k=2, t=16, window=8)).lower(
-        dec.weights, dec.latent_pages, jnp.zeros(S, jnp.int32),
+        dec.weights, dec.cache, jnp.zeros(S, jnp.int32),
         jnp.zeros(S, jnp.int32), jnp.zeros((S, 8), jnp.int32),
         jnp.zeros(S, bool), jnp.full(S, 2, jnp.int32),
         jnp.asarray(-1, jnp.int32),
@@ -473,7 +473,7 @@ def test_the_double_layer_program_names_its_parts(double_layer_decoder):
     dec, S = double_layer_decoder, 2
     text = jax.jit(lambda *a: dec._packed_multi_step(
         *a, k=2, t=16, window=8)).lower(
-        dec.weights, dec.latent_pages, jnp.zeros(S, jnp.int32),
+        dec.weights, dec.cache, jnp.zeros(S, jnp.int32),
         jnp.zeros(S, jnp.int32), jnp.zeros((S, 8), jnp.int32),
         jnp.zeros(S, bool), jnp.full(S, 2, jnp.int32),
         jnp.asarray(-1, jnp.int32),
@@ -486,6 +486,65 @@ def test_the_double_layer_program_names_its_parts(double_layer_decoder):
                   "shortcut_join", "lm_head"):
         assert scope in text, scope
     assert "moe_shared" not in text
+
+
+@pytest.fixture(scope="module")
+def conv_attention_decoder():
+    from paddle_tpu.models.lfm2_moe import Lfm2Moe, lfm2_moe_tiny
+    from paddle_tpu.serving.mla_decoder import PagedMLADecoder
+    model = Lfm2Moe(lfm2_moe_tiny(experts_held=4, expert_offset=2))
+    return PagedMLADecoder(model, num_pages=2 * 8 + 2, page_size=8,
+                           max_batch=2, max_pages_per_seq=8)
+
+
+def test_the_conv_attention_familys_record_adds_its_four_counters(
+        conv_attention_decoder):
+    """The same decoder over the LFM2-MoE family (a conv dense layer, then
+    attention and conv expert layers): the record's fields and the four
+    counters, the held experts' under the names the latent families use,
+    and nothing else. Every real token selects 3 experts in each of 4
+    expert layers; of them the 4 held (2-5) take at most all."""
+    dec = conv_attention_decoder
+    eng = ContinuousBatchingEngine(dec, max_new_tokens=4, chunk_tokens=8)
+    for p in PROMPTS:
+        eng.submit(p)
+    eng.run()
+    hz = eng.serve_schedule()
+    assert dec.horizon_counters == (
+        "expert_assignments", "experts_hit", "absorbed_rows",
+        "materialised_tokens")
+    for ev in hz:
+        assert set(ev) == FIELDS | set(dec.horizon_counters)
+        assert ev["program"].startswith("mla_packed_multi_k")
+        assert all(isinstance(ev[c], int) and ev[c] >= 0
+                   for c in dec.horizon_counters)
+        real = ev["tokens_dispatched"] - ev["tokens_padded"]
+        assert ev["expert_assignments"] <= real * 3 * 4
+        assert ev["experts_hit"] <= 4 * 4 * ev["k"]
+    assert sum(ev["expert_assignments"] for ev in hz) > 0
+    assert sum(ev["materialised_tokens"] for ev in hz) == \
+        sum(map(len, PROMPTS))
+    assert sum(ev["absorbed_rows"] for ev in hz) == 3 * len(PROMPTS)
+
+
+def test_the_conv_attention_program_names_its_parts(conv_attention_decoder):
+    import jax.numpy as jnp
+    dec, S = conv_attention_decoder, 2
+    text = jax.jit(lambda *a: dec._packed_multi_step(
+        *a, k=2, t=16, window=8)).lower(
+        dec.weights, dec.cache, jnp.zeros(S, jnp.int32),
+        jnp.zeros(S, jnp.int32), jnp.zeros((S, 8), jnp.int32),
+        jnp.zeros(S, bool), jnp.full(S, 2, jnp.int32),
+        jnp.asarray(-1, jnp.int32),
+        jnp.zeros((S, dec.pend_capacity), jnp.int32),
+        jnp.zeros(S, jnp.int32), jnp.asarray(8, jnp.int32)).as_text(
+        debug_info=True)
+    for scope in ("layers", "short_conv", "conv_state", "gqa_attention",
+                  "qk_norm", "kv_write", "paged_gather", "paged_attention",
+                  "mlp", "moe_router", "moe_experts", "lm_head"):
+        assert scope in text, scope
+    for scope in ("mla_q", "latent_write", "mla_absorbed", "moe_shared"):
+        assert scope not in text, scope
 
 
 def _lowered_step(model, loss_fn, batch):

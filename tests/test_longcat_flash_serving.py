@@ -284,7 +284,7 @@ def test_the_pool_has_an_entry_for_each_attention():
     prompt of 13 tokens every one of the four entries holds 13 written
     rows at the request's pages, and no two entries hold the same."""
     dec = _decoder()
-    assert dec.latent_pages.shape == (4, 34, 8, 16 + 4)
+    assert dec.cache.shape == (4, 34, 8, 16 + 4)
     assert dec.kv_token_bytes == 20 * 4
     assert dec.kv_token_bytes_by_layer() == [160, 160]
     assert dec.kv_page_bytes == 2 * 2 * 8 * 80
@@ -293,7 +293,7 @@ def test_the_pool_has_an_entry_for_each_attention():
     eng = ContinuousBatchingEngine(dec, max_new_tokens=1, chunk_tokens=16)
     eng.submit(_prompts((13,))[0])
     eng.run()
-    pool = np.asarray(dec.latent_pages)[:, :-1]     # the last page is scrap
+    pool = np.asarray(dec.cache)[:, :-1]     # the last page is scrap
     written = np.abs(pool).max(-1) > 0              # [entries, pages, ps]
     assert (written.sum((1, 2)) == 13).all()
     assert (written == written[0]).all()
@@ -313,8 +313,8 @@ def test_an_attention_that_reads_its_twins_entry_is_not_the_reference():
 
     def swap(e):
         if not swapped and all(len(v) >= 2 for v in e._outputs.values()):
-            p = dec.latent_pages
-            dec.latent_pages = p.at[jnp.asarray([0, 1])].set(
+            p = dec.cache
+            dec.cache = p.at[jnp.asarray([0, 1])].set(
                 p[jnp.asarray([1, 0])])
             swapped.append(True)
 
@@ -471,9 +471,10 @@ def test_speculation_is_refused(plain_decoder):
 
 
 def test_the_decoder_walks_the_familys_table_and_knows_no_model():
-    """DeepSeek-V2 is the first entry and LongCat-Flash the second; a
-    config of a family the table lacks is refused by name."""
-    assert list(FAMILIES) == ["deepseek_v2", "longcat_flash"]
+    """DeepSeek-V2 is the first entry, LongCat-Flash the second and
+    LFM2-MoE the third; a config of a family the table lacks is refused
+    by name."""
+    assert list(FAMILIES) == ["deepseek_v2", "longcat_flash", "lfm2_moe"]
     assert FAMILIES["deepseek_v2"].cache_entries == 1
     assert FAMILIES["longcat_flash"].cache_entries == 2
     model = family.build_model(TINY, SEED, {})

@@ -697,3 +697,78 @@ def test_reference_reads_the_layer_through_the_gather(pool, entry):
               if e.primitive.name in ("dynamic_slice", "slice", "squeeze")
               and e.invars[0].aval.shape in leaf_shapes]
     assert not sliced, sliced
+
+
+# ------------------------------------------- the grouped (GQA) packed walk
+
+def _grouped_case(seed, H, Hk, D=8, P=12, ps=4, MP=6, L=2):
+    """A whole [L, P, ps, 2 x Hk x D] grouped pool (keys, then values), its
+    keys and values split out, a table, and the late joiners' stream: two
+    decode rows and two chunk rows of unequal length."""
+    rng = np.random.RandomState(seed)
+    kv = jnp.asarray(rng.randn(L, P, ps, 2 * Hk * D).astype(np.float32))
+    k = kv[..., :Hk * D].reshape(L, P, ps, Hk, D)
+    v = kv[..., Hk * D:].reshape(L, P, ps, Hk, D)
+    table = jnp.asarray(rng.permutation(P)[:4 * MP].reshape(4, MP)
+                        if 4 * MP <= P else
+                        rng.randint(0, P, (4, MP)).astype(np.int32),
+                        jnp.int32)
+    rows, pos = _pack([(0, 9, 1), (1, 0, 7), (2, 14, 1), (3, 5, 6)])
+    q = jnp.asarray(rng.randn(len(rows), H, D).astype(np.float32))
+    return kv, k, v, table, rows, pos, q
+
+
+@pytest.mark.parametrize("H,Hk", [(4, 2), (8, 2), (4, 1)])
+def test_grouped_walk_matches_the_oracle(H, Hk):
+    """Query head h over key/value head h // (H / Hk) of one grouped pool
+    entry, against the direct-softmax oracle over keys and values repeated
+    to every query head; a grouping h % Hk is not what it computes."""
+    kv, k, v, table, rows, pos, q = _grouped_case(7 + H + Hk, H, Hk)
+    ours, swapped = np.arange(H) // (H // Hk), np.arange(H) % Hk
+    got = np.asarray(ragged_paged_attention_packed(
+        q, kv, None, table, rows, pos, window=8, layer=jnp.int32(1)))
+    for r in range(4):
+        sel = np.asarray(rows) == r
+        start = int(np.asarray(pos)[sel][0])
+        for grouping in (ours, swapped):
+            want = _oracle(np.asarray(q)[sel][None], k[1][..., grouping, :],
+                           v[1][..., grouping, :], table[r:r + 1],
+                           np.asarray([start]))[0]
+            close = np.allclose(got[sel], want, atol=2e-5)
+            # one key/value head: both groupings are the same
+            assert close == (grouping is ours
+                             or np.array_equal(ours, swapped)), (r, grouping)
+
+
+def test_grouped_walk_at_equal_heads_is_the_split_walk_bit_for_bit():
+    """With as many key/value heads as query heads, the grouped form (one
+    pool, keys then values) gives the bytes of the existing walk over the
+    two split pools: the fold is the identity and the block's keys and
+    values are the same numbers."""
+    H = 2
+    kv, k, v, table, rows, pos, q = _grouped_case(3, H, H)
+    for window in (None, 8):
+        grouped = ragged_paged_attention_packed(
+            q, kv, None, table, rows, pos, window=window, layer=jnp.int32(0))
+        split = ragged_paged_attention_packed(
+            q, k, v, table, rows, pos, window=window, layer=jnp.int32(0))
+        assert np.array_equal(np.asarray(grouped), np.asarray(split))
+
+
+def test_grouped_walk_is_one_walk_for_every_group():
+    """The G query heads of a key/value head ride one walk: each step
+    gathers the block once, [n, block, 2 x Hk x D] (no key repeated to
+    the query heads), and its product has G x W rows."""
+    H, Hk, W = 4, 2, 8
+    kv, _, _, table, rows, pos, q = _grouped_case(5, H, Hk)
+    jaxpr = jax.make_jaxpr(lambda li: ragged_paged_attention_packed(
+        q, kv, None, table, rows, pos, window=W, layer=li))(
+        jnp.int32(0)).jaxpr
+    eqns = [e for j in _sub_jaxprs(jaxpr) for e in j.eqns]
+    gathers = [e.outvars[0].aval.shape for e in eqns
+               if e.primitive.name == "gather"
+               and e.outvars[0].aval.shape[-1] == kv.shape[-1]]
+    assert gathers and all(s[-1] == 2 * Hk * 8 for s in gathers)
+    dots = [e.outvars[0].aval.shape for e in eqns
+            if e.primitive.name == "dot_general"]
+    assert (4, Hk, (H // Hk) * W, KEY_BLOCK_PAGES * 4) in dots, dots
