@@ -708,15 +708,17 @@ def mla_paged_attention_packed(q_nope, q_rope, latent_pages, layer, w_kvb,
     token stream, ROW-CONTIGUOUS as in `ragged_paged_attention_packed`
     (rows in increasing order; `row_new[r]` tokens of row r from its
     first stream slot on, at most `window`). latent_pages
-    [L, P, page_size, rank + dr] with `layer` the layer to read (the
-    new tokens' rows already written); w_kvb [rank, H, dn + dv];
+    [L, P, page_size, rank + dr or more] with `layer` the layer to read
+    (the new tokens' rows already written; what follows a row's rank +
+    dr values is padding and is not read); w_kvb [rank, H, dn + dv];
     page_table [n, max_pages]; pos [T] absolute positions. Returns
     [T, H, dv]; a token past its row's `row_new` gets garbage."""
     T, H, dn = q_nope.shape
     dr = q_rope.shape[-1]
     n, MP = page_table.shape
-    ps, C = latent_pages.shape[-2:]
-    r = C - dr
+    ps = latent_pages.shape[-2]
+    r = w_kvb.shape[0]
+    C = r + dr
     dv = w_kvb.shape[-1] - dn
     ctx = MP * ps
     dt = q_nope.dtype
@@ -726,7 +728,7 @@ def mla_paged_attention_packed(q_nope, q_rope, latent_pages, layer, w_kvb,
     with jax.named_scope("paged_gather"):
         # ONE copy of each row's latent pages, shared by both forms
         lat = latent_pages[layer, jnp.maximum(page_table, 0)].reshape(
-            n, ctx, C)
+            n, ctx, -1)[..., :C]
     first = jnp.argmax(row_ids[None, :] == jnp.arange(n)[:, None],
                        axis=1).astype(jnp.int32)               # [n]
     kpos = jnp.arange(ctx)

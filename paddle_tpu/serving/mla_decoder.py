@@ -11,7 +11,9 @@ What differs from the GPT decoder, by mechanism:
   (1,152 B a token in bfloat16 where DeepSeek-V2's full keys and values
   would be 81,920 B); LFM2 has one entry an attention layer, its
   grouped keys and then values (2 x 8 x 64), and none a conv layer.
-  Entry `e` of layer `l` sits at the layer's first entry plus `e`.
+  Entry `e` of layer `l` sits at the layer's first entry plus `e`. A
+  latent entry is stored in whole lanes (`LANES`: 576 values in 640),
+  so the pool's default device layout is the layer loop's row-major one.
 * The family's attention over its entries (`attention`): "mla" in two
   forms over one pool, chosen BY ROW KIND and never by a knob (a row
   that takes prompt chunks attends through MATERIALISED heads, a row
@@ -101,6 +103,13 @@ FAMILIES = {"deepseek_v2": deepseek_v2.Serving,
 
 _stack = jax.jit(lambda *arrays: jnp.stack(arrays))
 
+# A latent entry is stored padded to whole lanes of the chip's vector unit.
+# The device's default layout of a pool whose entry is lane-aligned is
+# row-major, the layout the layer loop reads and writes it in; a 576-wide
+# entry's default puts the pages minor-most, and every program then copied
+# the whole pool into the loop's layout and back out of it.
+LANES = 128
+
 
 def latent_token_bytes(cfg, itemsize=2):
     """Cache bytes ONE token costs in one attention: the latent row."""
@@ -114,7 +123,8 @@ def _mla_attend(dec, lay, pool, entry, y, w):
     q_nope, q_rope, latent = mla_project(w, y, lay.pos, dec.cfg,
                                          dec.inv_freq)
     with jax.named_scope("latent_write"):
-        pool = pool.at[entry, lay.pids, lay.offs].set(latent)
+        row = jnp.pad(latent, ((0, 0), (0, pool.shape[-1] - latent.shape[-1])))
+        pool = pool.at[entry, lay.pids, lay.offs].set(row)
     attn = mla_paged_attention_packed(
         q_nope, q_rope, pool, entry, w["kv_b"], lay.table, lay.rows,
         lay.pos, lay.row_new, lay.mat_rows, softmax_scale(dec.cfg),
@@ -226,8 +236,13 @@ class PagedMLADecoder:
         holder = {layer: i for i, layer in enumerate(self._state_layers)}
         self._index = [int(firsts[i]) if entries[i] else holder.get(i, 0)
                        for i in range(cfg.num_layers)]
-        pool = jnp.zeros((sum(entries), num_pages, page_size,
-                          fam.entry_width(cfg)), dt)
+        # a latent entry in whole lanes (`LANES`); the grouped walk reads
+        # its entry whole, and LFM2's 1,024 values are whole lanes. What a
+        # token costs (`kv_token_bytes`, the prices) counts its own values.
+        width = fam.entry_width(cfg)
+        if fam.attention == "mla":
+            width = -(-width // LANES) * LANES
+        pool = jnp.zeros((sum(entries), num_pages, page_size, width), dt)
         # what every program carries and returns: the pool, with the
         # per-slot state beside it where the family keeps one
         self.cache = pool if not self._state_layers else (pool, jnp.zeros(

@@ -14,6 +14,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
@@ -168,7 +169,6 @@ def compile_train_step(topo, layers, batch=B, seq=S, **axes):
     at the described ones for the trace, every argument keeping the
     PartitionSpec the trainer gave it."""
     import chip_smoke
-    import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec
 
     import paddle_tpu as paddle
@@ -321,3 +321,137 @@ def test_largest_serve_horizon_24_layers(one_chip):
     others = (ON_THE_CHIP - 1) * \
         compiled.memory_analysis().generated_code_size_in_bytes
     assert device_bytes(compiled) + others < V5E_HBM
+
+
+# ---------------------------------------------------------- the latent pool
+
+# the two MLA cells' pools, [entries, slots x table width + 2, 16, 576], and
+# the decode horizon each dispatches: (layers, slots, table width, K)
+MLA_CELLS = {
+    "deepseek_v2": ((6, 4226, 16, 576), 6, 16, 264, 2),
+    "longcat_flash": ((8, 4610, 16, 576), 4, 32, 144, 1),
+}
+
+
+def _mla_model(family, **kw):
+    """A tiny preset of one family of `mla_decoder.FAMILIES`, built on the
+    CPU."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import deepseek_v2, lfm2_moe, longcat_flash
+
+    made = {"deepseek_v2": (deepseek_v2.DeepSeekV2,
+                            deepseek_v2.deepseek_v2_tiny),
+            "longcat_flash": (longcat_flash.LongCatFlash,
+                              longcat_flash.longcat_flash_tiny),
+            "lfm2_moe": (lfm2_moe.Lfm2Moe, lfm2_moe.lfm2_moe_tiny)}
+    model, preset = made[family]
+    paddle.seed(0)
+    m = model(preset(**kw))
+    m.eval()
+    return m
+
+
+def _pool_of(cache):
+    return cache[0] if isinstance(cache, tuple) else cache
+
+
+@pytest.fixture
+def no_mesh():
+    """The decoder serves on one chip and refuses a tp mesh: the one the
+    sharded train step leaves set is not these tests'."""
+    from paddle_tpu.distributed import mesh as mesh_mod
+
+    held = mesh_mod.get_mesh(create_default=False)
+    mesh_mod.set_mesh(None)
+    yield
+    mesh_mod.set_mesh(held)
+
+
+@pytest.mark.parametrize("family", sorted(MLA_CELLS))
+def test_mla_decode_horizon_keeps_the_pool_layout(one_chip, as_tpu, no_mesh,
+                                                  family):
+    """Each MLA cell's decode horizon at its pool's real shape, the latent
+    576 wide (512 + 64) and every other width tiny: the pool's entry is
+    stored in whole lanes (640), so its default layout is row-major, the
+    layer loop's own; it goes in and comes out so, and nothing copies it.
+    A 576-wide pool defaults to pages minor-most, and the horizon converted
+    the whole pool into the loop's layout and back, two copies an
+    execution."""
+    import functools
+
+    from paddle_tpu.serving.mla_decoder import PagedMLADecoder
+    from tests._hlo_pool import pool_moves
+
+    shape, layers, slots, width, k = MLA_CELLS[family]
+    d = PagedMLADecoder(
+        _mla_model(family, kv_lora_rank=512, qk_rope_head_dim=64,
+                   num_layers=layers, dtype="bfloat16",
+                   max_seq_len=width * 16),
+        num_pages=18, page_size=16, max_batch=slots,
+        max_pages_per_seq=width)
+    assert d.kv_token_bytes == 576 * 2
+    stored = (shape[0], shape[1], shape[2], 640)
+    assert (d.cache.shape[0], d.cache.shape[3]) == (stored[0], stored[3])
+    # the cell's pool, described on the chip and not made here
+    d.num_pages = shape[1]
+    pool = spec(one_chip, stored, jnp.bfloat16)
+
+    def i32(*s):
+        return spec(one_chip, s, jnp.int32)
+
+    compiled = jax.jit(
+        functools.partial(d._packed_multi_step, k=k, t=slots, window=1),
+        donate_argnums=(1,),
+    ).lower(
+        jax.tree_util.tree_map(lambda v: spec(one_chip, v.shape, v.dtype),
+                               d.weights),
+        pool, i32(slots), i32(slots), i32(slots, width),
+        spec(one_chip, (slots,), jnp.bool_), i32(slots), i32(),
+        i32(slots, d.pend_capacity), i32(slots), i32()).compile()
+    assert pool_moves(compiled, pool) == []
+    row_major = (0, 1, 2, 3)
+    assert compiled.input_formats[0][1].layout.major_to_minor == row_major
+    assert compiled.output_formats[-1].layout.major_to_minor == row_major
+
+
+@pytest.mark.parametrize("family", ["deepseek_v2", "longcat_flash",
+                                    "lfm2_moe"])
+def test_every_mla_program_keeps_the_pool_format(no_mesh, family):
+    """On the CPU, where the pool's layout is the default: the horizon, the
+    packed prefill (a family without a per-slot state) and the page copy
+    each take and return the pool in the format it was made in, and it
+    leaves each of them so."""
+    from paddle_tpu.serving.mla_decoder import PagedMLADecoder
+
+    d = PagedMLADecoder(_mla_model(family), num_pages=18, page_size=8,
+                        max_batch=2, max_pages_per_seq=8)
+    made = _pool_of(d.cache).format
+    assert made.layout.major_to_minor == (0, 1, 2, 3)
+    S, MP = d.max_batch, d.max_pages
+
+    def i32(*s):
+        return jax.ShapeDtypeStruct(s, jnp.int32)
+
+    def flags(n):
+        return jax.ShapeDtypeStruct((n,), jnp.bool_)
+
+    d.ragged_multi(np.zeros(S), np.zeros(S), np.zeros((S, MP)), 1, 1,
+                   np.zeros((S, d.pend_capacity)), np.zeros(S))
+    if not d._state_layers:
+        d.prefill_suffix_batch([([1, 2, 3], 0, [0])])
+    d.copy_page(1, 2)
+    assert _pool_of(d.cache).format == made
+    built = [(fn, (i32(S), i32(S), i32(S, width), flags(S), i32(S), i32(),
+                   i32(S, d.pend_capacity), i32(S), i32()))
+             for (_, _, _, width), fn in d._packeds.items()]
+    built += [(fn, (i32(t), i32(t), i32(t), flags(t), i32(S, MP), i32(S),
+                    flags(S), i32(S)))
+              for (t, _), fn in d._packed_prefills.items()]
+    assert len(built) == 1 + (not d._state_layers)
+    for fn, rest in built:
+        c = fn.lower(d.weights, d.cache, *rest).compile()
+        assert _pool_of(c.input_formats[0][1]) == made
+        assert _pool_of(c.output_formats[-1]) == made
+    c = d._copy.lower(d.cache, i32(), i32()).compile()
+    assert _pool_of(c.input_formats[0][0]) == made
+    assert _pool_of(c.output_formats) == made

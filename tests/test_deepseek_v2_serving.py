@@ -525,11 +525,12 @@ def test_no_adapters_and_no_other_program(plain_decoder):
 
 # ------------------------------------------------- the pool's arithmetic
 def test_latent_pool_layout_and_bytes(plain_decoder):
-    """[layers, pages, page_size, rank + rope] in the compute dtype; a
-    token is latent_dim x itemsize a layer. At the published widths in
-    bfloat16: (512 + 64) x 2 = 1,152 B a layer."""
+    """[layers, pages, page_size, rank + rope in whole lanes of 128] in
+    the compute dtype; a token is latent_dim x itemsize a layer, the
+    lanes' padding not counted. At the published widths in bfloat16:
+    (512 + 64) x 2 = 1,152 B a layer, stored in 640 values."""
     d, cfg = plain_decoder, plain_decoder.cfg
-    assert d.cache.shape == (3, 34, 8, 16 + 4)
+    assert d.cache.shape == (3, 34, 8, 128)     # 16 + 4 values in a lane
     assert d.kv_token_bytes == 20 * 4
     assert d.kv_page_bytes == 3 * 8 * 80
     assert d.pend_capacity == 64

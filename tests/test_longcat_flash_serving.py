@@ -284,7 +284,7 @@ def test_the_pool_has_an_entry_for_each_attention():
     prompt of 13 tokens every one of the four entries holds 13 written
     rows at the request's pages, and no two entries hold the same."""
     dec = _decoder()
-    assert dec.cache.shape == (4, 34, 8, 16 + 4)
+    assert dec.cache.shape == (4, 34, 8, 128)     # 16 + 4 values in a lane
     assert dec.kv_token_bytes == 20 * 4
     assert dec.kv_token_bytes_by_layer() == [160, 160]
     assert dec.kv_page_bytes == 2 * 2 * 8 * 80
