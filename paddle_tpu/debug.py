@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from .framework.core import Tensor, apply_op
+from .profiler import compile_log
 
 __all__ = ["check_numerics", "enable_check_nan_inf", "check_nan_inf_enabled",
            "assert_finite_pytree", "TensorCheckerConfig", "diagnose",
@@ -39,17 +40,18 @@ def _horizon_summary(events):
     """(programs, schedule entries, pad ledger) from an engine's horizon
     records (`serve_schedule()`, always on: no recorder needed). Per
     `program` (the name its compiled program carries in a trace) the
-    count of horizons, how many were a first use (a compile or a cache
-    load inside) and the median tick — sync to sync: from the later of
-    the previous horizon's `t_fetched` and its own `t_round` to its own
-    `t_fetched`, over its k ticks. For the schedule: the median round of
-    host work (`admit_s + plan_s + dispatch_s + book_s`), the deepest
-    queue an admission pass left behind and the median submit-to-admit
-    wait. Which program is slow, how much host work a round hides behind
-    the device, whether requests queue: what a device trace alone cannot
-    answer. The pad ledger is the recent-horizon view of
-    `stats.pad_fraction`: how much of the dispatched token layout was
-    padding."""
+    count of horizons, the seconds the process spent compiling it or
+    loading it from the persistent cache (`profiler.compile_log`, every
+    signature under that name) and the median tick — sync to sync: from
+    the later of the previous horizon's `t_fetched` and its own
+    `t_round` to its own `t_fetched`, over its k ticks. For the
+    schedule: the median round of host work (`admit_s + plan_s +
+    dispatch_s + book_s`), the deepest queue an admission pass left
+    behind and the median submit-to-admit wait. Which program is slow,
+    how much host work a round hides behind the device, whether
+    requests queue: what a device trace alone cannot answer. The pad
+    ledger is the recent-horizon view of `stats.pad_fraction`: how much
+    of the dispatched token layout was padding."""
     by_program, host, depth, waits = {}, [], [], []
     disp = padded = 0
     prev_fetched = None
@@ -59,19 +61,23 @@ def _horizon_summary(events):
         start = ev["t_round"] if prev_fetched is None \
             else max(prev_fetched, ev["t_round"])
         prev_fetched = ev["t_fetched"]
-        ticks, first = by_program.setdefault(ev["program"], ([], []))
-        ticks.append((ev["t_fetched"] - start) / ev["k"])
-        first.append(bool(ev["first_use"]))
+        by_program.setdefault(ev["program"], []).append(
+            (ev["t_fetched"] - start) / ev["k"])
         host.append(ev["admit_s"] + ev["plan_s"] + ev["dispatch_s"]
                     + ev["book_s"])
         depth.append(ev["queue_depth"])
         waits.extend(ev["admit_waits_s"])
         disp += ev["tokens_dispatched"]
         padded += ev.get("tokens_padded") or 0
+    made = {}
+    for e in compile_log()[0]:
+        if e["phase"] in ("compile", "load"):
+            made[e["program"]] = made.get(e["program"], 0.0) \
+                + e["end"] - e["start"]
     programs = [{"program": name, "n": len(ticks),
-                 "first_uses": sum(first),
+                 "compile_load_s": made.get(name, 0.0),
                  "tick_ms_p50": 1e3 * statistics.median(ticks)}
-                for name, (ticks, first) in sorted(by_program.items())]
+                for name, ticks in sorted(by_program.items())]
     schedule = {}
     if host:
         schedule = {"round_host_ms_p50": 1e3 * statistics.median(host),
@@ -144,8 +150,8 @@ def serving_report(drift_factor=None, print_report=False):
                     print(f"  {key}: {entry[key]}")
             for pr in entry.get("programs", ()):
                 print(f"  program {pr['program']}: n={pr['n']} "
-                      f"(first uses {pr['first_uses']}) tick p50 "
-                      f"{pr['tick_ms_p50']:.3f} ms")
+                      f"(compile/load {pr['compile_load_s']:.3f} s) "
+                      f"tick p50 {pr['tick_ms_p50']:.3f} ms")
             for d in entry.get("drift", ()):
                 flag = "  << DRIFTING" if d["drifting"] else ""
                 print(f"  drift {d['shape']}: predicted "

@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..framework.core import Tensor
 from ..nn.layer_base import functional_call, load_state_pytree
-from ..profiler import span
+from ..profiler import _compiles_since, build_span, span
 from .mesh import get_mesh
 from .sharding_utils import plan_shardings
 
@@ -180,6 +180,7 @@ class Trainer:
         loss = trainer.step(batch)                      # batch: dict of arrays
     """
 
+    @build_span("trainer.build")
     def __init__(self, model, optimizer, loss_fn, mesh=None, donate=True,
                  grad_accum_steps=1, grad_transform=None,
                  batch_spec=("dp", "fsdp"), dp_overlap="off",
@@ -663,11 +664,6 @@ class Trainer:
                     for _ in range(int(n)):
                         sched.step()
             t0 = time.perf_counter() if self.recorder is not None else None
-            # a signature never dispatched before will compile inside this
-            # window — a pollution source the drift ledger must skip, like
-            # the first horizon (the memo is the compile's proxy: first
-            # call per signature pays the XLA compile)
-            warm_sig = sig in self._placed_multis
             fn = self._placed_multi(sig, horizon_sh)
             with span("trainer.dispatch", step=step):
                 (self.params, self.opt_state, self.gt_state, self.consts,
@@ -678,10 +674,10 @@ class Trainer:
             # the TRUE device step count, not the host dispatch count
             self._host_step += int(n)
             if self.recorder is not None:
-                self._record_tick(("train", int(n)), int(n), warm_sig, t0)
+                self._record_tick(("train", int(n)), int(n), t0)
             return losses
 
-    def _record_tick(self, shape, n, warm_sig, t0):
+    def _record_tick(self, shape, n, t0):
         """One dispatched horizon of `n` steps into the recorder.
         Called only with a recorder attached.
 
@@ -692,9 +688,12 @@ class Trainer:
         mark_recorder_idle() has no previous dispatch — its call wall
         (from `t0`) is recorded but kept out of the drift ledger (cold
         compiles and host pauses are pollution, the same exclusion the
-        serving engines apply to prefill windows)."""
+        serving engines apply to prefill windows), and so is a horizon
+        whose call lowered, compiled or loaded a program (the compile
+        log's entries since `t0`: a new signature, whichever memo holds
+        it)."""
         now = time.perf_counter()
-        steady = self._rec_last_t is not None and warm_sig
+        steady = self._rec_last_t is not None and not _compiles_since(t0)
         # the tick's chrome slice must span the window it measured:
         # steady ticks start at the PREVIOUS dispatch, not this one
         start = self._rec_last_t if self._rec_last_t is not None else t0
@@ -786,7 +785,6 @@ class Trainer:
             lr = self.optimizer.get_lr() if lr is None else lr
             with span("trainer.place_batch", step=step):
                 batch, sig, batch_sh = self.place_batch(batch)
-            warm_sig = sig in self._placed_steps
             step_fn = self._placed_step(sig, batch_sh)
             with span("trainer.dispatch", step=step):
                 (self.params, self.opt_state, self.gt_state, self.consts,
@@ -798,7 +796,7 @@ class Trainer:
                 sched.step()
             self._host_step += 1
             if self.recorder is not None:
-                self._record_tick(("step", 1), 1, warm_sig, t0)
+                self._record_tick(("step", 1), 1, t0)
             return loss
 
     def sync_to_model(self):

@@ -12,9 +12,14 @@ Three measurement layers, all real:
 - summary()/export(): aggregated statistics table / chrome-trace JSON;
 - `span(name, **ids)`: the program's own regions on the host's timeline of
   the JAX profiler's trace, live exactly when a profiler session is;
-  `read_spans(log_dir)` reads them back from the session's `.xplane.pb`.
+  `read_spans(log_dir)` reads them back from the session's `.xplane.pb`;
+- `compile_log()`: every program's tracing, lowering and compile (or load
+  from the persistent cache), and the constructors that build a decoder,
+  an engine or a trainer, on the host's clock, always on.
 """
+import collections
 import contextlib
+import functools
 import json
 import os
 import threading
@@ -23,7 +28,7 @@ import time
 import jax
 
 __all__ = ["Profiler", "ProfilerTarget", "RecordEvent", "span",
-           "read_spans", "profiler_guard",
+           "read_spans", "compile_log", "profiler_guard",
            "export_chrome_tracing", "make_scheduler", "ProfilerState",
            "SortedKeys", "export_protobuf", "load_profiler_result"]
 
@@ -341,6 +346,124 @@ def read_spans(log_dir, prefixes=("engine.", "trainer.")):
                         ev.stats, name=ev.name, start_ns=ev.start_ns,
                         end_ns=ev.start_ns + ev.duration_ns))
     return sorted(spans, key=lambda s: (s["start_ns"], -s["end_ns"]))
+
+
+# ------------------------------------------------------------ compile log
+
+# JAX's events for the three phases of making a program; the backend's
+# phase is a "load" where the persistent cache answered inside it
+_PHASE_OF = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+             "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+             "/jax/core/compile/backend_compile_duration": "compile"}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+# what a window that must not compile counts (the harness's two events)
+_COMPILING = ("lower", "compile", "load")
+# a cell's set-up, nested traces included, holds a few thousand entries
+_LOG_SIZE = 1 << 16
+_log = collections.deque(maxlen=_LOG_SIZE)   # (program, phase, start, end)
+_log_lock = threading.Lock()
+_dropped = 0
+_cache_hit = threading.local()
+
+
+def _program(fun_name):
+    """One program, one name: JAX calls a jitted `f` "f" while it traces
+    it and "jit(f)" while it lowers and compiles it."""
+    head, paren, inner = fun_name.partition("(")
+    if paren and head in ("jit", "pmap") and inner.endswith(")"):
+        return inner[:-1]
+    return fun_name
+
+
+def _note(program, phase, seconds):
+    """Append one entry that ends now, on `time.perf_counter()`'s clock
+    (JAX times its phases on `time.time()`: only the length is taken)."""
+    global _dropped
+    with _log_lock:
+        end = time.perf_counter()
+        if len(_log) == _LOG_SIZE:
+            _dropped += 1
+        _log.append((program, phase, end - seconds, end))
+
+
+def _on_duration(event, seconds, fun_name="", **_):
+    phase = _PHASE_OF.get(event)
+    if phase is None:
+        return
+    if phase == "compile":
+        if getattr(_cache_hit, "seen", False):
+            phase = "load"
+        _cache_hit.seen = False
+    _note(_program(str(fun_name)), phase, seconds)
+
+
+def _on_event(event, **_):
+    if event == _CACHE_HIT:
+        _cache_hit.seen = True
+
+
+# once a process, at import: `import paddle_tpu` brings this module in
+# before any of its programs compiles. JAX calls these only while it makes
+# a program; a dispatch of a program already made calls neither.
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
+
+
+def compile_log(since=None):
+    """(entries, dropped): what the process has spent making programs and
+    building what runs them, oldest first, on `time.perf_counter()`'s
+    clock (the engine's record's and a benchmark's `setup_s`'s).
+
+    An entry is {"program", "phase", "start", "end"}. `phase` is "trace"
+    (Python to a jaxpr), "lower" (jaxpr to an MLIR module), "compile"
+    (XLA made the executable) or "load" (the persistent compilation cache
+    held it), with `program` the jitted function's name (a serving
+    program's key: `packed_multi_k2_t8_w1_p64`); or "build", with
+    `program` the class whose constructor ran inside a `decoder.build`,
+    `engine.build` or `trainer.build` span. A trace nests the traces of
+    the jitted functions it calls, and a build the programs it makes.
+
+    `since` keeps the entries that ended at or after it. The log holds
+    the newest 65,536 entries; `dropped` counts the older ones it let go
+    that may have ended at or after `since` (0 where the log reaches back
+    before it), so a reading that would come out short can be refused."""
+    out = []
+    with _log_lock:
+        dropped = _dropped
+        for program, phase, start, end in reversed(_log):
+            if since is not None and end < since:
+                dropped = 0
+                break
+            out.append({"program": program, "phase": phase,
+                        "start": start, "end": end})
+    out.reverse()
+    return out, dropped
+
+
+def _compiles_since(t):
+    """How many lowerings, compiles and cache loads ended at or after `t`:
+    what a measured window that must not compile counts. A round that
+    made nothing (the log's newest entry ended before `t`) pays one look
+    at it."""
+    if not _log or _log[-1][3] < t:
+        return 0
+    return sum(e["phase"] in _COMPILING for e in compile_log(since=t)[0])
+
+
+def build_span(name):
+    """Decorator of the constructor of what serves or trains: each
+    construction is a `span(name, program=<class>)` on the profiler's
+    timeline and a "build" entry of the compile log."""
+    def wrap(init):
+        @functools.wraps(init)
+        def built(self, *args, **kwargs):
+            program = type(self).__name__
+            t0 = time.perf_counter()
+            with span(name, program=program):
+                init(self, *args, **kwargs)
+            _note(program, "build", time.perf_counter() - t0)
+        return built
+    return wrap
 
 
 class RecordEvent:

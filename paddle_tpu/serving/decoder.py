@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.ragged_paged_attention import KEY_BLOCK_PAGES
+from ..profiler import build_span
 
 __all__ = ["PagedGPTDecoder", "MultiDecodeOut", "RaggedMultiOut",
            "_spec_accept", "_sample_tokens", "_ln", "_mm", "_mm_heads",
@@ -38,7 +39,6 @@ def clear_compiled_memos():
             if getattr(dec, attr) is not None:
                 n += 1
                 setattr(dec, attr, None)
-        dec._used.clear()      # what recompiles is a first use again
     return n
 
 
@@ -497,6 +497,7 @@ class PagedGPTDecoder:
     # the whole table it is handed (the latent decoder's absorbed form)
     walk_block_pages = KEY_BLOCK_PAGES
 
+    @build_span("decoder.build")
     def __init__(self, model, num_pages=128, page_size=16, max_batch=8,
                  max_pages_per_seq=None, quant=None, kv_quant=None,
                  use_kernel=False, dtype=None, temperature=0.0, top_k=0,
@@ -638,7 +639,6 @@ class PagedGPTDecoder:
         # (w rides as a traced scalar — per-dispatch width changes
         # never compile a new program; dispatches bucket by total
         # token count t alone)
-        self._used = set()    # program names dispatched so far (first_use)
         self._packed_prefills = {}   # (t, window) -> jitted packed prefill
         self._verify = None   # jitted lazily (speculative decoding only)
         self._probs = None    # jitted lazily (sampled speculation)
@@ -659,17 +659,6 @@ class PagedGPTDecoder:
         self._adapter_salts = [b""]
         _LIVE_DECODERS.add(self)
 
-    def first_use(self, program):
-        """True the first time `program` (a `program_name`) is asked
-        about on this decoder, False ever after. A first use has a
-        compile or a cache load inside its measured time: the engines
-        stamp it on the horizon's record and keep such a window out of
-        the drift ledger."""
-        if program in self._used:
-            return False
-        self._used.add(program)
-        return True
-
     @staticmethod
     @functools.lru_cache(maxsize=None)
     def program_name(kind, k, x, width, window=None):
@@ -679,10 +668,10 @@ class PagedGPTDecoder:
         page table's `width` in columns and, packed, the `window` one
         row's tokens are laid out in (`packed_window`): `jit_<name>` on
         a trace's "XLA Modules" line, `program` on the engine's
-        `engine.dispatch` span
-        and on the horizon's record, and what `first_use` is asked
-        about. Kinds: "packed", "decode" (`decode_multi`, on
-        the whole table) and "tick" (the per-tick `decode`). Cached: a
+        `engine.dispatch` span, on the horizon's record and in the
+        compile log (`profiler.compile_log`). Kinds: "packed", "decode"
+        (`decode_multi`, on the whole table) and "tick" (the per-tick
+        `decode`). Cached: a
         round pays a lookup, not a format."""
         if kind == "packed":
             return f"packed_multi_k{k}_t{x}_w{window}_p{width}"

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework.core import Tensor
-from ..profiler import span
+from ..profiler import _compiles_since, build_span, span
 from .decoder import (PagedGPTDecoder, _spec_accept, packed_window,
                       pow2_at_least)
 from .stats import _ENGINES, ServeStats
@@ -92,6 +92,7 @@ class ContinuousBatchingEngine:
     pressure — every page freed exactly once, auditable via
     `page_ledger()`/`audit_pages()` (MEM-PAGE-REFCOUNT)."""
 
+    @build_span("engine.build")
     def __init__(self, decoder: PagedGPTDecoder, eos_token_id=None,
                  max_new_tokens=64, k_max=None, host_sync_s=None,
                  prefix_cache=None, ragged=None, chunk_tokens=None,
@@ -370,9 +371,10 @@ class ContinuousBatchingEngine:
         identity  kind, seq, k, w, t_tokens, decode_rows, prefill_rows,
                   slots, program (`PagedGPTDecoder.program_name`: the
                   name the jitted program and the `engine.dispatch`
-                  span carry), first_use (this decoder had not
-                  dispatched the program before: a compile or a cache
-                  load lies inside)
+                  span carry), compiles (the lowerings, compiles and
+                  cache loads of the compile log that ended inside the
+                  round, `profiler.compile_log`: any program's, this
+                  one's in another signature included)
         queue     queue_depth after admission; admit_waits_s, the
                   submit-to-admit wait of each request admitted
         times     t_round, t_fetched; admit_s, plan_s, dispatch_s,
@@ -446,7 +448,7 @@ class ContinuousBatchingEngine:
             pages_live=int(((self._lens + ps - 1) // ps).sum()),
             k=k, w=w, t_tokens=t_tokens, decode_rows=decode_rows,
             prefill_rows=prefill_rows, slots=self.d.max_batch,
-            program=program, first_use=self.d.first_use(program),
+            program=program, compiles=_compiles_since(rec["t_round"]),
             queue_depth=len(self._queue), tokens_dispatched=disp_toks)
         self._sched_events.append(rec)
         if self.trace is not None:
@@ -472,11 +474,11 @@ class ContinuousBatchingEngine:
         if step_times is not None:
             step_times.append(dt)
         if self.trace is not None:
-            # the program's first (compiling) dispatch stays out of
-            # the drift ledger: one compile sample would inflate the
-            # rolling mean for hundreds of steady ticks
+            # a round that compiled stays out of the drift ledger: one
+            # compile sample would inflate the rolling mean for hundreds
+            # of steady ticks
             self.trace.tick_complete(
-                rec, dt, drift=drift and not rec["first_use"],
+                rec, dt, drift=drift and not rec["compiles"],
                 pool=self._trace_pool_delta())
         return dt
 
@@ -1351,8 +1353,8 @@ class ContinuousBatchingEngine:
         horizon is dispatched, so the device→host wait overlaps it.
         `seq` is the round this runs in (its spans nest under that
         round's); what it measures goes into the FETCHED horizon's
-        record. `polluted`: another program's first (compiling)
-        dispatch landed inside this horizon's still-open window."""
+        record. `polluted`: the next round compiled something inside
+        this horizon's still-open window."""
         block_d, done_before_d, k, rids, had_prefill, rec = meta
         ids = {"seq": seq, "horizon": rec["seq"]}
         with _Phase("engine.fetch", rec, "fetch_wait_s", **ids):
@@ -1499,13 +1501,13 @@ class ContinuousBatchingEngine:
                             {s: self._slot_req[s] for s in disp},
                             prefilled, rec)
                 if pending is not None:
-                    # a first use dispatched THIS round compiled inside
-                    # the PENDING horizon's still-open measured window
-                    # (processing closes after the next dispatch)
+                    # a compile in THIS round lies inside the PENDING
+                    # horizon's still-open measured window (processing
+                    # closes after the next dispatch)
                     self._process_block(
                         pending, inflight, step_times, seq,
                         prefilled_since=prefilled,
-                        polluted=meta is not None and rec["first_use"])
+                        polluted=meta is not None and rec["compiles"] > 0)
                     if on_sync is not None:
                         prev = pending[-1]
                         with _Phase("engine.on_sync", prev, "on_sync_s",
@@ -1834,11 +1836,11 @@ class ContinuousBatchingEngine:
                              for s, rid in live.items()},
                             plan.emit_ticks, rec)
                 if pending is not None:
-                    # see _run_multi: a first use dispatched this
-                    # round compiled in the pending horizon's window
+                    # see _run_multi: a compile in this round lies in
+                    # the pending horizon's window
                     self._process_ragged_block(
                         pending, inflight, step_times, seq,
-                        polluted=meta is not None and rec["first_use"])
+                        polluted=meta is not None and rec["compiles"] > 0)
                     if on_sync is not None:
                         prev = pending[-1]
                         with _Phase("engine.on_sync", prev, "on_sync_s",

@@ -59,7 +59,7 @@ What differs from the GPT decoder, by mechanism:
   does not slice).
 
 What it keeps: the engine reaches a decoder only through `ragged_multi`,
-`prefill_suffix_batch`, `copy_page`, `program_name`/`first_use`,
+`prefill_suffix_batch`, `copy_page`, `program_name`,
 `pend_capacity`, `step_hbm_bytes`, `kv_page_bytes`, `cache_fingerprint`
 and the plain attributes (`num_pages`, `page_size`, `max_batch`,
 `max_pages`, `kv_quant`, `lora`, `n_adapters`, `sampling`,
@@ -89,6 +89,7 @@ from ..models.deepseek_v2 import mla_project, rms_norm, softmax_scale
 from ..ops.ragged_paged_attention import (KEY_BLOCK_PAGES,
                                           mla_paged_attention_packed,
                                           ragged_paged_attention_packed)
+from ..profiler import build_span
 from .decoder import (RaggedMultiOut, _named_jit, packed_prefill_layout,
                       packed_tick, packed_window, pow2_at_least)
 
@@ -178,6 +179,7 @@ class PagedMLADecoder:
                         "decode_multi, no per-tick decode)",
     }
 
+    @build_span("decoder.build")
     def __init__(self, model, num_pages=128, page_size=16, max_batch=8,
                  max_pages_per_seq=None, quant=None, kv_quant=None,
                  use_kernel=False, dtype=None, temperature=0.0, top_k=0,
@@ -251,7 +253,6 @@ class PagedMLADecoder:
         self._packeds = {}          # (k, t, window, width) -> program
         self._packed_prefills = {}  # (t, window) -> program
         self._copy = None
-        self._used = set()
         self._engines = weakref.WeakSet()
 
     # ------------------------------------------------------- the weights
@@ -297,14 +298,6 @@ class PagedMLADecoder:
                 "segments": segments}
 
     # -------------------------------------------------------- identities
-
-    def first_use(self, program):
-        """True the first time `program` is asked about (see
-        `PagedGPTDecoder.first_use`)."""
-        if program in self._used:
-            return False
-        self._used.add(program)
-        return True
 
     @staticmethod
     @functools.lru_cache(maxsize=None)

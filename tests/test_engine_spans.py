@@ -28,7 +28,7 @@ PHASES = ("admit_s", "plan_s", "dispatch_s", "fetch_wait_s", "book_s",
 # every field of an untraced engine's record; PERF.md section 3 names the
 # reader of each (a metric, `debug.serving_report()` or the engine itself)
 FIELDS = {"kind", "seq", "k", "w", "t_tokens", "decode_rows", "prefill_rows",
-          "slots", "program", "first_use", "queue_depth", "admit_waits_s",
+          "slots", "program", "compiles", "queue_depth", "admit_waits_s",
           "t_round", "t_fetched", "tokens", "tokens_dispatched",
           "tokens_padded", "pages_gathered", "pages_live"} | set(PHASES)
 CHILDREN = {"engine.admit", "engine.plan", "engine.dispatch",
@@ -113,13 +113,18 @@ def _children(spans, parent):
 def test_profiled_engine_nests_its_spans_under_the_round(tiny_model, loop,
                                                          tmp_path):
     """Under `jax.profiler.trace` the `.xplane.pb` holds `engine.round`
-    with its six children nested and carrying one `seq`; what the profiled
-    engine serves is what the unprofiled one does."""
+    with its six children nested and carrying one `seq`, after the
+    engine's own `engine.build`; what the profiled engine serves is what
+    the unprofiled one does."""
     base, _, _ = _serve(tiny_model, loop)
     (served, eng, _), spans = _profiled(
         tmp_path, lambda: _serve(tiny_model, loop))
     assert served == base
+    build, = [s for s in spans if s[0] == "engine.build"]
+    assert build[3]["program"] == "ContinuousBatchingEngine"
+    spans = [s for s in spans if s is not build]
     rounds = [s for s in spans if s[0] == "engine.round"]
+    assert build[2] <= rounds[0][1]
     assert [r[3]["seq"] for r in rounds] == list(range(1, len(rounds) + 1))
     inside = [c for r in rounds for c in _children(spans, r)]
     assert len(inside) == len(spans) - len(rounds), "a span outside a round"
@@ -204,21 +209,53 @@ def test_one_record_a_horizon(tiny_model, loop):
 
 
 @pytest.mark.parametrize("loop", list(LOOPS))
-def test_first_use_is_true_once_a_program(tiny_model, loop):
-    """`first_use` marks the one dispatch of each program that has a
-    compile or a cache load inside: once a decoder, whichever engine."""
+def test_compiles_counts_what_a_round_made(tiny_model, loop):
+    """`compiles` counts the lowerings, compiles and cache loads of the
+    compile log that ended inside the horizon's round: at least one on
+    each program's first horizon on a fresh decoder, none once the same
+    traffic finds every program made."""
     dec = PagedGPTDecoder(tiny_model, num_pages=48, page_size=16,
                           max_batch=2)
-    seen = []
+    runs = []
     for _ in range(2):
         _, eng, _ = _serve(tiny_model, loop, dec=dec)
-        seen += [(ev["program"], ev["first_use"])
-                 for ev in eng.serve_schedule() if ev["kind"] == "horizon"]
-    programs = {p for p, _ in seen}
+        runs.append([(ev["program"], ev["compiles"])
+                     for ev in eng.serve_schedule()
+                     if ev["kind"] == "horizon"])
+    first, again = runs
+    programs = {p for p, _ in first}
     assert len(programs) >= (1 if loop == "per_tick" else 2)
     for p in programs:
-        uses = [first for q, first in seen if q == p]
-        assert uses[0] is True and not any(uses[1:]), (p, uses)
+        counts = [n for q, n in first if q == p]
+        assert counts[0] >= 1, (p, counts)
+    assert [n for _, n in again] == [0] * len(again)
+    assert {p for p, _ in again} == programs
+
+
+def test_compiles_sees_a_second_signature_under_one_name(tiny_model):
+    """A program made again under a name it already dispatched (a weak-
+    typed scalar where a typed one was: a second signature, as a carry
+    that is committed once and uncommitted once makes) reads `compiles`
+    at least 1; the memo of names dispatched so far read it as warm."""
+    dec = PagedGPTDecoder(tiny_model, num_pages=48, page_size=16,
+                          max_batch=2)
+    plain, eng, _ = _serve(tiny_model, "ragged", dec=dec)
+    warm = {ev["program"] for ev in eng.serve_schedule()}
+
+    def weak(fn):
+        return lambda *args: fn(*args[:-1], int(args[-1]))
+
+    for key, fn in list(dec._packeds.items()):
+        dec._packeds[key] = weak(fn)
+    served, eng, _ = _serve(tiny_model, "ragged", dec=dec)
+    assert served == plain
+    hz = [ev for ev in eng.serve_schedule() if ev["kind"] == "horizon"]
+    assert {ev["program"] for ev in hz} <= warm
+    assert hz[0]["compiles"] >= 1
+    from paddle_tpu.profiler import compile_log
+    made = {e["program"] for e in compile_log(since=hz[0]["t_round"])[0]
+            if e["phase"] == "compile" or e["phase"] == "load"}
+    assert hz[0]["program"] in made
 
 
 def test_serving_report_speaks_by_program(tiny_model):
@@ -231,6 +268,8 @@ def test_serving_report_speaks_by_program(tiny_model):
         sorted({ev["program"] for ev in hz})
     assert sum(p["n"] for p in entry["programs"]) == len(hz)
     assert all(p["tick_ms_p50"] > 0 for p in entry["programs"])
+    # a fresh decoder made each program: the compile log has its seconds
+    assert all(p["compile_load_s"] > 0 for p in entry["programs"])
     assert entry["schedule"]["round_host_ms_p50"] > 0
 
 
@@ -259,7 +298,7 @@ def test_serving_report_reads_queue_and_pad_off_the_records(tiny_model):
 
 def test_a_program_name_is_made_once(tiny_model):
     """One key, one name: the round looks the name up (`lru_cache`), the
-    record, the span and `first_use` share the very string."""
+    record, the span and the compile log share the very string."""
     name = PagedGPTDecoder.program_name("packed", 2, 256, 64, 128)
     assert name == "packed_multi_k2_t256_w128_p64"
     assert PagedGPTDecoder.program_name("packed", 2, 256, 64, 128) is name
